@@ -27,8 +27,10 @@ RESULTS: list[dict] = []
 @functools.lru_cache(maxsize=1)
 def run_meta() -> dict:
     """Provenance stamped into every recorded run: git SHA, hostname,
-    platform, python/jax versions, and the JAX device kind. Every probe
-    is best-effort -- a bench run must never fail on metadata."""
+    platform, python/jax versions, and the JAX device kind. The git SHA
+    is best-effort (a copy of the tree need not be a repository); the
+    device probe is not: a row must never be recorded without the
+    device it ran on."""
     meta = {
         "hostname": socket.gethostname(),
         "platform": platform.platform(),
@@ -41,15 +43,12 @@ def run_meta() -> dict:
         ).stdout.strip() or None
     except Exception:
         meta["git_sha"] = None
-    try:
-        import jax
-        meta["jax"] = jax.__version__
-        dev = jax.devices()[0]
-        meta["device_kind"] = dev.device_kind
-        meta["backend"] = dev.platform
-        meta["device_count"] = jax.device_count()
-    except Exception:
-        meta["jax"] = None
+    import jax
+    meta["jax"] = jax.__version__
+    dev = jax.devices()[0]
+    meta["device_kind"] = dev.device_kind
+    meta["backend"] = dev.platform
+    meta["device_count"] = jax.device_count()
     return dict(meta)
 
 

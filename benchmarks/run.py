@@ -15,7 +15,8 @@ counts (100 graphs/group).
 Every invocation appends a run record to BENCH_all.json, and the kernel
 section always appends its rows to BENCH_kernels.json (written from
 `bench_kernels.main`'s finally-block, so a mid-bench failure still
-records the partial run).
+records the partial run). A bench that raises does not stop the rest;
+the harness names it and exits non-zero once the others have run.
 """
 from __future__ import annotations
 
@@ -32,6 +33,8 @@ def main() -> None:
                             bench_features, bench_incremental,
                             bench_frontier_density,
                             bench_telemetry_overhead, bench_autotune)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     fast = bool(os.environ.get("BENCH_FAST"))
     calls = [
         (bench_speedup, dict(graphs_per_group=1, sources_per_graph=1,
@@ -63,6 +66,7 @@ def main() -> None:
         # only; the autotune-smoke CI job enforces the bounds
         (bench_autotune, dict(min_vs_default=0.0, min_vs_worst=0.0)),
     ]
+    failed = []
     for m, kw in calls:
         try:
             if kw and hasattr(m, "run"):
@@ -72,6 +76,7 @@ def main() -> None:
         except Exception:
             print(f"[bench] {m.__name__} FAILED", file=sys.stderr)
             traceback.print_exc()
+            failed.append(m.__name__)
     # roofline table only if dry-run results exist
     try:
         from benchmarks import roofline
@@ -79,8 +84,12 @@ def main() -> None:
             roofline.main()
     except Exception:
         traceback.print_exc()
+        failed.append("roofline")
     from benchmarks.common import write_json
     write_json("all")
+    if failed:
+        raise SystemExit(f"[bench] {len(failed)} bench(es) failed: "
+                         f"{', '.join(failed)}")
 
 
 if __name__ == "__main__":

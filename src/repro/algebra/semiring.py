@@ -121,9 +121,12 @@ def _generic_contract(add_reduce, mul, slab: int = 8):
 
 def _matmul_contract(sv, w):
     """(+, ×) tile contraction as a true matmul: ``w.T @ sv`` contracts
-    the source axis on the MXU ((T, T) × (T, d) per tile)."""
+    the source axis on the MXU ((T, T) × (T, d) per tile). HIGHEST
+    precision: at the default one the TPU multiplies f32 operands in a
+    single bfloat16 pass, which is off by ~1e-3 relative."""
     return jnp.matmul(jnp.swapaxes(w, -1, -2), sv,
-                      preferred_element_type=jnp.float32)
+                      preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _segment_or(x, seg, num_segments):

@@ -58,8 +58,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.algebra import VertexAlgebra
 from repro.core.mapping import Mapping
@@ -230,20 +229,27 @@ class FlipEngine:
         return attrs, aux, jnp.asarray(
             frontier.reshape(b, bg.ntiles, bg.tile))
 
-    def _step(self, attrs, aux, frontier, with_stats: bool = False):
+    def _step(self, attrs, aux, frontier, with_stats: bool = False,
+              bg: BlockedGraph | None = None):
+        """One relax step over `bg` (default: the engine's own layout;
+        the device fixpoint passes a view whose arrays are its traced
+        arguments)."""
         alg, features = self.algebra, self._features
+        bg = self.bg if bg is None else bg
         sv, carry = alg.scatter_carry_jnp(attrs, frontier,
                                           op_mode=(self.mode == "op"),
                                           features=features)
-        new = frontier_relax(sv, carry, self.bg, mode=self.relax_mode,
+        new = frontier_relax(sv, carry, bg, mode=self.relax_mode,
                              compact=self._use_compact,
                              feature_dim=self.feature_dim)
         out = alg.post_step_jnp(attrs, aux, sv, new, features=features)
         if not with_stats:
             return out
-        return out, self._step_stats_jit()(sv, frontier)
+        if bg is self.bg:
+            return out, self._step_stats_jit()(sv, frontier)
+        return out, self._step_stats(sv, frontier, bg)
 
-    def _step_stats(self, sv, frontier):
+    def _step_stats(self, sv, frontier, bg: BlockedGraph | None = None):
         """One trace row's worth of per-step stats, computed from the
         exact quantities the compaction machinery derives anyway: the
         frontier entering the step, the per-tile activity of the
@@ -254,7 +260,7 @@ class FlipEngine:
         Returns ``(active_vertices (B,), active_tiles (), fetched ())``
         as i32; `fetched` is the blocks streamed from HBM this step
         (active blocks under compaction, all blocks under dense)."""
-        bg = self.bg
+        bg = self.bg if bg is None else bg
         act = tile_activity(sv, bg.semiring, self._features)  # (ntiles,)
         active_tiles = jnp.sum(act.astype(jnp.int32))
         nb = bg.bsrc.shape[0]
@@ -278,7 +284,8 @@ class FlipEngine:
         return fn
 
     def _masked_step(self, attrs, aux, frontier, live,
-                     with_stats: bool = False):
+                     with_stats: bool = False,
+                     bg: BlockedGraph | None = None):
         """One relax step with the per-query freeze applied: queries not
         in `live` ((B,) bool -- frontier emptied, or step/deadline budget
         exhausted) keep their state *and their frontier* untouched, so a
@@ -287,7 +294,8 @@ class FlipEngine:
         emptied naturally). The single body behind both fixpoint
         drivers, so host-driven and while_loop runs stay bit-for-bit
         identical."""
-        stepped = self._step(attrs, aux, frontier, with_stats=with_stats)
+        stepped = self._step(attrs, aux, frontier, with_stats=with_stats,
+                             bg=bg)
         (attrs_n, aux_n, frontier_n), stats = \
             stepped if with_stats else (stepped, None)
         # live broadcasts from the query axis over every trailing state
@@ -345,8 +353,10 @@ class FlipEngine:
             return self._fixpoint_host(attrs0, aux0, frontier0, trace_cap,
                                        budgets=budgets,
                                        deadlines_t=deadlines_t)
-        out = self._dense_fixpoint_jit(trace_cap)(attrs0, aux0, frontier0,
-                                                  budgets)
+        bg = self.bg
+        out = self._dense_fixpoint_jit(trace_cap)(
+            (bg.blocks, bg.blocks_ext, bg.bsrc, bg.bdst), attrs0, aux0,
+            frontier0, budgets)
         attrs, aux, frontier, steps = out[0], out[1], out[2], out[3]
         converged = ~np.asarray(frontier.any(axis=(1, 2)))
         expired = np.zeros(b, dtype=bool)
@@ -369,7 +379,13 @@ class FlipEngine:
         dispatch of the loop would otherwise dominate the step cost (and
         blow the traced/untraced overhead bound). The traced variant only
         adds fixed-shape stat-buffer writes to the carry, so both compile
-        to the same fused step with tracing as a few extra reductions."""
+        to the same fused step with tracing as a few extra reductions.
+
+        The layout's arrays (blocks, sentinel-extended blocks, bsrc,
+        bdst) are arguments of the program, not values it closes over:
+        a closed-over array is embedded in the program as a constant,
+        which at a million vertices means gigabytes of constants to
+        lower, compile and hold a second time on the device."""
         cache = self.__dict__.setdefault("_fixpoint_cache", {})
         fn = cache.get(trace_cap)
         if fn is not None:
@@ -387,17 +403,17 @@ class FlipEngine:
             frontier, steps, budgets = state[2], state[3], state[4]
             return live_mask(frontier, steps, budgets).any()
 
-        def body(state):
+        def body(bg, state):
             attrs, aux, frontier, steps, budgets = state[:5]
             live = live_mask(frontier, steps, budgets)
             if not trace_cap:
-                attrs, aux, frontier = self._masked_step(attrs, aux,
-                                                         frontier, live)
+                attrs, aux, frontier = self._masked_step(
+                    attrs, aux, frontier, live, bg=bg)
                 return (attrs, aux, frontier,
                         steps + live.astype(jnp.int32), budgets)
             it, (b_av, b_at, b_bf, b_cv) = state[5], state[6]
             (attrs, aux, frontier), (av, at, bf) = self._masked_step(
-                attrs, aux, frontier, live, with_stats=True)
+                attrs, aux, frontier, live, with_stats=True, bg=bg)
             # rows past the capacity are dropped, not wrapped: the trace
             # stays a prefix of the run and `truncated` flags the cut
             bufs = (b_av.at[it].set(av, mode="drop"),
@@ -408,7 +424,11 @@ class FlipEngine:
                     budgets, it + 1, bufs)
 
         @jax.jit
-        def run(attrs0, aux0, frontier0, budgets):
+        def run(layout, attrs0, aux0, frontier0, budgets):
+            blocks, blocks_ext, bsrc, bdst = layout
+            bg = dataclasses.replace(self.bg, blocks=blocks,
+                                     blocks_ext=blocks_ext, bsrc=bsrc,
+                                     bdst=bdst)
             b = attrs0.shape[0]
             state0 = (attrs0, aux0, frontier0, jnp.zeros(b, jnp.int32),
                       budgets)
@@ -418,7 +438,8 @@ class FlipEngine:
                          jnp.zeros((trace_cap,), jnp.int32),
                          jnp.zeros((trace_cap, b), bool))
                 state0 = state0 + (jnp.int32(0), bufs0)
-            return jax.lax.while_loop(cond, body, state0)
+            return jax.lax.while_loop(cond, functools.partial(body, bg),
+                                      state0)
 
         cache[trace_cap] = run
         return run
@@ -787,9 +808,8 @@ class FlipEngine:
             devs = np.array(jax.devices())
             mesh = Mesh(devs, (axis,))
         ndev = mesh.shape[axis]
-        bg, alg = self.bg, self.algebra
-        sr = alg.semiring
-        zero = np.float32(sr.zero)
+        bg = self.bg
+        zero = np.float32(self.algebra.semiring.zero)
 
         # pad tiles to a multiple of ndev, then slice each device's block
         # slab straight out of the bdst-sorted list via the precomputed
@@ -816,11 +836,14 @@ class FlipEngine:
             # destination indices local to the device slab
             bdst_sh[dev, :e - s] = bdst[s:e] - dev * tiles_per_dev
             valid_sh[dev, :e - s] = True
-            # padding blocks (and the whole slab of a block-less device)
-            # keep bsrc/bdst 0 and all ⊕-identity entries = exact no-op;
-            # valid=False keeps them out of the idle-skip predicate (a
-            # padding slot's bsrc points at global tile 0, whose activity
-            # must not keep this device awake)
+            # padding slots hold all-⊕-identity blocks (exact no-ops) and
+            # repeat the last real slot's tile pair, so the kernel never
+            # revisits an earlier destination slab; valid=False keeps
+            # them out of the idle-skip predicate. A block-less device
+            # keeps tile pair (0, 0) throughout.
+            if e > s:
+                bsrc_sh[dev, e - s:] = bsrc_sh[dev, e - s - 1]
+                bdst_sh[dev, e - s:] = bdst_sh[dev, e - s - 1]
 
         features = self._features
         attrs0, aux0, frontier0 = self.initial_state(srcs, warm=warm)
@@ -830,24 +853,55 @@ class FlipEngine:
             attrs0 = jnp.pad(attrs0, widths, constant_values=zero)
             aux0 = jnp.pad(aux0, widths)
             frontier0 = jnp.pad(frontier0, ((0, 0), (0, pad), (0, 0)))
-        op_mode = self.mode == "op"
-        skip_idle = self._use_compact
         if budgets is None:
             budgets0 = np.full(srcs.shape[0], self.max_steps,
                                dtype=np.int32)
         else:
             budgets0 = np.asarray(budgets, dtype=np.int32)
 
+        # each device receives only its own slab: placing the stacked
+        # (ndev, max_nb, T, T) array with one jnp.asarray would first
+        # land all of it on the default device
+        shard = NamedSharding(mesh, P(axis))
+        slabs = [jax.device_put(a, shard)
+                 for a in (blocks_sh, bsrc_sh, bdst_sh, valid_sh)]
+        attrs_f, aux_f, steps, conv = self._dist_fixpoint(
+            mesh, axis, tiles_per_dev)(*slabs, attrs0, aux0, frontier0,
+                                       jnp.asarray(budgets0))
+        out = self.algebra.finalize(attrs_f, aux_f)
+        out = self.bg.to_orig(out[:, :bg.ntiles], features=features)
+        return out, np.asarray(steps), np.asarray(conv)
+
+    def _dist_fixpoint(self, mesh: Mesh, axis: str, tiles_per_dev: int):
+        """The jitted shard_map fixpoint of `_execute_distributed` over
+        `mesh`: arguments are the (ndev, max_nb, ...) per-device slabs
+        (sharded over `axis`) and the replicated (B, ntiles_p, T[, d])
+        state plus (B,) budgets. Each device relaxes the whole source
+        state into its own destination slab through the same
+        `frontier_relax` dispatch as the local fixpoint, so every
+        destination tile accumulates its blocks in the same order and
+        the results are bit-for-bit the local ones."""
+        alg = self.algebra
+        features = self._features
+        op_mode = self.mode == "op"
+        skip_idle = self._use_compact
+
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis),
                       P(None), P(None), P(None), P(None)),
             out_specs=(P(None), P(None), P(None), P(None)),
-            check_rep=False)
+            check_vma=False)
         def dist_fix(blocks, bsrc_l, bdst_l, valid_l, attrs, aux, frontier,
                      budgets):
             blocks, bsrc_l, bdst_l, valid_l = (blocks[0], bsrc_l[0],
                                                bdst_l[0], valid_l[0])
+            # this device's layout view; the dense relax never reads
+            # blocks_ext, and passing one keeps __post_init__ from
+            # building a sentinel-extended copy inside the trace
+            local = dataclasses.replace(self.bg, blocks=blocks,
+                                        blocks_ext=blocks, bsrc=bsrc_l,
+                                        bdst=bdst_l)
 
             def cond(state):
                 _, _, frontier, steps = state
@@ -855,15 +909,10 @@ class FlipEngine:
                                        steps < budgets).any()
 
             def relax_local(args):
-                svb, carry_local = args
-                if features:        # (B, nb, T, d) x (nb, T, T) contraction
-                    cand = sr.contract_jnp(svb, blocks)
-                else:
-                    cand = sr.add_reduce_jnp(
-                        sr.mul_jnp(svb[..., :, None], blocks), axis=-2)
-                best = jax.vmap(lambda c: sr.segment_reduce_jnp(
-                    c, bdst_l, tiles_per_dev))(cand)
-                return sr.add_jnp(carry_local, best)
+                sv, carry_local = args
+                return frontier_relax(sv, carry_local, local,
+                                      mode=self.relax_mode,
+                                      feature_dim=self.feature_dim)
 
             def body(state):
                 attrs, aux, frontier, steps = state
@@ -874,23 +923,19 @@ class FlipEngine:
                 carry_local = jax.lax.dynamic_slice_in_dim(
                     carry, jax.lax.axis_index(axis) * tiles_per_dev,
                     tiles_per_dev, axis=1)
-                svb = sv[:, bsrc_l]                        # (B, nb, T[, d])
-                valid_b = valid_l.reshape(
-                    (1, -1) + (1,) * (svb.ndim - 2))
                 if skip_idle:
                     # per-device frontier compaction, degenerate exact
                     # form: no active source among the local *real*
                     # blocks (any query) => the local relax is pure
                     # ⊕-identity, so return the carry without touching
-                    # the weight slab. Padding slots are masked out --
-                    # their bsrc points at global tile 0, whose activity
-                    # must not keep an otherwise idle device awake.
+                    # the weight slab
+                    act = tile_activity(sv, alg.semiring, features)
                     new_local = jax.lax.cond(
-                        jnp.any(jnp.logical_and(svb != zero, valid_b)),
+                        jnp.any(jnp.logical_and(act[bsrc_l], valid_l)),
                         relax_local, lambda args: args[1],
-                        (svb, carry_local))
+                        (sv, carry_local))
                 else:
-                    new_local = relax_local((svb, carry_local))
+                    new_local = relax_local((sv, carry_local))
                 new = jax.lax.all_gather(new_local, axis, axis=1,
                                          tiled=True)
                 attrs_n, aux_n, frontier_n = alg.post_step_jnp(
@@ -908,14 +953,7 @@ class FlipEngine:
             conv = jnp.logical_not(frontier_f.any(axis=(1, 2)))
             return attrs_f, aux_f, steps, conv
 
-        blocks_sh = jnp.asarray(blocks_sh)
-        attrs_f, aux_f, steps, conv = jax.jit(dist_fix)(
-            blocks_sh, jnp.asarray(bsrc_sh), jnp.asarray(bdst_sh),
-            jnp.asarray(valid_sh), attrs0, aux0, frontier0,
-            jnp.asarray(budgets0))
-        out = self.algebra.finalize(attrs_f, aux_f)
-        out = self.bg.to_orig(out[:, :bg.ntiles], features=features)
-        return out, np.asarray(steps), np.asarray(conv)
+        return jax.jit(dist_fix)
 
     # -------------------------------------------------------------- #
     # deprecated pre-api entry points: thin shims over `execute`

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.distributed.sharding import current_mesh
 
@@ -93,7 +92,7 @@ def moe_all_to_all(p, x, cfg, model_axis: str = "model"):
                     * weights.astype(out.dtype)[:, :, None], axis=1)
         return y.reshape(b_loc, s_loc, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(None, None),                     # router replicated
                   P(model_axis, None, None),         # experts sharded
@@ -101,5 +100,5 @@ def moe_all_to_all(p, x, cfg, model_axis: str = "model"):
                   P(model_axis, None, None),
                   P(dp_spec, model_axis, None)),     # x: batch x seq-shard
         out_specs=(P(dp_spec, model_axis, None), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(p["router"], p["w_gate"], p["w_in"], p["w_out"], x)

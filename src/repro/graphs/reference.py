@@ -98,16 +98,16 @@ def pagerank(g: Graph, damping: float = 0.85, tol: float = 1e-12,
     deg = g.out_degree().astype(np.float64)
     b = (1.0 - damping) / n
     p = np.zeros(n, dtype=np.float64)
+    eu = g.edge_sources()
     iters = 0
     edges_relaxed = 0
     for iters in range(1, max_iters + 1):
         contrib = np.where(deg > 0, p / np.maximum(deg, 1), 0.0)
-        new = np.full(n, b)
-        for u in range(n):
-            lo, hi = g.indptr[u], g.indptr[u + 1]
-            if contrib[u]:
-                new[g.indices[lo:hi]] += damping * contrib[u]
-            edges_relaxed += hi - lo
+        # push every out-edge's share along it (CSR holds no duplicate
+        # edges, so one weighted bincount is the per-edge scatter)
+        new = b + np.bincount(g.indices, weights=damping * contrib[eu],
+                              minlength=n)
+        edges_relaxed += g.m
         delta = np.abs(new - p).max()
         p = new
         if delta < tol:
@@ -199,17 +199,17 @@ def labelprop(g: Graph, src: int, d: int = 8, damping: float = 0.85,
     b = np.zeros((n, d), dtype=np.float64)
     b[lm, np.arange(d)] = 1.0 - damping
     p = np.zeros((n, d), dtype=np.float64)
+    eu = g.edge_sources()
     iters = 0
     edges_relaxed = 0
     for iters in range(1, max_iters + 1):
         contrib = np.where(deg[:, None] > 0,
                            p / np.maximum(deg, 1)[:, None], 0.0)
-        new = b.copy()
-        for u in range(n):
-            lo, hi = g.indptr[u], g.indptr[u + 1]
-            if contrib[u].any():
-                new[g.indices[lo:hi]] += damping * contrib[u]
-            edges_relaxed += hi - lo
+        push = damping * contrib[eu]                     # (m, d)
+        new = b + np.stack([np.bincount(g.indices, weights=push[:, f],
+                                        minlength=n)
+                            for f in range(d)], axis=1)
+        edges_relaxed += g.m
         delta = np.abs(new - p).max()
         p = new
         if delta < tol:

@@ -38,6 +38,7 @@ import numpy as np
 from repro import api as flip
 from repro.core import (compile_mapping, simulate, PROGRAMS, baselines)
 from repro.graphs import make_dataset, reference
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -90,6 +91,7 @@ def main():
                          "re-emitted through the same schema for sim")
     args = ap.parse_args()
     args.compact = {"auto": "auto", "on": True, "off": False}[args.compact]
+    enable_compile_cache()
 
     # one plan resolution folds every deprecated CLI spelling
     # (--engine op -> --engine jax --mode op, warns once)
@@ -137,7 +139,7 @@ def main():
     if srcs is not None:
         ok = _run_batched(args, g, mapping, srcs)
         print(f"[graph] correct vs reference: {ok}")
-        return
+        raise SystemExit(0 if ok else 1)
 
     if args.engine == "sim":
         if not PROGRAMS[args.algo].sim_ok:
@@ -186,8 +188,9 @@ def main():
             g, attrs = _replay_updates(args, g, cq, res)
 
     ref, _ = reference.run(args.algo, g, args.src)
-    print(f"[graph] correct vs reference: "
-          f"{PROGRAMS[args.algo].results_match(attrs, ref)}")
+    ok = PROGRAMS[args.algo].results_match(attrs, ref)
+    print(f"[graph] correct vs reference: {ok}")
+    raise SystemExit(0 if ok else 1)
 
 
 def _cli_plan(args, **kw):

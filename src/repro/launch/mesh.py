@@ -15,18 +15,21 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple, axes: tuple) -> Mesh:
-    """Arbitrary mesh for tests/examples (e.g. (2,4) on 8 host devices)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh for tests/examples (e.g. (2,4) on 8 host devices).
+    Axes are `Auto`: the sharding rules place arrays with
+    `with_sharding_constraint`, which refuses `Explicit` axes."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def elastic_mesh(preferred: tuple = (16, 16),
@@ -39,4 +42,4 @@ def elastic_mesh(preferred: tuple = (16, 16),
     while model > 1 and n % model:
         model //= 2
     data = n // model
-    return jax.make_mesh((data, model), axes)
+    return make_mesh((data, model), axes)

@@ -55,6 +55,7 @@ from repro.api import CompiledQuery, ExecutionPlan
 from repro.distributed.health import HeartbeatMonitor
 from repro.graphs import make_dataset, reference
 from repro.graphs.csr import Graph
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import MetricsRegistry
 from repro.resilience import (CapacityExceeded, ConvergenceFailure,
                               DeadlineExceeded, FaultInjector, FlipError,
@@ -546,6 +547,42 @@ def _random_update_batch(g, rng, k: int = 4):
     return batch
 
 
+def demo_stream(g: Graph, algos: list, requests: int, updates: int,
+                rng: np.random.Generator) -> tuple[list, list]:
+    """Seeded mixed request stream: `requests` ``(algo, src)`` queries
+    with `updates` random mutation batches interleaved at evenly spaced
+    positions. Returns ``(stream, snapshots)``: `snapshots[i]` is the
+    graph version query i is dispatched against, so every response can
+    be checked against the right oracle."""
+    update_at = (set(np.linspace(1, requests - 1, updates,
+                                 dtype=int).tolist())
+                 if updates else set())
+    stream, snapshots, g_cur = [], [], g
+    for i in range(requests):
+        if i in update_at:
+            batch = _random_update_batch(g_cur, rng)
+            stream.append(("update", batch))
+            g_cur = g_cur.apply_updates(batch)
+        stream.append((algos[int(rng.integers(len(algos)))],
+                       int(rng.integers(g.n))))
+        snapshots.append(g_cur)
+    return stream, snapshots
+
+
+def oracle_check(reqs: list, snapshots: list) -> tuple[int, int]:
+    """Check every served response against the numpy oracle on its
+    graph-version snapshot; returns ``(checked, wrong)``. Requests that
+    ended in a typed failure carry no result and are not counted."""
+    checked = wrong = 0
+    for r, g_snap in zip(reqs, snapshots):
+        if not r.ok:
+            continue
+        checked += 1
+        ref, _ = reference.run(r.algo, g_snap, r.src)
+        wrong += not ALGEBRAS[r.algo].results_match(r.result, ref)
+    return checked, wrong
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="LRN",
@@ -614,22 +651,9 @@ def main():
     print(f"[serve] {args.dataset}: |V|={g.n} |E|={g.m} "
           f"algos={algos} B={args.batch}")
 
-    rng = np.random.default_rng(args.seed)
-    # interleave update batches at evenly spaced stream positions; track
-    # the graph version each query will be dispatched against so --check
-    # verifies every response against the right oracle snapshot
-    update_at = (set(np.linspace(1, args.requests - 1, args.updates,
-                                 dtype=int).tolist())
-                 if args.updates else set())
-    stream, snapshots, g_cur = [], [], g
-    for i in range(args.requests):
-        if i in update_at:
-            batch = _random_update_batch(g_cur, rng)
-            stream.append(("update", batch))
-            g_cur = g_cur.apply_updates(batch)
-        stream.append((algos[int(rng.integers(len(algos)))],
-                       int(rng.integers(g.n))))
-        snapshots.append(g_cur)
+    enable_compile_cache()
+    stream, snapshots = demo_stream(g, algos, args.requests, args.updates,
+                                    np.random.default_rng(args.seed))
 
     compact = {"auto": "auto", "on": True, "off": False}[args.compact]
     plan = ExecutionPlan(mode=args.mode, compact=compact, tile=args.tile,
@@ -684,19 +708,23 @@ def main():
               f"{srv.metrics.sum_counters('fallback.')} fallbacks")
     if args.stats:
         print(json.dumps(srv.stats(), indent=2, sort_keys=True))
+    problems = []
     if args.check:
-        bad = 0
-        checked = 0
-        for r, g_snap in zip(reqs, snapshots):
-            if not r.ok:
-                continue                 # typed failure, not a result
-            checked += 1
-            ref, _ = reference.run(r.algo, g_snap, r.src)
-            bad += not ALGEBRAS[r.algo].results_match(r.result, ref)
+        checked, bad = oracle_check(reqs, snapshots)
         print(f"[serve] oracle check: {checked - bad}/{checked} correct "
               f"({len(reqs) - checked} failed requests excluded)")
         if bad:
-            raise SystemExit(1)
+            problems.append(f"{bad} wrong results")
+    # injected faults are expected to fail or fall back; without them a
+    # failed request or a fired ladder rung is a fault of the system
+    fallbacks = srv.metrics.sum_counters("fallback.")
+    if not args.fault_rate:
+        if srv.failed:
+            problems.append(f"{srv.failed} failed requests")
+        if fallbacks:
+            problems.append(f"{fallbacks} degradation-ladder fallbacks")
+    if problems:
+        raise SystemExit(f"[serve] FAILED: {', '.join(problems)}")
 
 
 if __name__ == "__main__":

@@ -2,6 +2,7 @@
 xla_force_host_platform_device_count (the main test process keeps the real
 1-device platform per the assignment)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -17,6 +18,9 @@ from repro.distributed.sharding import (DEFAULT_RULES, logical_to_pspec,
                                         mesh_context, constrain)
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
 def _run_sub(code: str, devices: int = 8) -> str:
     prog = (f"import os\n"
             f"os.environ['XLA_FLAGS'] = "
@@ -25,11 +29,11 @@ def _run_sub(code: str, devices: int = 8) -> str:
     out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                          text=True, timeout=600,
                          env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                              "HOME": "/root",
+                              "HOME": os.environ.get("HOME", REPO),
                               # force the host platform: without this, jax
                               # backend discovery can block for minutes
                               # probing accelerators from the clean env
-                              "JAX_PLATFORMS": "cpu"}, cwd="/root/repo")
+                              "JAX_PLATFORMS": "cpu"}, cwd=REPO)
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
 
@@ -49,7 +53,8 @@ def test_pspec_rules_subprocess():
     import jax, json
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import logical_to_pspec, DEFAULT_RULES
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     checks = []
     # normal weight: embed->data, mlp->model
     s = logical_to_pspec((8, 16), ("embed", "mlp"), mesh)
@@ -125,10 +130,11 @@ def test_moe_all_to_all_matches_gspmd():
     from repro.distributed.sharding import mesh_context
     from repro.models import moe
     from repro.models.layers import init_tree
+    from repro.launch.mesh import make_mesh
     cfg = get_smoke("granite_moe_3b_a800m")
     p = init_tree(jax.random.PRNGKey(0), moe.decls(cfg), jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with mesh_context(mesh):
         y1, a1 = jax.jit(lambda p, x: moe.apply(p, x, cfg, "gspmd"))(p, x)
         y2, a2 = jax.jit(lambda p, x: moe.apply(p, x, cfg,
@@ -146,13 +152,13 @@ def test_compressed_psum_pod_axis():
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.compression import compressed_psum
-    mesh = jax.make_mesh((4,), ("pod",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("pod",))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(4, 64)),
                     jnp.float32)
-    @partial(shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
+             out_specs=P("pod"), check_vma=False)
     def f(xs):
         mean, fb = compressed_psum(xs[0], "pod")
         return mean[None]
@@ -175,6 +181,7 @@ def test_sharded_train_step_matches_single_device():
     from repro.models import model as M
     from repro.optim import adamw
     from repro.optim.adamw import AdamWConfig
+    from repro.launch.mesh import make_mesh
     cfg = get_smoke("qwen3_0_6b")
     opt_cfg = AdamWConfig()
     params = M.init_params(cfg, jax.random.PRNGKey(0))
@@ -186,7 +193,7 @@ def test_sharded_train_step_matches_single_device():
     # single device
     s1, m1 = jax.jit(step)(jax.tree_util.tree_map(lambda x: x, state), batch)
     # 8-device mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with mesh_context(mesh, DEFAULT_RULES):
         sh = S.train_state_shardings(cfg, mesh, opt_cfg)
         bsh = S.batch_shardings(

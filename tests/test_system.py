@@ -9,11 +9,16 @@ import numpy as np
 import pytest
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
 def _run(args, timeout=900, **kw):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # the CLIs keep a persistent compile cache; tests leave it off
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     return subprocess.run([sys.executable, "-m"] + args,
-                          capture_output=True, text=True, cwd="/root/repo",
+                          capture_output=True, text=True, cwd=REPO,
                           timeout=timeout, env=env, **kw)
 
 
