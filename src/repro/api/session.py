@@ -21,6 +21,7 @@ the resolved plan, per-query steps, and wall time attached.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 
 import numpy as np
@@ -30,6 +31,7 @@ from repro.api.program import Program
 from repro.core.engine import FlipEngine, WarmStart
 from repro.graphs.csr import Graph
 from repro.kernels.frontier.ops import UpdateDelta
+from repro.obs.spans import span
 from repro.obs.telemetry import QueryTelemetry
 from repro.resilience.errors import ConvergenceFailure, InvalidRequest
 
@@ -125,6 +127,10 @@ class CompiledQuery:
     # QueryResult.compile_s. Shared across update()-derived sessions
     # (value-only rebuilds keep the compiled executables hot).
     _dispatched: set = dataclasses.field(default_factory=set, repr=False)
+    # query ids for the profiler's spans (`flip.query`, `flip.dispatch`):
+    # one counter shared, like `_dispatched`, with derived sessions
+    _qids: itertools.count = dataclasses.field(
+        default_factory=itertools.count, repr=False)
 
     # -------------------------------------------------------------- #
     def query(self, srcs, *, warm=None, trace: bool | int = False,
@@ -162,7 +168,17 @@ class CompiledQuery:
         Every combination returns bit-for-bit the attrs a plain scratch
         scalar run would produce (budget-stopped queries excepted: they
         are flagged partials).
+
+        The call is the profiler span ``flip.query`` (attributes `qid`,
+        this session's query counter, and `batch`, the sources given);
+        docs/OBSERVABILITY.md lists the spans inside it.
         """
+        qid = next(self._qids)
+        with span("flip.query", qid=qid, batch=int(np.size(srcs))):
+            return self._query(qid, srcs, warm, trace, max_steps,
+                               deadline_s)
+
+    def _query(self, qid, srcs, warm, trace, max_steps, deadline_s):
         t0 = time.perf_counter()
         if trace and self.plan.distributed:
             raise ValueError(
@@ -203,7 +219,7 @@ class CompiledQuery:
         compile_s = 0.0
         if not batched or self.plan.batch == 0:
             det, wall, first = self._dispatch(srcs, ws, trace, budgets,
-                                              deadline_abs)
+                                              deadline_abs, qid=qid)
             out, steps = det.attrs, det.steps
             conv, expired = det.converged, det.deadline_expired
             dispatches = 1
@@ -218,7 +234,7 @@ class CompiledQuery:
             (out, steps, conv, expired, dispatches, teles, compile_s) = \
                 self._query_bucketed(
                     np.atleast_1d(np.asarray(srcs, dtype=np.int64)),
-                    ws, trace, budgets, deadline_abs)
+                    ws, trace, budgets, deadline_abs, qid)
         wall_s = time.perf_counter() - t0
         telemetry = None
         if trace:
@@ -313,11 +329,13 @@ class CompiledQuery:
                 f"{minimum}, got {finite[low][0]}", value=val)
         return np.broadcast_to(arr, (b,))
 
-    def _dispatch(self, srcs, ws, trace, budgets=None, deadline_abs=None):
+    def _dispatch(self, srcs, ws, trace, budgets=None, deadline_abs=None,
+                  qid=0, bucket=0):
         """One engine dispatch with compile-time attribution: returns
         ``(ExecutionDetail, wall_s, first)`` where `first` marks the
         first dispatch of this signature (its wall includes the
-        one-time jit trace)."""
+        one-time jit trace). The dispatch is the profiler span
+        ``flip.dispatch`` (`qid`, `bucket`, `first`)."""
         # tracing rides extra stat buffers through the fixpoint carry,
         # so traced and untraced runs are distinct executables
         sig = ("solo" if not np.ndim(srcs) else len(srcs),
@@ -326,10 +344,13 @@ class CompiledQuery:
         remaining = (None if deadline_abs is None
                      else np.asarray(deadline_abs) - time.monotonic())
         t0 = time.perf_counter()
-        det = self.engine.execute(
-            srcs, warm=ws, distributed=self.plan.distributed,
-            mesh=self.plan.mesh, axis=self.plan.mesh_axis, trace=trace,
-            max_steps=budgets, deadline_s=remaining, detail=True)
+        with span("flip.dispatch", qid=qid, bucket=bucket,
+                  first=int(first)):
+            det = self.engine.execute(
+                srcs, warm=ws, distributed=self.plan.distributed,
+                mesh=self.plan.mesh, axis=self.plan.mesh_axis,
+                trace=trace, max_steps=budgets, deadline_s=remaining,
+                detail=True)
         wall = time.perf_counter() - t0
         self._dispatched.add(sig)
         if det.telemetry is not None:
@@ -337,7 +358,7 @@ class CompiledQuery:
         return det, wall, first
 
     def _query_bucketed(self, srcs, ws, trace, budgets=None,
-                        deadline_abs=None):
+                        deadline_abs=None, qid=0):
         """plan.batch-sized dispatch: pad the tail bucket by repeating
         its last source (budgets and deadlines pad along with it) so
         every dispatch shares one (B, ntiles, T) executable, then drop
@@ -362,7 +383,7 @@ class CompiledQuery:
             w = self._slice_warm(ws, i, k, nb)
             det, wall, first = self._dispatch(
                 padded, w, trace, pad(budgets, i, k),
-                pad(deadline_abs, i, k))
+                pad(deadline_abs, i, k), qid=qid, bucket=i // nb)
             if first:
                 compile_s += wall
             if det.telemetry is not None:

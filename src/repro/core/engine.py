@@ -66,6 +66,7 @@ from repro.graphs.csr import Graph
 from repro.kernels.frontier.ops import (BlockedGraph, UpdateDelta,
                                         build_blocks, frontier_relax,
                                         resolve_relax_mode, tile_activity)
+from repro.obs.spans import span
 from repro.obs.telemetry import DispatchTelemetry, StepTrace
 from repro.resilience.errors import InvalidRequest
 
@@ -331,21 +332,20 @@ class FlipEngine:
 
         `trace_cap > 0` additionally records one per-step stats row into
         fixed-shape (trace_cap, ...) buffers riding the carry (see
-        `_step_stats`). Returns ``(attrs, aux, frontier, steps, trace,
-        converged, expired)`` where `trace` is a `(StepTrace, truncated)`
-        pair or None, `converged` is the (B,) bool end-of-run mask, and
-        `expired` marks deadline-stopped queries. The final frontier is
+        `_step_stats`). Returns ``(attrs, aux, frontier, steps,
+        read_trace, converged, expired)`` where `read_trace` is None, or
+        with `trace_cap` a function of no arguments that reads the stat
+        rows back and returns the `(StepTrace, truncated)` pair (left to
+        the caller, so the answer is read before the telemetry),
+        `converged` is the (B,) bool end-of-run mask, and `expired`
+        marks deadline-stopped queries. The final frontier is
         part of the return so a bounded-budget run is *resumable*: the
         continuous-batching scheduler (`repro.serving`) re-enters with
         the same state to run the next segment. The stat buffers are
         write-only extra outputs, so attrs and step counts are
         bit-identical either way."""
         b = attrs0.shape[0]
-        if budgets is None:
-            budgets = jnp.full((b,), self.max_steps, dtype=jnp.int32)
-        else:
-            budgets = jnp.asarray(np.broadcast_to(
-                np.asarray(budgets, dtype=np.int32), (b,)))
+        budgets = self._device_budgets(budgets, b)
         deadlined = (deadlines_t is not None
                      and bool(np.isfinite(deadlines_t).any()))
         if deadlined or (self._use_compact
@@ -354,24 +354,44 @@ class FlipEngine:
                                        budgets=budgets,
                                        deadlines_t=deadlines_t)
         bg = self.bg
-        out = self._dense_fixpoint_jit(trace_cap)(
-            (bg.blocks, bg.blocks_ext, bg.bsrc, bg.bdst), attrs0, aux0,
-            frontier0, budgets)
+        with span("flip.launch"):
+            out = self._dense_fixpoint_jit(trace_cap)(
+                (bg.blocks, bg.blocks_ext, bg.bsrc, bg.bdst), attrs0, aux0,
+                frontier0, budgets)
         attrs, aux, frontier, steps = out[0], out[1], out[2], out[3]
-        converged = ~np.asarray(frontier.any(axis=(1, 2)))
+        # the host's first read of the loop's output: it waits here for
+        # the device to finish the fixpoint
+        with span("flip.wait"):
+            converged = ~np.asarray(frontier.any(axis=(1, 2)))
         expired = np.zeros(b, dtype=bool)
-        if not trace_cap:
-            return attrs, aux, frontier, steps, None, converged, expired
-        n_iter = int(out[5])
+        read_trace = (functools.partial(self._read_trace, out[5], out[6],
+                                        trace_cap)
+                      if trace_cap else None)
+        return attrs, aux, frontier, steps, read_trace, converged, expired
+
+    def _device_budgets(self, budgets, b: int):
+        """(B,) i32 per-query step budgets on the device (default:
+        `max_steps` everywhere); such an array passes through."""
+        if budgets is None:
+            return jnp.full((b,), self.max_steps, dtype=jnp.int32)
+        if (isinstance(budgets, jax.Array) and budgets.shape == (b,)
+                and budgets.dtype == jnp.int32):
+            return budgets
+        return jnp.asarray(np.broadcast_to(
+            np.asarray(budgets, dtype=np.int32), (b,)))
+
+    def _read_trace(self, n_iter, bufs, trace_cap: int):
+        """The dense fixpoint's stat buffers read back to the host as
+        ``(StepTrace, truncated)``."""
+        n_iter = int(n_iter)
         rows = min(n_iter, trace_cap)
-        b_av, b_at, b_bf, b_cv = (np.asarray(x)[:rows] for x in out[6])
+        b_av, b_at, b_bf, b_cv = (np.asarray(x)[:rows] for x in bufs)
         nb = int(self.bg.bsrc.shape[0])
         trace = StepTrace(active_vertices=b_av, active_tiles=b_at,
                           blocks_fetched=b_bf,
                           blocks_skipped=np.int32(nb) - b_bf,
                           converged=b_cv)
-        return (attrs, aux, frontier, steps,
-                (trace, n_iter > trace_cap), converged, expired)
+        return trace, n_iter > trace_cap
 
     def _dense_fixpoint_jit(self, trace_cap: int):
         """The whole dense while_loop compiled as ONE jitted program per
@@ -423,8 +443,10 @@ class FlipEngine:
             return (attrs, aux, frontier, steps + live.astype(jnp.int32),
                     budgets, it + 1, bufs)
 
+        # the function's name names the program: `jit_flip_fixpoint` on
+        # the profiler's `XLA Modules` line
         @jax.jit
-        def run(layout, attrs0, aux0, frontier0, budgets):
+        def flip_fixpoint(layout, attrs0, aux0, frontier0, budgets):
             blocks, blocks_ext, bsrc, bdst = layout
             bg = dataclasses.replace(self.bg, blocks=blocks,
                                      blocks_ext=blocks_ext, bsrc=bsrc,
@@ -441,8 +463,8 @@ class FlipEngine:
             return jax.lax.while_loop(cond, functools.partial(body, bg),
                                       state0)
 
-        cache[trace_cap] = run
-        return run
+        cache[trace_cap] = flip_fixpoint
+        return flip_fixpoint
 
     def _fixpoint_host(self, attrs, aux, frontier, trace_cap: int = 0,
                        budgets=None, deadlines_t=None):
@@ -477,38 +499,47 @@ class FlipEngine:
         n_iter = 0
         t0 = time.perf_counter()
         while True:
-            # this concrete read is the loop's natural per-step sync: it
-            # also closes the previous traced step's wall measurement, so
-            # tracing adds no extra host<->device round trips
-            active = np.asarray(frontier.any(axis=(1, 2)))
-            if len(walls) < len(rows):
-                walls.append(time.perf_counter() - t0)
-            if deadlines is not None:
-                # a deadline only *expires* a query that still has work
-                # left: converged queries met their deadline by definition
-                expired |= active & (deadlines <= time.monotonic())
-            live = active & ~expired & (steps < budgets)
-            if not live.any():
-                break
-            t0 = time.perf_counter()
-            if trace_cap:
-                (attrs, aux, frontier), st = self._masked_step(
-                    attrs, aux, frontier, jnp.asarray(live),
-                    with_stats=True)
-                if n_iter < trace_cap:
-                    # stats stay on device until after the loop: only
-                    # the row tuple is kept per step
-                    av, at, bf = st
-                    rows.append((av, at, bf, ~live))
-            else:
-                attrs, aux, frontier = self._masked_step(
-                    attrs, aux, frontier, jnp.asarray(live))
-            steps = steps + live.astype(np.int32)
-            n_iter += 1
+            with span("flip.step", step=n_iter):
+                # this concrete read is the loop's natural per-step sync:
+                # it also closes the previous traced step's wall
+                # measurement, so tracing adds no extra host<->device
+                # round trips
+                active = np.asarray(frontier.any(axis=(1, 2)))
+                if len(walls) < len(rows):
+                    walls.append(time.perf_counter() - t0)
+                if deadlines is not None:
+                    # a deadline only *expires* a query that still has
+                    # work left: converged queries met their deadline by
+                    # definition
+                    expired |= active & (deadlines <= time.monotonic())
+                live = active & ~expired & (steps < budgets)
+                if not live.any():
+                    break
+                t0 = time.perf_counter()
+                if trace_cap:
+                    (attrs, aux, frontier), st = self._masked_step(
+                        attrs, aux, frontier, jnp.asarray(live),
+                        with_stats=True)
+                    if n_iter < trace_cap:
+                        # stats stay on device until after the loop:
+                        # only the row tuple is kept per step
+                        av, at, bf = st
+                        rows.append((av, at, bf, ~live))
+                else:
+                    attrs, aux, frontier = self._masked_step(
+                        attrs, aux, frontier, jnp.asarray(live))
+                steps = steps + live.astype(np.int32)
+                n_iter += 1
         converged = ~np.asarray(frontier.any(axis=(1, 2)))
-        if not trace_cap:
-            return (attrs, aux, frontier, jnp.asarray(steps), None,
-                    converged, expired)
+        read_trace = (functools.partial(self._host_trace, rows, walls, b,
+                                        n_iter > trace_cap)
+                      if trace_cap else None)
+        return (attrs, aux, frontier, jnp.asarray(steps), read_trace,
+                converged, expired)
+
+    def _host_trace(self, rows, walls, b: int, truncated: bool):
+        """The host fixpoint's per-step stat rows (device scalars until
+        now) read back as ``(StepTrace, truncated)``."""
         nb = int(self.bg.bsrc.shape[0])
         bf = np.asarray([int(r[2]) for r in rows], dtype=np.int32)
         trace = StepTrace(
@@ -522,8 +553,7 @@ class FlipEngine:
             converged=(np.stack([r[3] for r in rows]) if rows
                        else np.zeros((0, b), bool)),
             step_wall_s=np.asarray(walls, dtype=np.float64))
-        return (attrs, aux, frontier, jnp.asarray(steps),
-                (trace, n_iter > trace_cap), converged, expired)
+        return trace, truncated
 
     # -------------------------------------------------------------- #
     # the one plan-driven executor
@@ -672,17 +702,23 @@ class FlipEngine:
         """Local fixpoint over a (B,) source array; always batched.
         Returns ``(out, steps, DispatchTelemetry | None, converged,
         deadline_expired)`` -- the last two are (B,) bool masks."""
-        attrs0, aux0, frontier0 = self.initial_state(srcs, warm=warm)
+        with span("flip.prepare"):
+            attrs0, aux0, frontier0 = self.initial_state(srcs, warm=warm)
+            budgets = self._device_budgets(budgets, len(srcs))
         t0 = time.perf_counter()
-        attrs, aux, _, steps, rec, converged, expired = self._fixpoint(
-            attrs0, aux0, frontier0, trace_cap, budgets=budgets,
-            deadlines_t=deadlines_t)
-        out = self.bg.to_orig(self.algebra.finalize(attrs, aux),
-                              features=self._features)
-        steps = np.asarray(steps)
-        tele = None
-        if rec is not None:
-            trace, truncated = rec
+        attrs, aux, _, steps, read_trace, converged, expired = \
+            self._fixpoint(attrs0, aux0, frontier0, trace_cap,
+                           budgets=budgets, deadlines_t=deadlines_t)
+        with span("flip.finalize"):
+            out = self.bg.to_orig(self.algebra.finalize(attrs, aux),
+                                  features=self._features)
+            steps = np.asarray(steps)
+        if read_trace is None:
+            return out, steps, None, converged, expired
+        # what only traced dispatches pay: the stat rows' readback
+        with span("flip.telemetry") as sp:
+            trace, truncated = read_trace()
+            sp.set_metadata(rows=len(trace.active_tiles))
             tele = DispatchTelemetry(
                 backend=self._resolved_relax_mode(), mode=self.mode,
                 compact=self._use_compact, batch=int(steps.shape[0]),
