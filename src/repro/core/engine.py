@@ -65,7 +65,8 @@ from repro.core.mapping import Mapping
 from repro.graphs.csr import Graph
 from repro.kernels.frontier.ops import (BlockedGraph, UpdateDelta,
                                         build_blocks, frontier_relax,
-                                        resolve_relax_mode, tile_activity)
+                                        relax_grid, resolve_relax_mode,
+                                        tile_activity)
 from repro.obs.spans import span
 from repro.obs.telemetry import DispatchTelemetry, StepTrace
 from repro.resilience.errors import InvalidRequest
@@ -719,6 +720,8 @@ class FlipEngine:
         with span("flip.telemetry") as sp:
             trace, truncated = read_trace()
             sp.set_metadata(rows=len(trace.active_tiles))
+            path, grid_steps = relax_grid(self.bg, int(steps.shape[0]),
+                                          self.relax_mode, self.feature_dim)
             tele = DispatchTelemetry(
                 backend=self._resolved_relax_mode(), mode=self.mode,
                 compact=self._use_compact, batch=int(steps.shape[0]),
@@ -726,7 +729,8 @@ class FlipEngine:
                 n_blocks=int(self.bg.bsrc.shape[0]), steps=steps,
                 trace=trace, wall_s=time.perf_counter() - t0,
                 truncated=truncated, tile=self.bg.tile,
-                feature_dim=self.feature_dim)
+                feature_dim=self.feature_dim, relax_path=path,
+                relax_grid_steps=grid_steps)
         return out, steps, tele, converged, expired
 
     # -------------------------------------------------------------- #

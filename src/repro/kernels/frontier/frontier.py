@@ -1,91 +1,93 @@
 """Pallas TPU kernel: frontier-masked semiring relaxation, batched.
 
 TPU-native form of FLIP's data-centric PE array (DESIGN.md Sec. 2): graph
-vertices are tiled onto the 8x128 VPU lane grid; one grid step relaxes all
-edges between a source tile and a destination tile held as a dense weight
-block in VMEM (absent edge = the semiring's ⊕-identity). One kernel body
-serves every registered algebra: the merge ⊕, combine ⊗, and reduction
-are closed over as static ops, so each (semiring, tile) pair specializes
-to its own executable at trace time -- tropical (min,+) for BFS/SSSP/WCC,
+vertices are tiled onto the 8x128 VPU lane grid; one weight block holds
+all edges between a source tile and a destination tile as a dense (T, T)
+array (absent edge = the semiring's ⊕-identity). One kernel body serves
+every registered algebra: the merge ⊕, combine ⊗, and reduction are
+closed over as static ops, so each (semiring, tile) pair specializes to
+its own executable at trace time -- tropical (min,+) for BFS/SSSP/WCC,
 (max,min) for widest-path, (or,and) for reachability, (+,x) for
 delta-PageRank.
 
 The frontier bitmask plays FLIP's packet-trigger role: a block whose
-source tile holds only ⊕-identity lanes is skipped entirely (`pl.when`),
-so inactive regions cost (almost) no *compute* -- the kernel preserves
-the paper's "only active vertices scatter" property. Because the
-⊕-identity annihilates ⊗, skipping such a block is exact, not
-approximate.
-
-Compacted block streaming extends that skip to the *memory system*, where
-a memory-bound relax kernel actually spends its time: the block stream is
-indexed through a scalar-prefetched selection list ``bsel`` (see
-`ops.compact_block_stream`), whose active prefix names real blocks in
-(bdst, bsrc) order and whose inactive tail repeats one all-identity
-sentinel block index. The weight BlockSpec's index map reads ``bsel[i]``,
-so consecutive sentinel slots produce identical indices and the Pallas
-pipeline skips their copies -- the sentinel is fetched into VMEM once and
-every dead weight block stays in HBM. Per-step HBM traffic is therefore
-(active + 1)·T²·4 B instead of nb·T²·4 B; the sentinel slots still run
-the (T, T) VPU combine, but that compute is free under the memory bound.
-The dense path is the special case ``bsel = arange(nb)``.
+source tile holds only ⊕-identity lanes leaves a query's output
+untouched (a `pl.when` on the slab grid, a select on the grouped grid),
+per query -- the kernel preserves the paper's "only active vertices
+scatter" property. Because the ⊕-identity annihilates ⊗, skipping such
+a block is exact.
 
 Block-sparsity replaces the Inter-/Intra-Tables: `bsrc/bdst` (scalar-
-prefetched, so index maps can read them) name the tile pair of each block;
-position inside the block is the DRF register. Blocks are sorted by
-destination tile so a destination's partial ⊕ accumulates in VMEM across
-consecutive grid steps (revisit-friendly "arbitrary" dimension semantics);
-a compacted stream preserves that order because the compaction is stable.
+prefetched) name the tile pair of each block; position inside the block
+is the DRF register. Blocks are sorted by destination tile, so a
+destination's partial ⊕ accumulates in VMEM across consecutive slots.
 
-Batched execution (serving-style multi-query workloads): the state is
-(B, ntiles, T) -- B independent queries over one shared block structure --
-and the grid gains a trailing query dimension, grid = (nb, B). The weight
-block's index map ignores the query index, so each block is fetched into
-VMEM once and stays resident while all B queries relax against it (the
-whole point of batching: amortize the block stream over the batch). The
-output/carry specs cover the destination slab of all B queries and also
-ignore the query index, so every visit to one output slab is consecutive
-and the single-query accumulation semantics carry over unchanged. The
-packet trigger is per query: block i is skipped for query b exactly when
-that query's source tile holds only ⊕-identity lanes.
+Compacted block streaming extends the skip to the memory system: the
+block stream is indexed through a scalar-prefetched selection list
+``bsel`` (see `ops.compact_block_stream`), whose first ``n_active`` slots
+name the live blocks in (bdst, bsrc) order and whose tail repeats one
+all-identity sentinel block index. A stable compaction keeps the
+destination order. The dense stream is ``bsel = arange(nb)``,
+``n_active = nb``.
 
-Scalar state slabs: Mosaic only accepts blocks whose last two dims are
-multiples of (8, 128) or span the whole array, so a one-tile (1, T)
-block of the (B, ntiles, T) state is refused. The scalar state therefore
-moves in slabs of SLAB = 8 consecutive tiles -- (1, 8, T) source and
-(B, 8, T) carry/output blocks at tile index ``bsrc // 8`` / ``bdst // 8``
--- and the kernel reads and writes its tile's row inside the slab. The
-block list is (bdst, bsrc)-sorted, so the blocks of one destination slab
-are still consecutive; a slab is seeded from the carry on its first
-visit. A state whose tile count is not a multiple of 8 is padded with
-⊕-identity tiles inside the call. The alternative, a (B, ntiles, 1, T)
-view with (1, 1, 1, T) blocks, also compiles, but HBM tiles its (1, T)
-minor dims as (8, T); at T=256 on the 2^20-vertex graph (B=32) it costs
-a 128 MiB relayout copy per call, which the slabs do not.
+The state is (B, ntiles, T) -- B independent queries over one shared
+block structure -- or (B, ntiles, T, d) for vector-valued vertex state.
+Two grids run it; the shapes alone choose (`relax_path`):
 
-Vector-valued vertex state (feature_dim d > 1): the state blocks grow a
-trailing feature axis -- (B, ntiles, T, d) -- and one grid step becomes a
-(T, T) × (T, d) tile contraction via `Semiring.contract_jnp`: a true MXU
-matmul (`W.T @ sv`) for (+, ×), a d-slab-swept broadcast-⊕-reduce on the
-VPU for the tropical/boolean pairs. The weight block stays resident in
-VMEM while the B query visits spin against it, so each streamed block is
-amortized over B·d lanes instead of B -- the same HBM traffic now feeds
-d× the math, which is exactly the memory-bound regime's win.
+Grouped grid (scalar state whose source and output fit
+GROUPED_VMEM_BUDGET in VMEM). The source and output state are whole
+VMEM blocks with a constant index map, so they are copied in once and
+the output written back once per call; the carry stays in HBM and is
+copied into the output at the first step. The weight blocks stay in
+HBM (`pl.ANY`). Grid step g walks slots [g*GROUP, (g+1)*GROUP) below
+``n_active`` in order: it copies ``blocks[bsel[j]]`` by hand into a ring
+of WEIGHT_BUFFERS VMEM buffers, the copies of the next slots in flight
+while slot j relaxes against all B rows, so the grid has
+ceil(nslots / GROUP) steps instead of nslots * B and slots at or past
+``n_active`` (the sentinel tail) do no copy and no compute. The trigger
+is read from a scalar-prefetched bitmask (`_row_triggers`, one bit per
+row and source tile) and applied as a select, so the rows of a slot
+run without a branch between them; at B a multiple of 8 the source
+tile of 8 rows is moved onto the sublane axis by one transpose. Each
+row's candidate and its ⊕ into the output are the slab grid's, in the
+same slot order, so both grids give the same bits. A device of the
+distributed fixpoint, whose source state holds more tiles than its
+destination slab, takes this grid too.
 
-Layout: tile size T is a multiple of 128 (lane width). Per grid step
-the pipeline holds one buffer each of the source slab, the carry slab,
-the weight block and the output slab, and double-buffers them:
+Slab grid (d > 1, or a state over the budget): grid = (nslots, B), one
+(slot, query) pair per step. The weight block's index map reads
+``bsel[i]`` and ignores the query index, so each block is fetched once
+and stays resident while the B queries relax against it, and
+consecutive sentinel slots repeat one index, so the pipeline skips their
+copies (the sentinel slots still run the combine, an exact no-op).
+Mosaic only accepts blocks whose last two dims are multiples of
+(8, 128) or span the whole array, so scalar state moves in slabs of
+SLAB = 8 consecutive tiles -- (1, 8, T) source and (B, 8, T)
+carry/output blocks at tile index ``bsrc // 8`` / ``bdst // 8`` -- and
+the kernel reads and writes its tile's row inside the slab; a slab is
+seeded from the carry on its first visit, and a tile count that is not
+a multiple of 8 is padded with ⊕-identity tiles inside the call. At
+d > 1 the state blocks grow a trailing feature axis and one step becomes
+a (T, T) × (T, d) tile contraction via `Semiring.contract_jnp`: an MXU
+matmul (`W.T @ sv`) for (+, ×), a swept broadcast-⊕-reduce on the VPU
+for the tropical/boolean pairs, so each streamed block feeds B·d lanes.
 
-  d = 1:  2 * (8*T + B*8*T + T*T + B*8*T) * 4 B
-  d > 1:  2 * (T*d' + B*T*d' + T*T + B*T*d') * 4 B
-          + 8*T*d' * 4 B (min/max contraction transient)
+VMEM (T a multiple of 128, f32; d' = d rounded up to 128 lanes):
 
-where d' = d rounded up to 128 lanes (a (T, d) f32 slab is laid out in
-(8, 128) tiles). Examples: 648 KiB for T=128, B=32, d=1; 2.8 MiB for
-T=128, B=8, d=8; 1 MiB for d=128 solo -- all inside the 16 MiB scoped
-VMEM budget. In HBM the scalar state is (B, ntiles, T) f32 with no
-padding when ntiles is a multiple of 8. ops.py picks T; plan.resolve
-validates d.
+  grouped:  B * (ns' + ntiles') * T * 4 B + WEIGHT_BUFFERS * T*T * 4 B
+            (ns', ntiles' = tile counts rounded up to 8; one copy of
+            each, as the block index never changes)
+  slab d=1: 2 * (8*T + B*8*T + T*T + B*8*T) * 4 B
+  slab d>1: 2 * (T*d' + B*T*d' + T*T + B*T*d') * 4 B
+            + 8*T*d' * 4 B (min/max contraction transient)
+
+Examples: grouped 2.5 MiB at graph500-s15 (256 tiles, T=128) for B=8,
+8.5 MiB for the 2^20-vertex road graph solo (8192 tiles), 64 MiB budget
+(v5e has 128 MiB; the call raises its scoped limit to what it holds plus
+8 MiB); slab 648 KiB for T=128, B=32, d=1; 2.8 MiB for T=128, B=8,
+d=8; 1 MiB for d=128 solo -- inside the 16 MiB default scoped VMEM. In
+HBM the scalar state is (B, ntiles, T) f32. ops.py picks T;
+plan.resolve validates d.
 """
 from __future__ import annotations
 
@@ -178,6 +180,190 @@ def _make_relax_kernel(semiring: Semiring, feature_dim: int = 1,
     return _relax_kernel
 
 
+# Slots per grid step of the grouped path: one step walks GROUP
+# consecutive slots of the block stream.
+GROUP = 32
+# Weight blocks the grouped path keeps in flight: slot j waits for its
+# copy while the copies of slots j+1 .. j+WEIGHT_BUFFERS-1 run.
+WEIGHT_BUFFERS = 8
+# VMEM the grouped path may hold resident: the source and output state
+# plus the weight buffers. v5e has 128 MiB of VMEM; states above this
+# take the slab grid.
+GROUPED_VMEM_BUDGET = 64 << 20
+# scoped VMEM granted above the resident bytes, for Mosaic's own scratch
+# and the (T, T) combine transient of one row
+_VMEM_HEADROOM = 8 << 20
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def grouped_vmem_bytes(batch: int, ns: int, ntiles: int, t: int) -> int:
+    """VMEM the grouped path holds at state (B, ns | ntiles, T): one copy
+    each of the source and output state (their block index never
+    changes, so the pipeline keeps a single buffer), laid out in
+    (8, 128) f32 tiles, plus WEIGHT_BUFFERS (T, T) weight buffers."""
+    lanes = _round_up(t, 128)
+    state = batch * (_round_up(ns, 8) + _round_up(ntiles, 8)) * lanes * 4
+    return state + WEIGHT_BUFFERS * _round_up(t, 8) * lanes * 4
+
+
+def relax_path(batch: int, ns: int, ntiles: int, t: int,
+               feature_dim: int = 1) -> str:
+    """Which grid a relax call at these shapes runs: 'grouped' (GROUP
+    slots of the stream per grid step, all B rows, state resident in
+    VMEM) for scalar state that fits GROUPED_VMEM_BUDGET, else 'slab'
+    (one (slot, row) pair per grid step). Shapes alone decide."""
+    if (feature_dim == 1
+            and grouped_vmem_bytes(batch, ns, ntiles, t)
+            <= GROUPED_VMEM_BUDGET):
+        return "grouped"
+    return "slab"
+
+
+def relax_grid_steps(path: str, nslots: int, batch: int) -> int:
+    """Grid steps of one relax call: ceil(nslots / GROUP) on the grouped
+    path (dead groups run an empty step), nslots * B on the slab grid."""
+    return -(-nslots // GROUP) if path == "grouped" else nslots * batch
+
+
+def _row_triggers(src_vals, zero) -> jnp.ndarray:
+    """(B, ns, T) source state -> (words * ns,) i32 trigger bitmask, words
+    = ceil(B / 32): bit b % 32 of entry (b // 32) * ns + s is set iff row
+    b's source tile s holds a lane other than the ⊕-identity -- the
+    packet-trigger condition, read by the kernel as a scalar."""
+    batch, ns, _ = src_vals.shape
+    words = -(-batch // 32)
+    act = jnp.any(src_vals != zero, axis=-1).astype(jnp.int32)   # (B, ns)
+    act = jnp.pad(act, ((0, words * 32 - batch), (0, 0)))
+    shift = jnp.arange(32, dtype=jnp.int32)[None, :, None]
+    return jnp.sum(act.reshape(words, 32, ns) << shift,
+                   axis=1).reshape(words * ns)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_grouped_kernel(semiring: Semiring, batch: int, group: int):
+    """Kernel body of the grouped path: grid step g relaxes slots
+    [g*group, min((g+1)*group, n_active)) of the block stream, each
+    against all B rows of the VMEM-resident source state, into the
+    VMEM-resident output. Weight blocks stay in HBM and are copied in by
+    hand through a ring of WEIGHT_BUFFERS buffers, the copies of the
+    next slots in flight while slot j relaxes, across group boundaries
+    too."""
+    add, mul = semiring.add_jnp, semiring.mul_jnp
+    add_reduce = semiring.add_reduce_jnp
+    ahead = WEIGHT_BUFFERS - 1
+
+    def _grouped_relax_kernel(nact_ref, bsrc_ref, bdst_ref, bsel_ref,
+                              trig_ref, src_ref, carry_hbm, blocks_hbm,
+                              out_ref, wbuf, wsem, csem):
+        g = pl.program_id(0)
+        n = nact_ref[0]
+        ns = src_ref.shape[1]
+
+        def fetch(j):
+            k = j % WEIGHT_BUFFERS
+            return pltpu.make_async_copy(blocks_hbm.at[bsel_ref[j]],
+                                         wbuf.at[k], wsem.at[k])
+
+        # seed the resident output with the carry (current attrs for
+        # monotone algebras, the un-absorbed residual for delta-PR) and
+        # put the first blocks in flight
+        @pl.when(g == 0)
+        def _seed():
+            seed = pltpu.make_async_copy(carry_hbm, out_ref, csem)
+            seed.start()
+            for j in range(ahead):
+                pl.when(j < n)(lambda j=j: fetch(j).start())
+            seed.wait()
+
+        def slot(j, carry):
+            pl.when(j + ahead < n)(lambda: fetch(j + ahead).start())
+            fetch(j).wait()
+            w = wbuf.at[j % WEIGHT_BUFFERS]
+            s, d = bsrc_ref[j], bdst_ref[j]
+
+            def merge(b, cand):                         # cand: (1, T)
+                cur = out_ref[b, pl.ds(d, 1)]
+                # FLIP trigger rule, per query: the block leaves row b
+                # untouched if none of its sources is active. A select on
+                # the scalar trigger, not a branch: rows without a branch
+                # between them interleave, which on a v5e costs less than
+                # the compute a branch would skip
+                fire = (trig_ref[(b // 32) * ns + s] >> (b % 32)) & 1
+                out_ref[b, pl.ds(d, 1)] = jnp.where(
+                    fire != 0, add(cur, cand), cur)
+
+            if batch % SLAB == 0:
+                # one (8, T) -> (T, 8) transpose puts the source tile of 8
+                # rows on the sublane axis at once; below 8 rows the
+                # per-row (1, T) -> (T, 1) relayout costs less
+                def rows8(c, carry):
+                    b0 = pl.multiple_of(c * SLAB, SLAB)
+                    cols = src_ref[pl.ds(b0, SLAB), s, :].T  # (T, 8)
+                    for r in range(SLAB):
+                        merge(b0 + r, add_reduce(
+                            mul(cols[:, r:r + 1], w[...]), axis=0)[None])
+                    return carry
+
+                return jax.lax.fori_loop(0, batch // SLAB, rows8, carry)
+
+            def row(b, carry):
+                src = src_ref[b, pl.ds(s, 1)]               # (1, T)
+                merge(b, add_reduce(mul(src[0][:, None], w[...]),
+                                    axis=0)[None])
+                return carry
+
+            return jax.lax.fori_loop(0, batch, row, carry,
+                                     unroll=batch <= 8)
+
+        base = g * group
+        jax.lax.fori_loop(base, jnp.minimum(base + group, n), slot, 0)
+
+    return _grouped_relax_kernel
+
+
+def _relax_grouped(src_vals, carry, blocks, bsrc, bdst, bsel, n_active,
+                   semiring, interpret, group=GROUP):
+    """The grouped grid: ceil(nslots / group) steps over (B, ns, T)
+    source and (B, ntiles, T) carry, both whole in VMEM."""
+    batch, ns, t = src_vals.shape
+    ntiles = carry.shape[1]
+    nslots = bsrc.shape[0]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(-(-nslots // group),),
+        in_specs=[
+            pl.BlockSpec((batch, ns, t), lambda g, *_: (0, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),              # carry
+            pl.BlockSpec(memory_space=pl.ANY),              # blocks
+        ],
+        out_specs=pl.BlockSpec((batch, ntiles, t), lambda g, *_: (0, 0, 0),
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((WEIGHT_BUFFERS, t, t), jnp.float32),
+                        pltpu.SemaphoreType.DMA((WEIGHT_BUFFERS,)),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    kwargs = {}
+    if interpret:
+        kwargs["interpret"] = interpret
+    else:
+        need = grouped_vmem_bytes(batch, ns, ntiles, t)
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=need + _VMEM_HEADROOM)
+    return pl.pallas_call(
+        _make_grouped_kernel(semiring, batch, group),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(carry.shape, jnp.float32),
+        input_output_aliases={6: 0},   # alias carry -> out
+        **kwargs,
+    )(jnp.reshape(n_active, (1,)).astype(jnp.int32), bsrc, bdst, bsel,
+      _row_triggers(src_vals, semiring.zero), src_vals, carry, blocks)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("semiring", "interpret", "feature_dim"))
 def frontier_relax_pallas(src_vals: jnp.ndarray,  # (B?, ns, T[, d]) f32
@@ -186,9 +372,11 @@ def frontier_relax_pallas(src_vals: jnp.ndarray,  # (B?, ns, T[, d]) f32
                           bsrc: jnp.ndarray,      # (nslots,) i32, sorted by
                           bdst: jnp.ndarray,      # (nslots,) i32 (bdst, bsrc)
                           semiring: Semiring = MIN_PLUS,
-                          interpret: bool = False,
+                          interpret=False,
                           bsel: jnp.ndarray | None = None,
-                          feature_dim: int = 1) -> jnp.ndarray:
+                          feature_dim: int = 1,
+                          n_active: jnp.ndarray | None = None
+                          ) -> jnp.ndarray:
     """One relaxation step: new[b, d] = carry[b, d] ⊕ (⊕_s sv[b, s] ⊗ W[s, d]).
 
     `src_vals`/`carry` are (ntiles, T) for one query or (B, ntiles, T) for
@@ -212,7 +400,14 @@ def frontier_relax_pallas(src_vals: jnp.ndarray,  # (B?, ns, T[, d]) f32
     indirection: grid slot i fetches ``blocks[bsel[i]]``. Dense streaming
     is ``bsel = None`` (identity). Compacted streaming passes the output
     of `ops.compact_block_stream` together with the sentinel-extended
-    block array and the compacted `bsrc`/`bdst` slot coordinates.
+    block array and the compacted `bsrc`/`bdst` slot coordinates, and
+    its traced active count `n_active`: the grouped grid relaxes only
+    slots below it (the default, None, is every slot).
+
+    The grid is chosen from the shapes (`relax_path`; module docstring).
+    `interpret` is False on the chip, True for the Pallas interpreter,
+    or a `pltpu.InterpretParams` for the TPU interpreter, which also
+    runs the grouped grid's manual copies.
     """
     features = feature_dim > 1
     if features and src_vals.shape[-1] != feature_dim:
@@ -230,6 +425,25 @@ def frontier_relax_pallas(src_vals: jnp.ndarray,  # (B?, ns, T[, d]) f32
     nslots = bsrc.shape[0]
     if bsel is None:
         bsel = jnp.arange(nslots, dtype=jnp.int32)
+    batch, ntiles = carry.shape[0], carry.shape[1]
+    if relax_path(batch, src_vals.shape[1], ntiles, t,
+                  feature_dim) == "grouped":
+        out = _relax_grouped(src_vals, carry, blocks, bsrc, bdst, bsel,
+                             nslots if n_active is None else n_active,
+                             semiring, interpret)
+    else:
+        out = _relax_slab(src_vals, carry, blocks, bsrc, bdst, bsel,
+                          semiring, interpret, feature_dim)
+    return out[0] if squeeze else out
+
+
+def _relax_slab(src_vals, carry, blocks, bsrc, bdst, bsel, semiring,
+                interpret, feature_dim):
+    """The slab grid: (nslots, B) steps, one (slot, row) pair each, over
+    R-tile slabs of the (B, ns | ntiles, T[, d]) state."""
+    features = feature_dim > 1
+    t = blocks.shape[-1]
+    nslots = bsrc.shape[0]
     batch, ntiles = carry.shape[0], carry.shape[1]
     # state slab: R tiles per block (one at d > 1, whose (T, d) minor
     # axes already span the whole array); tile counts are padded up to a
@@ -276,5 +490,4 @@ def frontier_relax_pallas(src_vals: jnp.ndarray,  # (B?, ns, T[, d]) f32
         interpret=interpret,           # keep their carry values
         **kwargs,
     )(bsrc, bdst, bsel, src_vals, carry, blocks)
-    out = out[:, :ntiles]
-    return out[0] if squeeze else out
+    return out[:, :ntiles]

@@ -47,7 +47,8 @@ import numpy as np
 
 from repro.algebra import MIN_PLUS, Semiring, VertexAlgebra, get_algebra
 from repro.graphs.csr import Graph
-from repro.kernels.frontier.frontier import frontier_relax_pallas
+from repro.kernels.frontier.frontier import (frontier_relax_pallas,
+                                             relax_grid_steps, relax_path)
 
 
 @dataclasses.dataclass
@@ -524,6 +525,18 @@ def resolve_relax_mode(mode: str) -> str:
     return mode
 
 
+def relax_grid(bg: BlockedGraph, batch: int, mode: str = "auto",
+               feature_dim: int = 1) -> tuple[str, int]:
+    """``(path, grid steps per relax step)`` that `frontier_relax` takes
+    for a (B, ntiles, T[, d]) state over `bg`, from static shapes alone:
+    the Pallas kernel's 'grouped' or 'slab' grid, or ('jnp', 0) off the
+    Pallas paths."""
+    if resolve_relax_mode(mode) == "jnp":
+        return "jnp", 0
+    path = relax_path(batch, bg.ntiles, bg.ntiles, bg.tile, feature_dim)
+    return path, relax_grid_steps(path, int(bg.bsrc.shape[0]), batch)
+
+
 def frontier_relax(src_vals, carry, bg: BlockedGraph, mode: str = "auto",
                    compact: bool = False, feature_dim: int = 1):
     """One frontier relaxation step over a BlockedGraph.
@@ -575,8 +588,9 @@ def frontier_relax(src_vals, carry, bg: BlockedGraph, mode: str = "auto",
                                      bg.bdst, semiring=sr,
                                      interpret=interpret,
                                      feature_dim=feature_dim)
-    bsel, bsrc_c, bdst_c, _ = compact_block_stream(
+    bsel, bsrc_c, bdst_c, n_active = compact_block_stream(
         tile_activity(src_vals, sr, features), bg.bsrc, bg.bdst)
     return frontier_relax_pallas(src_vals, carry, bg.blocks_ext, bsrc_c,
                                  bdst_c, semiring=sr, interpret=interpret,
-                                 bsel=bsel, feature_dim=feature_dim)
+                                 bsel=bsel, feature_dim=feature_dim,
+                                 n_active=n_active)

@@ -84,6 +84,8 @@ class DispatchTelemetry:
     truncated: bool = False   # fixpoint outran the trace row capacity
     tile: int = 0           # T (0 when unknown, e.g. the sim bridge)
     feature_dim: int = 1    # feature width d of the vertex state
+    relax_path: str = ""    # relax kernel grid: 'grouped' | 'slab' | 'jnp'
+    relax_grid_steps: int = 0   # kernel grid steps per relax step
     meta: dict = dataclasses.field(default_factory=dict)
 
     def summary(self) -> dict:
@@ -106,6 +108,8 @@ class DispatchTelemetry:
             "compact": self.compact,
             "batch": self.batch,
             "feature_dim": d,
+            "relax_path": self.relax_path,
+            "relax_grid_steps": self.relax_grid_steps,
             "steps_max": int(self.steps.max()) if self.steps.size else 0,
             "steps_mean": float(self.steps.mean()) if self.steps.size
             else 0.0,
@@ -131,6 +135,8 @@ class DispatchTelemetry:
             "n": self.n, "ntiles": self.ntiles,
             "n_blocks": self.n_blocks, "tile": self.tile,
             "feature_dim": self.feature_dim,
+            "relax_path": self.relax_path,
+            "relax_grid_steps": self.relax_grid_steps,
             "steps": [int(s) for s in np.atleast_1d(self.steps)],
             "wall_s": self.wall_s, "truncated": self.truncated,
             "meta": self.meta, "trace": self.trace.to_json(),

@@ -12,11 +12,16 @@ whether its tests exist would hand pytest-xdist workers different test
 lists. All such compiles live in this one file.
 
 Shapes are the real ones: ExtLRN (16,384 vertices: 128 tiles, 382
-blocks at T=128) and the 2^20-vertex road network (8192 tiles, ~35k
-blocks).
+blocks at T=128), the 2^20-vertex road network (8192 tiles, ~35k
+blocks) and the benchmark's graph500-s15 (256 tiles, 65,448 blocks).
+Scalar states that fit the VMEM budget compile the grouped grid, the
+others and every d > 1 state the slab grid; the grouped cases also
+check the scoped VMEM Mosaic reports against the budget.
 """
 import functools
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,11 +29,15 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.algebra import MAX_MIN, MIN_PLUS, OR_AND, PLUS_TIMES
-from repro.kernels.frontier.frontier import frontier_relax_pallas
+from repro.kernels.frontier.frontier import (GROUPED_VMEM_BUDGET,
+                                             _VMEM_HEADROOM,
+                                             frontier_relax_pallas,
+                                             grouped_vmem_bytes, relax_path)
 
 T = 128
 EXT_LRN = (128, 382)          # (ntiles, blocks) of make_dataset("ExtLRN")
 ROAD_1M = (8192, 34990)       # make_road_network(1 << 20, delete_frac=0.56)
+GRAPH500_S15 = (256, 65448)   # bench/configs/graph500-s15.json
 
 
 @pytest.fixture(scope="module")
@@ -50,17 +59,18 @@ def one_chip(topo):
 def _compile(one_chip, semiring, state, nblocks, nslots, compact=False,
              feature_dim=1):
     """Lower + compile one relax call for the described chip; returns the
-    compiled executable's HLO text."""
+    compiled executable's HLO text. `compact` passes the selection list
+    and the traced active count, as `ops.frontier_relax` does."""
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     idx = sds((nslots,), jnp.int32)
     args = [sds(state), sds(state), sds((nblocks, T, T)), idx, idx]
     if compact:
-        args.append(idx)
-        fn = jax.jit(lambda sv, c, bl, bs, bd, sel: frontier_relax_pallas(
+        args += [idx, sds((), jnp.int32)]
+        fn = jax.jit(lambda sv, c, bl, bs, bd, sel, n: frontier_relax_pallas(
             sv, c, bl, bs, bd, semiring=semiring, bsel=sel,
-            feature_dim=feature_dim))
+            feature_dim=feature_dim, n_active=n))
     else:
         fn = jax.jit(functools.partial(frontier_relax_pallas,
                                        semiring=semiring,
@@ -96,9 +106,42 @@ def test_scalar_compacted(one_chip, shape):
 
 
 def test_scalar_tile_count_not_multiple_of_slab(one_chip):
-    """ntiles not a multiple of the 8-tile slab pads inside the call."""
+    """ntiles not a multiple of the 8-tile slab: the grouped grid holds
+    the state whole, and the slab grid (a state over the VMEM budget)
+    pads it inside the call."""
+    assert relax_path(4, 13, 13, T) == "grouped"
     assert "tpu_custom_call" in _compile(one_chip, MIN_PLUS, (4, 13, T),
                                          40, 40)
+    assert relax_path(32, 8189, 8189, T) == "slab"
+    assert "tpu_custom_call" in _compile(one_chip, MIN_PLUS,
+                                         (32, 8189, T), 40, 40)
+
+
+def _used_vmem(hlo: str) -> int:
+    """Scoped VMEM (memory space 1) the compiled kernel uses, from the
+    custom call's backend config."""
+    line = next(ln for ln in hlo.splitlines() if "tpu_custom_call" in ln)
+    cfg = json.loads(re.search(r"backend_config=(\{.*\})", line).group(1))
+    return sum(int(c["size"]) for c in cfg["used_scoped_memory_configs"]
+               if c["memory_space"] == "1")
+
+
+@pytest.mark.parametrize("batch", [1, 8])
+@pytest.mark.parametrize("semiring", [MIN_PLUS, PLUS_TIMES],
+                         ids=lambda s: s.name)
+def test_grouped_graph500_s15(one_chip, semiring, batch):
+    """The benchmark's shape on the grouped grid, compacted stream: Mosaic
+    takes the resident state and the manual weight copies, within the
+    scoped VMEM the call grants."""
+    ntiles, nb = GRAPH500_S15
+    assert relax_path(batch, ntiles, ntiles, T) == "grouped"
+    state = (batch, ntiles, T) if batch > 1 else (ntiles, T)
+    hlo = _compile(one_chip, semiring, state, nb + 1, nb, compact=True)
+    need = grouped_vmem_bytes(batch, ntiles, ntiles, T)
+    assert need <= GROUPED_VMEM_BUDGET
+    # Mosaic's own transients fit the headroom the call grants above the
+    # resident bytes
+    assert 0 < _used_vmem(hlo) <= need + _VMEM_HEADROOM
 
 
 @pytest.mark.parametrize("semiring", [MIN_PLUS, PLUS_TIMES],
