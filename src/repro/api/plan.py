@@ -21,9 +21,12 @@ import dataclasses
 import warnings
 
 import jax
+import numpy as np
+from jax.sharding import Mesh
 
 from repro.algebra import VertexAlgebra
-from repro.kernels.frontier.ops import resolve_relax_mode
+from repro.kernels.frontier.ops import (BlockKeys, mesh_key,
+                                        resolve_relax_mode)
 
 MODES = ("data", "op")
 RELAX_MODES = ("auto", "pallas", "interpret", "jnp")
@@ -48,6 +51,8 @@ class ExecutionPlan:
                    (B, ntiles, T) executable (the GraphServer policy).
     distributed -- run the shard_map fixpoint (destination tiles
                    sharded over `mesh_axis`, queries replicated).
+                   `flip.compile` also chooses it when the graph's
+                   blocks do not fit one device (`place`).
     mesh        -- jax Mesh for distributed runs (None = all local
                    devices); supplying a mesh implies distributed=True.
     mesh_axis   -- mesh axis name the tiles shard over.
@@ -199,15 +204,62 @@ class ExecutionPlan:
         plan.validate(algebra)
         return plan
 
+    def place(self, keys: BlockKeys,
+              algebra: VertexAlgebra | None = None) -> "ExecutionPlan":
+        """This resolved plan with the layout of `keys` placed: an
+        explicit distributed plan or mesh keeps the sharded layout (over
+        all local devices when no mesh is given); otherwise the layout
+        stays on one device where it fits its memory, and is sharded
+        over all local devices where only that fits (`layout_devices`).
+        Decided from the key count alone, before anything is allocated;
+        the returned plan records the mesh."""
+        devices = jax.devices()
+        if not self.distributed and layout_devices(
+                keys.device_bytes(), keys.shard_bytes(len(devices)),
+                device_bytes_limit(), len(devices)) == 1:
+            return self
+        mesh = (self.mesh if self.mesh is not None
+                else Mesh(np.array(devices), (self.mesh_axis,)))
+        plan = dataclasses.replace(self, distributed=True, mesh=mesh)
+        plan.validate(algebra)
+        return plan
+
     def key(self) -> tuple:
         """Hashable cache key (session caches key on fingerprint+plan).
-        The mesh participates by identity: two plans over different mesh
-        objects compile different executables."""
+        The mesh participates by its devices and axis names, so one
+        session's key never changes between calls."""
         return (self.mode, self.relax_mode, self.compact, self.tile,
                 self.batch, self.distributed,
-                None if self.mesh is None else id(self.mesh),
+                None if self.mesh is None
+                else mesh_key(self.mesh, self.mesh_axis),
                 self.mesh_axis, self.warm, self.feature_dim,
                 self.max_steps, self.deadline_s, self.tuned)
+
+
+def device_bytes_limit() -> int | None:
+    """The first device's memory limit (`memory_stats()["bytes_limit"]`),
+    or None where the backend reports none (the CPU)."""
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def layout_devices(local_bytes: int, shard_bytes: int, limit: int | None,
+                   ndev: int) -> int:
+    """How many devices the default plan lays a graph's blocks over:
+    1 where the one-device layout (`local_bytes`, both block copies)
+    fits `limit` or no limit is known; `ndev` where it does not, more
+    devices exist, and the fullest device of the sharded layout
+    (`shard_bytes`) fits. Raises ValueError naming the sizes where
+    neither fits."""
+    if limit is None or local_bytes <= limit:
+        return 1
+    if ndev > 1 and shard_bytes <= limit:
+        return ndev
+    raise ValueError(
+        f"the graph's block layout does not fit: {local_bytes:,} B on "
+        f"one device" + (f", {shard_bytes:,} B on the fullest of {ndev} "
+                         "devices sharded" if ndev > 1 else "")
+        + f", against {limit:,} B of device memory")
 
 
 # ------------------------------------------------------------------ #

@@ -28,9 +28,9 @@ import numpy as np
 
 from repro.api.plan import ExecutionPlan
 from repro.api.program import Program
-from repro.core.engine import FlipEngine, WarmStart
+from repro.core.engine import FlipEngine, WarmStart, mapping_order
 from repro.graphs.csr import Graph
-from repro.kernels.frontier.ops import UpdateDelta
+from repro.kernels.frontier.ops import UpdateDelta, block_keys
 from repro.obs.spans import span
 from repro.obs.telemetry import QueryTelemetry
 from repro.resilience.errors import ConvergenceFailure, InvalidRequest
@@ -180,10 +180,6 @@ class CompiledQuery:
 
     def _query(self, qid, srcs, warm, trace, max_steps, deadline_s):
         t0 = time.perf_counter()
-        if trace and self.plan.distributed:
-            raise ValueError(
-                "query(trace=...) is not supported on a distributed "
-                "plan yet; trace on a local plan")
         self._validate_srcs(srcs)
         if deadline_s is None:
             deadline_s = self.plan.deadline_s
@@ -501,7 +497,11 @@ def compile(graph: Graph, program, plan: ExecutionPlan | None = None, *,
                `VertexAlgebra`, or a `Program`.
     plan    -- an `ExecutionPlan` (default `ExecutionPlan.auto()`);
                validated and resolved here, so every knob conflict
-               fails at compile time. With ``plan.tuned`` set (e.g.
+               fails at compile time. The block layout goes on one
+               device where it fits that device's memory, else it is
+               sharded over every local device (`ExecutionPlan.place`):
+               the resolved plan then reads distributed, with its
+               mesh. With ``plan.tuned`` set (e.g.
                `ExecutionPlan.auto(tuned=True)`), the plan autotuner
                picks the performance knobs for this (graph, program,
                backend) -- consulting the tuning store first, so
@@ -527,11 +527,15 @@ def compile(graph: Graph, program, plan: ExecutionPlan | None = None, *,
         rplan, tune = resolve_tuned(graph, prog, plan, store=store)
     else:
         rplan = plan.resolve(prog.algebra)
-    engine = FlipEngine.build(graph, prog.algebra, mapping=mapping,
-                              tile=rplan.tile, mode=rplan.mode,
-                              relax_mode=rplan.relax_mode,
-                              compact=rplan.compact,
-                              feature_dim=rplan.feature_dim)
+    order = mapping_order(mapping) if mapping is not None else None
+    keys = block_keys(graph, prog.algebra, tile=rplan.tile, order=order)
+    rplan = rplan.place(keys, prog.algebra)
+    bg = keys.build(mesh=rplan.mesh if rplan.distributed else None,
+                    axis=rplan.mesh_axis)
+    engine = FlipEngine.over(bg, mode=rplan.mode,
+                             relax_mode=rplan.relax_mode,
+                             compact=rplan.compact,
+                             feature_dim=rplan.feature_dim)
     engine = dataclasses.replace(engine, max_steps=rplan.max_steps)
     return CompiledQuery(graph=graph, program=prog, plan=rplan,
                          engine=engine, tune=tune)

@@ -35,18 +35,22 @@ Execution is batched over independent queries: the state is
 inside one `jax.lax.while_loop` fixpoint. `FlipEngine.execute` is the
 single entry point (scalar source = the B=1 view; `distributed=True`
 switches to the shard_map fixpoint; `warm=` resumes a prior result) --
-the legacy `run`/`run_batch`/`run_distributed`/`run_updated` methods are
-deprecated shims over it, and `repro.api` ( `flip.compile(graph,
+the legacy `run`/`run_batch`/`run_updated` methods are deprecated shims
+over it, and `repro.api` ( `flip.compile(graph,
 program, plan).query(srcs)` ) is the intended front door. Queries whose
 frontier has emptied are frozen by a per-query convergence mask, so a
 long-tail query never perturbs finished ones and batched results are
 bit-for-bit the per-source results.
 
-Both paths can execute distributed via `shard_map`: destination tiles
-are partitioned over a mesh axis (devices = PE clusters), queries stay
-replicated, each device relaxes its local blocks, and the updated
-attribute vector is re-assembled with an all-gather -- the collective is
-the NoC, and its cost amortizes over the whole batch.
+Both paths can execute distributed via `shard_map` over a sharded
+layout (`repro.kernels.frontier.ops`): destination tiles are partitioned
+over a mesh axis (devices = PE clusters), each device holds only the
+blocks that write its tiles, queries stay replicated, each device
+relaxes its blocks through the same compacted relax as the local step,
+and the updated attribute vector is re-assembled with an all-gather --
+the collective is the NoC, and its cost amortizes over the whole batch.
+The loop is the local one (`_dense_fixpoint_jit`), compiled once per
+mesh; the gather is the only difference.
 """
 from __future__ import annotations
 
@@ -58,13 +62,14 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.algebra import VertexAlgebra
 from repro.core.mapping import Mapping
 from repro.graphs.csr import Graph
 from repro.kernels.frontier.ops import (BlockedGraph, UpdateDelta,
-                                        build_blocks, frontier_relax,
+                                        block_activity, build_blocks,
+                                        frontier_relax, mesh_key,
                                         relax_grid, resolve_relax_mode,
                                         tile_activity)
 from repro.obs.spans import span
@@ -117,6 +122,14 @@ class ExecutionDetail:
     telemetry: DispatchTelemetry | None = None
 
 
+def _layout_args(bg: BlockedGraph) -> tuple:
+    """A layout's arrays as the fixpoint program takes them: a sharded
+    layout holds its slabs once and marks its live slots."""
+    if bg.shards is None:
+        return (bg.blocks, bg.blocks_ext, bg.bsrc, bg.bdst)
+    return (bg.blocks, bg.bsrc, bg.bdst, bg.live)
+
+
 def mapping_order(mapping: Mapping) -> np.ndarray:
     """Vertex ordering induced by the FLIP placement: vertices co-located
     on a (copy, PE) become adjacent tile positions, so the compiled
@@ -150,7 +163,16 @@ class FlipEngine:
               compact: bool | str = "auto",
               feature_dim: int | None = None) -> "FlipEngine":
         order = mapping_order(mapping) if mapping is not None else None
-        bg = build_blocks(graph, algo=algo, tile=tile, order=order)
+        return FlipEngine.over(
+            build_blocks(graph, algo=algo, tile=tile, order=order),
+            mode=mode, relax_mode=relax_mode, compact=compact,
+            feature_dim=feature_dim)
+
+    @staticmethod
+    def over(bg: BlockedGraph, mode: str = "data",
+             relax_mode: str = "auto", compact: bool | str = "auto",
+             feature_dim: int | None = None) -> "FlipEngine":
+        """An engine over a built layout, local or sharded."""
         d = bg.algebra.feature_dim if feature_dim is None else feature_dim
         if bg.algebra.feature_dim > 1 and d != bg.algebra.feature_dim:
             raise ValueError(
@@ -241,15 +263,32 @@ class FlipEngine:
         sv, carry = alg.scatter_carry_jnp(attrs, frontier,
                                           op_mode=(self.mode == "op"),
                                           features=features)
-        new = frontier_relax(sv, carry, bg, mode=self.relax_mode,
-                             compact=self._use_compact,
-                             feature_dim=self.feature_dim)
+        new = self._relax(sv, carry, bg)
         out = alg.post_step_jnp(attrs, aux, sv, new, features=features)
         if not with_stats:
             return out
         if bg is self.bg:
             return out, self._step_stats_jit()(sv, frontier)
         return out, self._step_stats(sv, frontier, bg)
+
+    def _relax(self, sv, carry, bg: BlockedGraph):
+        """The step's relax over `bg`. Inside the sharded fixpoint `bg` is
+        one device's shard of a sharded layout: the device relaxes the
+        whole source state into its own destination tiles, and an
+        all-gather over the mesh axis puts the state back together --
+        the one difference from the local step."""
+        relax = functools.partial(frontier_relax, bg=bg,
+                                  mode=self.relax_mode,
+                                  compact=self._use_compact,
+                                  feature_dim=self.feature_dim)
+        sh = bg.shards
+        if sh is None:
+            return relax(sv, carry)
+        lo = jax.lax.axis_index(sh.axis) * sh.tiles_per_dev
+        mine = jax.lax.dynamic_slice_in_dim(carry, lo, sh.tiles_per_dev,
+                                            axis=1)
+        return jax.lax.all_gather(relax(sv, mine), sh.axis, axis=1,
+                                  tiled=True)
 
     def _step_stats(self, sv, frontier, bg: BlockedGraph | None = None):
         """One trace row's worth of per-step stats, computed from the
@@ -261,17 +300,24 @@ class FlipEngine:
 
         Returns ``(active_vertices (B,), active_tiles (), fetched ())``
         as i32; `fetched` is the blocks streamed from HBM this step
-        (active blocks under compaction, all blocks under dense)."""
+        (active blocks under compaction, all blocks under dense). On a
+        device of the sharded fixpoint `fetched` is summed over the
+        devices, and a fourth entry is the largest device's share."""
         bg = self.bg if bg is None else bg
         act = tile_activity(sv, bg.semiring, self._features)  # (ntiles,)
         active_tiles = jnp.sum(act.astype(jnp.int32))
-        nb = bg.bsrc.shape[0]
         if self._use_compact:
-            fetched = jnp.sum(jnp.take(act, bg.bsrc).astype(jnp.int32))
+            streamed = block_activity(act, bg.bsrc, bg.live)
         else:
-            fetched = jnp.int32(nb)
+            streamed = (jnp.ones(bg.bsrc.shape, bool) if bg.live is None
+                        else bg.live)
+        fetched = jnp.sum(streamed.astype(jnp.int32))
         active_v = jnp.sum(frontier, axis=(1, 2)).astype(jnp.int32)
-        return active_v, active_tiles, fetched
+        if bg.shards is None:
+            return active_v, active_tiles, fetched
+        axis = bg.shards.axis
+        return (active_v, active_tiles, jax.lax.psum(fetched, axis),
+                jax.lax.pmax(fetched, axis))
 
     def _step_stats_jit(self):
         """`_step_stats` as one cached jitted dispatch: the host-driven
@@ -309,7 +355,8 @@ class FlipEngine:
         return (out, stats) if with_stats else out
 
     def _fixpoint(self, attrs0, aux0, frontier0, trace_cap: int = 0,
-                  budgets=None, deadlines_t=None):
+                  budgets=None, deadlines_t=None,
+                  bg: BlockedGraph | None = None):
         """Shared (B, ntiles, T) while_loop with per-query convergence
         masking: a query whose frontier emptied is frozen, so late
         queries in the batch cannot perturb finished ones (op-mode
@@ -329,7 +376,10 @@ class FlipEngine:
         none) needs host-observable step boundaries, so any finite
         deadline routes the fixpoint through the host driver (same
         body, bit-for-bit results). Compacted jnp streaming routes
-        there too (concrete frontiers pick the bucket sizes).
+        there too (concrete frontiers pick the bucket sizes). `bg`
+        (default: the engine's layout) may be a sharded layout: the
+        same loop then runs as the sharded program over its mesh
+        (`_dense_fixpoint_jit`), always on the devices.
 
         `trace_cap > 0` additionally records one per-step stats row into
         fixed-shape (trace_cap, ...) buffers riding the carry (see
@@ -345,20 +395,20 @@ class FlipEngine:
         the same state to run the next segment. The stat buffers are
         write-only extra outputs, so attrs and step counts are
         bit-identical either way."""
+        bg = self.bg if bg is None else bg
         b = attrs0.shape[0]
         budgets = self._device_budgets(budgets, b)
         deadlined = (deadlines_t is not None
                      and bool(np.isfinite(deadlines_t).any()))
-        if deadlined or (self._use_compact
-                         and self._resolved_relax_mode() == "jnp"):
+        if bg.shards is None and (
+                deadlined or (self._use_compact
+                              and self._resolved_relax_mode() == "jnp")):
             return self._fixpoint_host(attrs0, aux0, frontier0, trace_cap,
                                        budgets=budgets,
                                        deadlines_t=deadlines_t)
-        bg = self.bg
         with span("flip.launch"):
-            out = self._dense_fixpoint_jit(trace_cap)(
-                (bg.blocks, bg.blocks_ext, bg.bsrc, bg.bdst), attrs0, aux0,
-                frontier0, budgets)
+            out = self._dense_fixpoint_jit(trace_cap, bg)(
+                _layout_args(bg), attrs0, aux0, frontier0, budgets)
         attrs, aux, frontier, steps = out[0], out[1], out[2], out[3]
         # the host's first read of the loop's output: it waits here for
         # the device to finish the fixpoint
@@ -366,7 +416,7 @@ class FlipEngine:
             converged = ~np.asarray(frontier.any(axis=(1, 2)))
         expired = np.zeros(b, dtype=bool)
         read_trace = (functools.partial(self._read_trace, out[5], out[6],
-                                        trace_cap)
+                                        trace_cap, bg)
                       if trace_cap else None)
         return attrs, aux, frontier, steps, read_trace, converged, expired
 
@@ -381,34 +431,51 @@ class FlipEngine:
         return jnp.asarray(np.broadcast_to(
             np.asarray(budgets, dtype=np.int32), (b,)))
 
-    def _read_trace(self, n_iter, bufs, trace_cap: int):
-        """The dense fixpoint's stat buffers read back to the host as
-        ``(StepTrace, truncated)``."""
+    def _read_trace(self, n_iter, bufs, trace_cap: int, bg: BlockedGraph):
+        """The device fixpoint's stat buffers read back to the host as
+        ``(StepTrace, truncated)``; a sharded run's also give each
+        step's largest and mean live slots per device."""
         n_iter = int(n_iter)
         rows = min(n_iter, trace_cap)
-        b_av, b_at, b_bf, b_cv = (np.asarray(x)[:rows] for x in bufs)
-        nb = int(self.bg.bsrc.shape[0])
+        bufs = [np.asarray(x)[:rows] for x in bufs]
+        b_av, b_at, b_bf, b_cv = bufs[0], bufs[1], bufs[2], bufs[-1]
+        shard = {}
+        if bg.shards is not None:
+            shard = dict(shard_live_max=bufs[3],
+                         shard_live_mean=b_bf / bg.shards.ndev)
         trace = StepTrace(active_vertices=b_av, active_tiles=b_at,
                           blocks_fetched=b_bf,
-                          blocks_skipped=np.int32(nb) - b_bf,
-                          converged=b_cv)
+                          blocks_skipped=np.int32(bg.n_blocks) - b_bf,
+                          converged=b_cv, **shard)
         return trace, n_iter > trace_cap
 
-    def _dense_fixpoint_jit(self, trace_cap: int):
-        """The whole dense while_loop compiled as ONE jitted program per
-        (engine, trace_cap), cached on the instance: eager per-call
-        dispatch of the loop would otherwise dominate the step cost (and
-        blow the traced/untraced overhead bound). The traced variant only
-        adds fixed-shape stat-buffer writes to the carry, so both compile
-        to the same fused step with tracing as a few extra reductions.
+    def _dense_fixpoint_jit(self, trace_cap: int,
+                            bg: BlockedGraph | None = None):
+        """The whole device while_loop compiled as ONE jitted program per
+        (engine, trace_cap[, mesh]), cached on the instance: eager
+        per-call dispatch of the loop would otherwise dominate the step
+        cost (and blow the traced/untraced overhead bound). The traced
+        variant only adds fixed-shape stat-buffer writes to the carry,
+        so both compile to the same fused step with tracing as a few
+        extra reductions.
 
-        The layout's arrays (blocks, sentinel-extended blocks, bsrc,
-        bdst) are arguments of the program, not values it closes over:
-        a closed-over array is embedded in the program as a constant,
-        which at a million vertices means gigabytes of constants to
-        lower, compile and hold a second time on the device."""
+        The layout's arrays are arguments of the program, not values it
+        closes over: a closed-over array is embedded in the program as a
+        constant, which at a million vertices means gigabytes of
+        constants to lower, compile and hold a second time on the
+        device.
+
+        Over a sharded layout the program is `flip_fixpoint_sharded`:
+        the same loop inside a `shard_map` over the layout's mesh axis,
+        each device holding its own slab and the replicated state
+        (padded to a tile count the devices divide), so that per call
+        only the (B, ntiles, T) state moves. `bg` defaults to the
+        engine's own layout."""
+        bg = self.bg if bg is None else bg
+        sh = bg.shards
         cache = self.__dict__.setdefault("_fixpoint_cache", {})
-        fn = cache.get(trace_cap)
+        key = (trace_cap, None if sh is None else sh.key())
+        fn = cache.get(key)
         if fn is not None:
             return fn
 
@@ -432,40 +499,73 @@ class FlipEngine:
                     attrs, aux, frontier, live, bg=bg)
                 return (attrs, aux, frontier,
                         steps + live.astype(jnp.int32), budgets)
-            it, (b_av, b_at, b_bf, b_cv) = state[5], state[6]
-            (attrs, aux, frontier), (av, at, bf) = self._masked_step(
+            it, bufs = state[5], state[6]
+            (attrs, aux, frontier), stats = self._masked_step(
                 attrs, aux, frontier, live, with_stats=True, bg=bg)
             # rows past the capacity are dropped, not wrapped: the trace
             # stays a prefix of the run and `truncated` flags the cut
-            bufs = (b_av.at[it].set(av, mode="drop"),
-                    b_at.at[it].set(at, mode="drop"),
-                    b_bf.at[it].set(bf, mode="drop"),
-                    b_cv.at[it].set(~live, mode="drop"))
+            bufs = tuple(buf.at[it].set(x, mode="drop")
+                         for buf, x in zip(bufs, stats + (~live,)))
             return (attrs, aux, frontier, steps + live.astype(jnp.int32),
                     budgets, it + 1, bufs)
+
+        def loop(bg, attrs0, aux0, frontier0, budgets):
+            b = attrs0.shape[0]
+            state0 = (attrs0, aux0, frontier0, jnp.zeros(b, jnp.int32),
+                      budgets)
+            if trace_cap:
+                # active vertices, active tiles, blocks fetched[, the
+                # largest device's live slots], converged
+                rows = ([((b,), jnp.int32)] + [((), jnp.int32)] * 2
+                        + [((), jnp.int32)] * (sh is not None)
+                        + [((b,), bool)])
+                bufs0 = tuple(jnp.zeros((trace_cap,) + shape, dtype)
+                              for shape, dtype in rows)
+                state0 = state0 + (jnp.int32(0), bufs0)
+            return jax.lax.while_loop(cond, functools.partial(body, bg),
+                                      state0)
 
         # the function's name names the program: `jit_flip_fixpoint` on
         # the profiler's `XLA Modules` line
         @jax.jit
         def flip_fixpoint(layout, attrs0, aux0, frontier0, budgets):
             blocks, blocks_ext, bsrc, bdst = layout
-            bg = dataclasses.replace(self.bg, blocks=blocks,
-                                     blocks_ext=blocks_ext, bsrc=bsrc,
-                                     bdst=bdst)
-            b = attrs0.shape[0]
-            state0 = (attrs0, aux0, frontier0, jnp.zeros(b, jnp.int32),
-                      budgets)
-            if trace_cap:
-                bufs0 = (jnp.zeros((trace_cap, b), jnp.int32),
-                         jnp.zeros((trace_cap,), jnp.int32),
-                         jnp.zeros((trace_cap,), jnp.int32),
-                         jnp.zeros((trace_cap, b), bool))
-                state0 = state0 + (jnp.int32(0), bufs0)
-            return jax.lax.while_loop(cond, functools.partial(body, bg),
-                                      state0)
+            return loop(dataclasses.replace(bg, blocks=blocks,
+                                            blocks_ext=blocks_ext,
+                                            bsrc=bsrc, bdst=bdst),
+                        attrs0, aux0, frontier0, budgets)
 
-        cache[trace_cap] = flip_fixpoint
-        return flip_fixpoint
+        if sh is None:
+            cache[key] = flip_fixpoint
+            return flip_fixpoint
+
+        zero = np.float32(self.algebra.semiring.zero)
+        pad = sh.ntiles_p - bg.ntiles
+
+        def widen(x, fill):
+            widths = ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)
+            return jnp.pad(x, widths, constant_values=fill) if pad else x
+
+        @functools.partial(jax.shard_map, mesh=sh.mesh,
+                           in_specs=(P(sh.axis), P(), P(), P(), P()),
+                           out_specs=P(), check_vma=False)
+        def per_device(layout, attrs0, aux0, frontier0, budgets):
+            blocks, bsrc, bdst, live = layout
+            return loop(dataclasses.replace(bg, blocks=blocks,
+                                            blocks_ext=blocks, bsrc=bsrc,
+                                            bdst=bdst, live=live),
+                        attrs0, aux0, frontier0, budgets)
+
+        # `jit_flip_fixpoint_sharded` on the profiler's `XLA Modules` line
+        @jax.jit
+        def flip_fixpoint_sharded(layout, attrs0, aux0, frontier0,
+                                  budgets):
+            out = per_device(layout, widen(attrs0, zero), widen(aux0, 0),
+                             widen(frontier0, False), budgets)
+            return tuple(x[:, :bg.ntiles] for x in out[:3]) + out[3:]
+
+        cache[key] = flip_fixpoint_sharded
+        return flip_fixpoint_sharded
 
     def _fixpoint_host(self, attrs, aux, frontier, trace_cap: int = 0,
                        budgets=None, deadlines_t=None):
@@ -579,8 +679,7 @@ class FlipEngine:
         `TRACE_CAP_DEFAULT` row capacity, an int = that capacity) and
         makes the call return ``(out, steps, DispatchTelemetry)``
         instead of ``(out, steps)``; results and step counts are
-        bit-identical with tracing on. Tracing the shard_map fixpoint is
-        not supported yet.
+        bit-identical with tracing on, local or sharded.
 
         `max_steps` (int or (B,) per-query ints) caps each query's
         relaxation steps below the session-wide `self.max_steps` valve;
@@ -601,25 +700,16 @@ class FlipEngine:
         srcs = np.atleast_1d(np.asarray(srcs, dtype=np.int64))
         budgets = self._resolve_budgets(max_steps, len(srcs))
         deadlines_t = self._resolve_deadlines(deadline_s, len(srcs))
-        if distributed:
-            if trace:
-                raise ValueError(
-                    "per-step tracing is not supported on the "
-                    "distributed (shard_map) fixpoint yet; run the "
-                    "trace on a local plan")
-            if deadlines_t is not None:
-                raise InvalidRequest(
-                    "deadline_s is not supported on the distributed "
-                    "(shard_map) fixpoint: deadlines are enforced at "
-                    "host-observable step boundaries -- use max_steps, "
-                    "or run on a local plan")
-            out, steps, conv = self._execute_distributed(
-                srcs, warm=warm, mesh=mesh, axis=axis, budgets=budgets)
-            tele, expired = None, np.zeros(len(srcs), dtype=bool)
-        else:
-            out, steps, tele, conv, expired = self._execute_local(
-                srcs, warm=warm, trace_cap=self._trace_cap(trace),
-                budgets=budgets, deadlines_t=deadlines_t)
+        bg = self._layout(distributed, mesh, axis)
+        if bg.shards is not None and deadlines_t is not None:
+            raise InvalidRequest(
+                "deadline_s is not supported on the distributed "
+                "(shard_map) fixpoint: deadlines are enforced at "
+                "host-observable step boundaries -- use max_steps, "
+                "or run on a local plan")
+        out, steps, tele, conv, expired = self._execute_local(
+            srcs, warm=warm, trace_cap=self._trace_cap(trace),
+            budgets=budgets, deadlines_t=deadlines_t, bg=bg)
         if detail:
             if batched:
                 return ExecutionDetail(attrs=out, steps=steps,
@@ -632,6 +722,35 @@ class FlipEngine:
                                    telemetry=tele)
         r = (out, steps) if batched else (out[0], int(steps[0]))
         return r + (tele,) if trace else r
+
+    def _layout(self, distributed: bool, mesh: Mesh | None,
+                axis: str) -> BlockedGraph:
+        """The layout a call runs over: the engine's own, or with
+        `distributed` one sharded over `mesh`'s `axis` (default: all
+        local devices) -- the engine's own when it is sharded so, else
+        a sharded copy of it made once per mesh and kept."""
+        bg = self.bg
+        if not distributed:
+            if bg.shards is not None:
+                raise ValueError(
+                    "this engine's layout is sharded over a mesh: run it "
+                    "with distributed=True")
+            return bg
+        if mesh is None:
+            if bg.shards is not None:
+                return bg
+            mesh = Mesh(np.array(jax.devices()), (axis,))
+        key = mesh_key(mesh, axis)
+        if bg.shards is not None:
+            if bg.shards.key() != key:
+                raise ValueError(
+                    "this engine's layout is sharded over another mesh "
+                    f"({bg.shards.key()}); asked for {key}")
+            return bg
+        cache = self.__dict__.setdefault("_shard_cache", {})
+        if key not in cache:
+            cache[key] = bg.shard(mesh, axis)
+        return cache[key]
 
     def _resolve_budgets(self, max_steps, b: int):
         """Per-query step budgets ((B,) i32) from a caller cap: None
@@ -699,20 +818,23 @@ class FlipEngine:
 
     def _execute_local(self, srcs, warm: WarmStart | None = None,
                        trace_cap: int = 0, budgets=None,
-                       deadlines_t=None):
-        """Local fixpoint over a (B,) source array; always batched.
-        Returns ``(out, steps, DispatchTelemetry | None, converged,
-        deadline_expired)`` -- the last two are (B,) bool masks."""
+                       deadlines_t=None, bg: BlockedGraph | None = None):
+        """One fixpoint over a (B,) source array; always batched. It runs
+        on one device, or over the devices of `bg` when that is a
+        sharded layout (`_layout`). Returns ``(out, steps,
+        DispatchTelemetry | None, converged, deadline_expired)`` -- the
+        last two are (B,) bool masks."""
+        bg = self.bg if bg is None else bg
         with span("flip.prepare"):
             attrs0, aux0, frontier0 = self.initial_state(srcs, warm=warm)
             budgets = self._device_budgets(budgets, len(srcs))
         t0 = time.perf_counter()
         attrs, aux, _, steps, read_trace, converged, expired = \
             self._fixpoint(attrs0, aux0, frontier0, trace_cap,
-                           budgets=budgets, deadlines_t=deadlines_t)
+                           budgets=budgets, deadlines_t=deadlines_t, bg=bg)
         with span("flip.finalize"):
-            out = self.bg.to_orig(self.algebra.finalize(attrs, aux),
-                                  features=self._features)
+            out = bg.to_orig(self.algebra.finalize(attrs, aux),
+                             features=self._features)
             steps = np.asarray(steps)
         if read_trace is None:
             return out, steps, None, converged, expired
@@ -720,17 +842,28 @@ class FlipEngine:
         with span("flip.telemetry") as sp:
             trace, truncated = read_trace()
             sp.set_metadata(rows=len(trace.active_tiles))
-            path, grid_steps = relax_grid(self.bg, int(steps.shape[0]),
-                                          self.relax_mode, self.feature_dim)
+            b = int(steps.shape[0])
+            path, grid_steps = relax_grid(bg, b, self.relax_mode,
+                                          self.feature_dim)
+            meta = {}
+            sh = bg.shards
+            if sh is not None:
+                # each step's all-gather: the whole (B, ntiles_p, T[, d])
+                # f32 state, assembled on every device
+                meta = {"devices": sh.ndev,
+                        "tiles_per_device": sh.tiles_per_dev,
+                        "slots_per_device": sh.slots,
+                        "gather_bytes": b * sh.ntiles_p * bg.tile
+                        * self.feature_dim * 4}
             tele = DispatchTelemetry(
                 backend=self._resolved_relax_mode(), mode=self.mode,
-                compact=self._use_compact, batch=int(steps.shape[0]),
-                n=self.bg.n, ntiles=self.bg.ntiles,
-                n_blocks=int(self.bg.bsrc.shape[0]), steps=steps,
+                compact=self._use_compact, batch=b,
+                n=bg.n, ntiles=bg.ntiles,
+                n_blocks=bg.n_blocks, steps=steps,
                 trace=trace, wall_s=time.perf_counter() - t0,
-                truncated=truncated, tile=self.bg.tile,
+                truncated=truncated, tile=bg.tile,
                 feature_dim=self.feature_dim, relax_path=path,
-                relax_grid_steps=grid_steps)
+                relax_grid_steps=grid_steps, meta=meta)
         return out, steps, tele, converged, expired
 
     # -------------------------------------------------------------- #
@@ -817,185 +950,6 @@ class FlipEngine:
         return dataclasses.replace(self, bg=bg2), delta
 
     # -------------------------------------------------------------- #
-    def _execute_distributed(self, srcs, warm: WarmStart | None = None,
-                             mesh: Mesh | None = None, axis: str = "data",
-                             budgets=None):
-        """shard_map fixpoint over a (B,) source array; always batched:
-        destination tiles sharded over `axis`, queries replicated.
-        `warm` resumes from a prior converged result (see `WarmStart`),
-        so incremental recompute after a monotone update batch works
-        distributed too.
-
-        Each device owns a contiguous slab of destination tiles and the
-        blocks that write them; per step it computes its slab's new attrs
-        for every query in the batch and the global attribute vector is
-        re-formed with an all-gather (the TPU analogue of FLIP's NoC
-        scatter) -- one collective per step regardless of B, so the NoC
-        cost amortizes over the batch. Works for every registered algebra
-        in both 'data' and 'op' modes; a device whose slab holds only
-        padded tiles owns zero real blocks and runs identity no-op blocks.
-
-        Because blocks are bdst-sorted, each device's slab is one
-        contiguous range of the block list, sliced directly from the
-        precomputed per-destination layout (`bg.dst_start`). In data mode
-        the per-device frontier compaction is the degenerate exact form:
-        a device none of whose local blocks has an active source returns
-        its carry without touching the weight slab (`lax.cond`), so
-        frontier locality idles whole devices just like FLIP's inactive
-        PE clusters.
-        """
-        if mesh is None:
-            devs = np.array(jax.devices())
-            mesh = Mesh(devs, (axis,))
-        ndev = mesh.shape[axis]
-        bg = self.bg
-        zero = np.float32(self.algebra.semiring.zero)
-
-        # pad tiles to a multiple of ndev, then slice each device's block
-        # slab straight out of the bdst-sorted list via the precomputed
-        # per-destination layout (no per-block Python loop)
-        ntiles_p = -(-bg.ntiles // ndev) * ndev
-        bsrc, bdst = np.asarray(bg.bsrc), np.asarray(bg.bdst)
-        tiles_per_dev = ntiles_p // ndev
-        bounds = np.minimum(np.arange(0, ntiles_p + 1, tiles_per_dev),
-                            bg.ntiles)
-        starts = np.asarray(bg.dst_start)[bounds]        # (ndev+1,)
-        # >= 1 so a device owning zero blocks still gets a (1, T, T)
-        # all-identity slab (exact no-op) instead of a zero-size array
-        max_nb = max(1, int(np.diff(starts).max()))
-        t = bg.tile
-        blocks_sh = np.full((ndev, max_nb, t, t), zero, dtype=np.float32)
-        bsrc_sh = np.zeros((ndev, max_nb), dtype=np.int32)
-        bdst_sh = np.zeros((ndev, max_nb), dtype=np.int32)
-        valid_sh = np.zeros((ndev, max_nb), dtype=bool)
-        blocks_np = np.asarray(bg.blocks)
-        for dev in range(ndev):
-            s, e = int(starts[dev]), int(starts[dev + 1])
-            blocks_sh[dev, :e - s] = blocks_np[s:e]
-            bsrc_sh[dev, :e - s] = bsrc[s:e]
-            # destination indices local to the device slab
-            bdst_sh[dev, :e - s] = bdst[s:e] - dev * tiles_per_dev
-            valid_sh[dev, :e - s] = True
-            # padding slots hold all-⊕-identity blocks (exact no-ops) and
-            # repeat the last real slot's tile pair, so the kernel never
-            # revisits an earlier destination slab; valid=False keeps
-            # them out of the idle-skip predicate. A block-less device
-            # keeps tile pair (0, 0) throughout.
-            if e > s:
-                bsrc_sh[dev, e - s:] = bsrc_sh[dev, e - s - 1]
-                bdst_sh[dev, e - s:] = bdst_sh[dev, e - s - 1]
-
-        features = self._features
-        attrs0, aux0, frontier0 = self.initial_state(srcs, warm=warm)
-        pad = ntiles_p - bg.ntiles
-        if pad:
-            widths = ((0, 0), (0, pad)) + ((0, 0),) * (attrs0.ndim - 2)
-            attrs0 = jnp.pad(attrs0, widths, constant_values=zero)
-            aux0 = jnp.pad(aux0, widths)
-            frontier0 = jnp.pad(frontier0, ((0, 0), (0, pad), (0, 0)))
-        if budgets is None:
-            budgets0 = np.full(srcs.shape[0], self.max_steps,
-                               dtype=np.int32)
-        else:
-            budgets0 = np.asarray(budgets, dtype=np.int32)
-
-        # each device receives only its own slab: placing the stacked
-        # (ndev, max_nb, T, T) array with one jnp.asarray would first
-        # land all of it on the default device
-        shard = NamedSharding(mesh, P(axis))
-        slabs = [jax.device_put(a, shard)
-                 for a in (blocks_sh, bsrc_sh, bdst_sh, valid_sh)]
-        attrs_f, aux_f, steps, conv = self._dist_fixpoint(
-            mesh, axis, tiles_per_dev)(*slabs, attrs0, aux0, frontier0,
-                                       jnp.asarray(budgets0))
-        out = self.algebra.finalize(attrs_f, aux_f)
-        out = self.bg.to_orig(out[:, :bg.ntiles], features=features)
-        return out, np.asarray(steps), np.asarray(conv)
-
-    def _dist_fixpoint(self, mesh: Mesh, axis: str, tiles_per_dev: int):
-        """The jitted shard_map fixpoint of `_execute_distributed` over
-        `mesh`: arguments are the (ndev, max_nb, ...) per-device slabs
-        (sharded over `axis`) and the replicated (B, ntiles_p, T[, d])
-        state plus (B,) budgets. Each device relaxes the whole source
-        state into its own destination slab through the same
-        `frontier_relax` dispatch as the local fixpoint, so every
-        destination tile accumulates its blocks in the same order and
-        the results are bit-for-bit the local ones."""
-        alg = self.algebra
-        features = self._features
-        op_mode = self.mode == "op"
-        skip_idle = self._use_compact
-
-        @functools.partial(
-            jax.shard_map, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P(axis),
-                      P(None), P(None), P(None), P(None)),
-            out_specs=(P(None), P(None), P(None), P(None)),
-            check_vma=False)
-        def dist_fix(blocks, bsrc_l, bdst_l, valid_l, attrs, aux, frontier,
-                     budgets):
-            blocks, bsrc_l, bdst_l, valid_l = (blocks[0], bsrc_l[0],
-                                               bdst_l[0], valid_l[0])
-            # this device's layout view; the dense relax never reads
-            # blocks_ext, and passing one keeps __post_init__ from
-            # building a sentinel-extended copy inside the trace
-            local = dataclasses.replace(self.bg, blocks=blocks,
-                                        blocks_ext=blocks, bsrc=bsrc_l,
-                                        bdst=bdst_l)
-
-            def cond(state):
-                _, _, frontier, steps = state
-                return jnp.logical_and(frontier.any(axis=(1, 2)),
-                                       steps < budgets).any()
-
-            def relax_local(args):
-                sv, carry_local = args
-                return frontier_relax(sv, carry_local, local,
-                                      mode=self.relax_mode,
-                                      feature_dim=self.feature_dim)
-
-            def body(state):
-                attrs, aux, frontier, steps = state
-                live = jnp.logical_and(frontier.any(axis=(1, 2)),
-                                       steps < budgets)
-                sv, carry = alg.scatter_carry_jnp(attrs, frontier, op_mode,
-                                                  features=features)
-                carry_local = jax.lax.dynamic_slice_in_dim(
-                    carry, jax.lax.axis_index(axis) * tiles_per_dev,
-                    tiles_per_dev, axis=1)
-                if skip_idle:
-                    # per-device frontier compaction, degenerate exact
-                    # form: no active source among the local *real*
-                    # blocks (any query) => the local relax is pure
-                    # ⊕-identity, so return the carry without touching
-                    # the weight slab
-                    act = tile_activity(sv, alg.semiring, features)
-                    new_local = jax.lax.cond(
-                        jnp.any(jnp.logical_and(act[bsrc_l], valid_l)),
-                        relax_local, lambda args: args[1],
-                        (sv, carry_local))
-                else:
-                    new_local = relax_local((sv, carry_local))
-                new = jax.lax.all_gather(new_local, axis, axis=1,
-                                         tiled=True)
-                attrs_n, aux_n, frontier_n = alg.post_step_jnp(
-                    attrs, aux, sv, new, features=features)
-                ms = live.reshape(live.shape + (1,) * (attrs.ndim - 1))
-                return (jnp.where(ms, attrs_n, attrs),
-                        jnp.where(ms, aux_n, aux),
-                        jnp.where(live[:, None, None], frontier_n,
-                                  frontier),
-                        steps + live.astype(jnp.int32))
-
-            steps0 = jnp.zeros(attrs.shape[0], jnp.int32)
-            attrs_f, aux_f, frontier_f, steps = jax.lax.while_loop(
-                cond, body, (attrs, aux, frontier, steps0))
-            conv = jnp.logical_not(frontier_f.any(axis=(1, 2)))
-            return attrs_f, aux_f, steps, conv
-
-        return jax.jit(dist_fix)
-
-    # -------------------------------------------------------------- #
     # deprecated pre-api entry points: thin shims over `execute`
     # -------------------------------------------------------------- #
     @staticmethod
@@ -1020,15 +974,6 @@ class FlipEngine:
         bit-for-bit the corresponding solo result."""
         self._warn_legacy("run_batch")
         return self.execute(np.atleast_1d(np.asarray(srcs)), warm=warm)
-
-    def run_distributed(self, src=0, mesh: Mesh | None = None,
-                        axis: str = "data", warm: WarmStart | None = None):
-        """Deprecated: `execute(src, distributed=True)`. shard_map
-        fixpoint with destination tiles sharded over `axis`; shapes
-        follow `src` like `execute`."""
-        self._warn_legacy("run_distributed")
-        return self.execute(src, warm=warm, distributed=True,
-                            mesh=mesh, axis=axis)
 
     def run_updated(self, src, prev, delta: UpdateDelta):
         """Deprecated: `execute(src, warm=resolve_warm(prev, delta))`.

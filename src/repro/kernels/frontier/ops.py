@@ -35,6 +35,17 @@ shrink, so when called with concrete (non-traced) arrays the active list
 is padded to the next power-of-two bucket -- at most log2(nb) specialized
 executables -- which is where the CPU fallback's asymptotic win comes
 from (`FlipEngine` drives its jnp fixpoint through this path).
+
+Sharded layout (`BlockKeys.build(mesh=...)`, `BlockedGraph.shard`): for
+a graph whose blocks one device cannot hold, device k of a mesh axis
+owns a contiguous range of destination tiles and the blocks that write
+them -- one contiguous range of the bdst-sorted list. Each device's
+slab is its blocks, ⊕-identity padding up to the largest slab, and one
+sentinel, built on the host one device at a time and placed on its own
+device only: the whole layout never exists on one device, and each
+device holds its weights once (the slab is both the dense and the
+sentinel-extended stream). Padding slots are not `live`, so compaction
+never streams them.
 """
 from __future__ import annotations
 
@@ -45,15 +56,66 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
 from repro.algebra import MIN_PLUS, Semiring, VertexAlgebra, get_algebra
 from repro.graphs.csr import Graph
 from repro.kernels.frontier.frontier import (frontier_relax_pallas,
                                              relax_grid_steps, relax_path)
+from repro.obs.spans import span
+
+
+@dataclasses.dataclass(frozen=True)
+class ShardLayout:
+    """How a sharded `BlockedGraph` lies over a mesh axis. Device k owns
+    destination tiles [k * tiles_per_dev, (k + 1) * tiles_per_dev) and
+    the blocks that write them, positions starts[k]:starts[k+1] of the
+    bdst-sorted list, as a slab of `slots` stream slots (its blocks,
+    then padding) and one trailing sentinel."""
+    mesh: Mesh
+    axis: str
+    tiles_per_dev: int
+    slots: int                  # stream slots per device (largest slab)
+    starts: np.ndarray          # (ndev+1,) slab bounds in the sorted list
+    keys: np.ndarray            # (nb,) i64 bdst * ntiles + bsrc, sorted
+
+    @property
+    def ndev(self) -> int:
+        return int(self.mesh.shape[self.axis])
+
+    @property
+    def ntiles_p(self) -> int:
+        """Tiles of the state the devices exchange: a multiple of ndev."""
+        return self.tiles_per_dev * self.ndev
+
+    @property
+    def n_blocks(self) -> int:
+        return int(self.keys.size)
+
+    def rows(self, pos: np.ndarray) -> np.ndarray:
+        """Rows of the sharded block array holding the blocks at
+        positions `pos` of the bdst-sorted list."""
+        k = np.searchsorted(self.starts, pos, side="right") - 1
+        return k * (self.slots + 1) + (pos - self.starts[k])
+
+    def key(self) -> tuple:
+        return mesh_key(self.mesh, self.axis)
+
+
+def mesh_key(mesh: Mesh, axis: str) -> tuple:
+    """What identifies a mesh for compiled programs: its device ids in
+    order and its axis names (plus the axis the tiles shard over)."""
+    return (tuple(int(d.id) for d in mesh.devices.flat),
+            tuple(mesh.axis_names), tuple(mesh.devices.shape), axis)
 
 
 @dataclasses.dataclass
 class BlockedGraph:
-    """Block-sparse tiled adjacency over one algebra's semiring."""
+    """Block-sparse tiled adjacency over one algebra's semiring. With
+    `shards` set, the arrays are a sharded layout's per-device streams
+    concatenated over the mesh axis (module doc): device k's `bsrc`/
+    `bdst`/`live` are its `slots` entries (destination tiles local to
+    it), and its `blocks` (= `blocks_ext`) its slots plus sentinel."""
     n: int                      # true vertex count
     tile: int                   # T
     ntiles: int
@@ -70,9 +132,8 @@ class BlockedGraph:
     blocks_ext: jnp.ndarray = None
     # (ntiles+1,) i32 per-destination segment layout: the blocks writing
     # destination tile d occupy bdst-sorted positions
-    # dst_start[d]:dst_start[d+1]. Precomputed so runtime compaction is a
-    # masked cumsum/scatter (never a sort) and the distributed engine can
-    # slice per-device block slabs directly.
+    # dst_start[d]:dst_start[d+1] (a sharded layout splits the list at
+    # these bounds).
     dst_start: np.ndarray = None
     bsrc_np: np.ndarray = None  # host copy of bsrc for the per-step
                                 # bucketing path (avoids a device->host
@@ -80,6 +141,10 @@ class BlockedGraph:
     version: int = 0            # Graph.version this layout was built from
     graph_fp: str = None        # Graph.fingerprint() of that graph, so
                                 # engine caches can detect stale layouts
+    # (nslots,) bool: the stream slots that hold a block (None: all do).
+    # A sharded slab's padding is not live, so compaction skips it.
+    live: jnp.ndarray = None
+    shards: ShardLayout = None  # set on a sharded layout
 
     def __post_init__(self):
         # precompute eagerly (construction always happens on the host):
@@ -99,6 +164,29 @@ class BlockedGraph:
     @property
     def padded_n(self) -> int:
         return self.ntiles * self.tile
+
+    @property
+    def n_blocks(self) -> int:
+        """Real weight blocks (padding and sentinels excluded)."""
+        if self.shards is not None:
+            return self.shards.n_blocks
+        return int(self.bsrc.shape[0])
+
+    def shard(self, mesh: Mesh, axis: str = "data") -> "BlockedGraph":
+        """This (local) layout re-laid over `mesh`'s `axis` (module doc),
+        copying its blocks to the host once."""
+        if self.shards is not None:
+            raise ValueError("the layout is sharded already")
+        keys = (np.asarray(self.bdst, np.int64) * self.ntiles
+                + np.asarray(self.bsrc, np.int64))
+        blocks = np.asarray(self.blocks)
+
+        def fill(s, e, out):
+            out[:e - s] = blocks[s:e]
+        return _sharded(keys, mesh, axis, fill, n=self.n, tile=self.tile,
+                        ntiles=self.ntiles, perm=self.perm,
+                        inv_perm=self.inv_perm, algebra=self.algebra,
+                        version=self.version, graph_fp=self.graph_fp)
 
     @property
     def semiring(self) -> Semiring:
@@ -232,16 +320,19 @@ class BlockedGraph:
         # only the dirty blocks are gathered from the device array --
         # the full block tensor never round-trips through the host on
         # the (common) value-only path
-        old_keys = (np.asarray(self.bdst, dtype=np.int64) * ntiles
+        sh = self.shards
+        old_keys = (sh.keys if sh is not None
+                    else np.asarray(self.bdst, dtype=np.int64) * ntiles
                     + np.asarray(self.bsrc, dtype=np.int64))
         nb = old_keys.size
         opos = np.searchsorted(old_keys, dkeys)
         exists = ((opos < nb)
                   & (old_keys[np.minimum(opos, nb - 1)] == dkeys))
         opos_e = opos[exists]
+        rows = opos_e if sh is None else sh.rows(opos_e)
         old = np.full_like(fresh, np.float32(sr.zero))
         if opos_e.size:
-            old[exists] = np.asarray(self.blocks[opos_e])
+            old[exists] = np.asarray(self.blocks[rows])
         monotone = sr.monotone_under(old, fresh)
 
         # affected sources: original ids of the lanes whose out-edge
@@ -265,14 +356,17 @@ class BlockedGraph:
         if not grow.any() and not drop.any():
             upd = self.blocks
             if opos_e.size:                # dirty tiles patched on device
-                upd = upd.at[opos_e].set(jnp.asarray(fresh[exists]))
-            new_bg = BlockedGraph(
-                n=self.n, tile=t, ntiles=ntiles,
-                blocks=upd, bsrc=self.bsrc, bdst=self.bdst,
-                perm=perm, inv_perm=self.inv_perm, algebra=alg,
-                dst_start=self.dst_start, bsrc_np=self.bsrc_np,
+                upd = upd.at[rows].set(jnp.asarray(fresh[exists]))
+            # a local layout re-derives its sentinel copy; a sharded one
+            # holds its slabs once
+            new_bg = dataclasses.replace(
+                self, blocks=upd, blocks_ext=None if sh is None else upd,
                 version=new_graph.version, graph_fp=fp)
             shape_changed = False
+        elif sh is not None:               # re-lay the slabs from scratch
+            new_bg = block_keys(new_graph, alg, t, order=self.inv_perm
+                                ).build(mesh=sh.mesh, axis=sh.axis)
+            shape_changed = True
         else:
             blocks = np.asarray(self.blocks).copy()
             blocks[opos_e] = fresh[exists]
@@ -327,10 +421,81 @@ def _scatter_edges(sr: Semiring, flat: np.ndarray, lin: np.ndarray,
             flat[j] = sr.add_np(flat[j], x)
 
 
-def build_blocks(graph: Graph, algo: str | VertexAlgebra = "sssp",
-                 tile: int = 128,
-                 order: np.ndarray | None = None) -> BlockedGraph:
-    """Block-sparse semiring adjacency for any registered algebra.
+@dataclasses.dataclass
+class BlockKeys:
+    """A graph's block structure under one algebra, before any block is
+    filled: the sorted block keys (one `np.unique`) and where each
+    stored edge lands. What a layout will cost is known here, before
+    anything is allocated (`device_bytes`, `shard_bytes`); `build` then
+    fills the blocks, on one device or sharded over a mesh axis."""
+    n: int
+    tile: int
+    ntiles: int
+    perm: np.ndarray            # original vertex id -> tiled position
+    order: np.ndarray           # tiled position -> original vertex id
+    algebra: VertexAlgebra
+    keys: np.ndarray            # (nb,) i64 bdst * ntiles + bsrc, sorted
+    edge_block: np.ndarray      # (m,) each stored edge's block position
+    edge_cell: np.ndarray       # (m,) its cell in the block: row * T + col
+    weights: np.ndarray         # (m,) f32 ⊗ operands
+    version: int = 0
+    graph_fp: str | None = None
+
+    @property
+    def n_blocks(self) -> int:
+        return int(self.keys.size)
+
+    @property
+    def block_bytes(self) -> int:
+        return self.tile * self.tile * 4
+
+    def device_bytes(self) -> int:
+        """Device bytes of the one-device layout: the blocks held twice
+        (`blocks` and the sentinel-extended `blocks_ext`)."""
+        return (2 * self.n_blocks + 1) * self.block_bytes
+
+    def shard_bytes(self, ndev: int) -> int:
+        """Device bytes on the fullest device of the layout sharded over
+        `ndev` devices: its slab (the largest) and one sentinel."""
+        counts = np.diff(_split(self.keys, self.ntiles, ndev)[1])
+        return (max(1, int(counts.max())) + 1) * self.block_bytes
+
+    def build(self, mesh: Mesh | None = None,
+              axis: str = "data") -> "BlockedGraph":
+        """The layout on the default device, or with `mesh` sharded over
+        its `axis` (module doc)."""
+        sr, t = self.algebra.semiring, self.tile
+        fields = dict(n=self.n, tile=t, ntiles=self.ntiles, perm=self.perm,
+                      inv_perm=np.asarray(self.order), algebra=self.algebra,
+                      version=self.version, graph_fp=self.graph_fp)
+        if mesh is not None:
+            # each slab scatters its own edges, taken in the order the
+            # whole build takes them, so parallel edges ⊕-combine alike
+            order = np.argsort(self.edge_block, kind="stable")
+            ecut = np.searchsorted(self.edge_block[order],
+                                   np.arange(self.n_blocks + 1))
+
+            def fill(s, e, out):
+                sel = order[ecut[s]:ecut[e]]
+                lin = (self.edge_block[sel] - s) * (t * t) \
+                    + self.edge_cell[sel]
+                _scatter_edges(sr, out.reshape(-1), lin, self.weights[sel])
+            return _sharded(self.keys, mesh, axis, fill, **fields)
+        blocks = np.full((self.n_blocks, t, t), np.float32(sr.zero),
+                         dtype=np.float32)
+        lin = self.edge_block * (t * t) + self.edge_cell
+        _scatter_edges(sr, blocks.reshape(-1), lin, self.weights)
+        return BlockedGraph(
+            blocks=jnp.asarray(blocks),
+            bsrc=jnp.asarray((self.keys % self.ntiles).astype(np.int32)),
+            bdst=jnp.asarray((self.keys // self.ntiles).astype(np.int32)),
+            **fields)
+
+
+def block_keys(graph: Graph, algo: str | VertexAlgebra = "sssp",
+               tile: int = 128,
+               order: np.ndarray | None = None) -> BlockKeys:
+    """The block structure of `graph` under an algebra (`BlockKeys`).
 
     algo: a registered algorithm name ('bfs', 'sssp', 'wcc', 'pagerank',
     'widest', 'reach', ...) or a `VertexAlgebra` directly. `order`:
@@ -340,11 +505,9 @@ def build_blocks(graph: Graph, algo: str | VertexAlgebra = "sssp",
     Fully vectorized: edges come straight out of the CSR arrays, the ⊗
     operands from the algebra's vectorized `edge_values`, block ids from
     one `np.unique` over (bdst, bsrc) keys (already the required sort
-    order), and parallel edges ⊕-combine through the semiring ufunc's
-    `.at` scatter -- no per-edge Python loop.
+    order) -- no per-edge Python loop.
     """
     alg = algo if isinstance(algo, VertexAlgebra) else get_algebra(algo)
-    sr = alg.semiring
     n = graph.n
     if order is None:
         order = np.arange(n)
@@ -369,19 +532,87 @@ def build_blocks(graph: Graph, algo: str | VertexAlgebra = "sssp",
     key = (pv // tile) * ntiles + (pu // tile)
     diag = np.arange(ntiles, dtype=np.int64) * (ntiles + 1)
     uniq, inv = np.unique(np.concatenate([key, diag]), return_inverse=True)
-    nb = uniq.size
-    bdst = (uniq // ntiles).astype(np.int32)
-    bsrc = (uniq % ntiles).astype(np.int32)
+    return BlockKeys(n=n, tile=tile, ntiles=ntiles, perm=perm, order=order,
+                     algebra=alg, keys=uniq, edge_block=inv[:key.size],
+                     edge_cell=(pu % tile) * tile + pv % tile,
+                     weights=w.astype(np.float32), version=graph.version,
+                     graph_fp=graph.fingerprint())
 
-    blocks = np.full((nb, tile, tile), np.float32(sr.zero), dtype=np.float32)
-    lin = (inv[:key.size] * tile + pu % tile) * tile + pv % tile
-    _scatter_edges(sr, blocks.reshape(-1), lin, w.astype(np.float32))
-    return BlockedGraph(n=n, tile=tile, ntiles=ntiles,
-                        blocks=jnp.asarray(blocks),
-                        bsrc=jnp.asarray(bsrc), bdst=jnp.asarray(bdst),
-                        perm=perm, inv_perm=np.asarray(order),
-                        algebra=alg, version=graph.version,
-                        graph_fp=graph.fingerprint())
+
+def build_blocks(graph: Graph, algo: str | VertexAlgebra = "sssp",
+                 tile: int = 128,
+                 order: np.ndarray | None = None) -> BlockedGraph:
+    """Block-sparse semiring adjacency for any registered algebra, on the
+    default device (`block_keys` for the arguments): parallel edges
+    ⊕-combine through the semiring ufunc's `.at` scatter."""
+    return block_keys(graph, algo, tile, order).build()
+
+
+def _split(keys: np.ndarray, ntiles: int,
+           ndev: int) -> tuple[int, np.ndarray]:
+    """``(tiles_per_dev, starts)`` of a layout sharded over `ndev`
+    devices: tiles padded to a multiple of `ndev`, device k owning
+    destination tiles [k * tiles_per_dev, (k + 1) * tiles_per_dev) and
+    the positions starts[k]:starts[k+1] of the sorted key list."""
+    tpd = -(-ntiles // ndev)
+    bounds = np.arange(ndev + 1, dtype=np.int64) * tpd * ntiles
+    return tpd, np.searchsorted(keys, bounds)
+
+
+def _sharded(keys: np.ndarray, mesh: Mesh, axis: str, fill,
+             **fields) -> BlockedGraph:
+    """A layout sharded over `mesh`'s `axis` (module doc): `keys` is its
+    sorted key list, ``fill(s, e, out)`` writes the blocks at sorted
+    positions s:e into the first e - s rows of a slab, and `fields` are
+    the other `BlockedGraph` fields. Device k's slab is built on the
+    host and placed on the devices of axis index k before the next one
+    is built."""
+    ndev = int(mesh.shape[axis])
+    ntiles, t = fields["ntiles"], fields["tile"]
+    tpd, starts = _split(keys, ntiles, ndev)
+    counts = np.diff(starts)
+    # >= 1 so a device owning no block still has a slot, never live
+    slots = max(1, int(counts.max()))
+    bsrc = np.zeros((ndev, slots), np.int32)
+    bdst = np.zeros((ndev, slots), np.int32)
+    live = np.arange(slots)[None, :] < counts[:, None]
+    for k in range(ndev):
+        s, e = int(starts[k]), int(starts[k + 1])
+        if e == s:
+            continue
+        bsrc[k, :e - s] = keys[s:e] % ntiles
+        bdst[k, :e - s] = keys[s:e] // ntiles - k * tpd
+        # padding repeats the last block's tile pair, so a dense sweep
+        # never revisits an earlier destination
+        bsrc[k, e - s:], bdst[k, e - s:] = bsrc[k, e - s - 1], \
+            bdst[k, e - s - 1]
+    sharding = NamedSharding(mesh, P(axis))
+    shape = (ndev * (slots + 1), t, t)
+    zero = np.float32(fields["algebra"].semiring.zero)
+    with span("flip.shard", devices=ndev, slots=slots):
+        placed = {}
+        for dev, idx in sharding.addressable_devices_indices_map(
+                shape).items():
+            k = (idx[0].start or 0) // (slots + 1)
+            placed.setdefault(k, []).append(dev)
+        shards = []
+        for k, devs in sorted(placed.items()):
+            slab = np.full((slots + 1, t, t), zero, np.float32)
+            fill(int(starts[k]), int(starts[k + 1]), slab)
+            shards += [jax.device_put(slab, dev) for dev in devs]
+            del slab
+        blocks = jax.make_array_from_single_device_arrays(shape, sharding,
+                                                          shards)
+        stream = [jax.device_put(a.reshape(-1), sharding)
+                  for a in (bsrc, bdst, live)]
+    layout = ShardLayout(mesh=mesh, axis=axis, tiles_per_dev=tpd,
+                         slots=slots, starts=starts, keys=keys)
+    return BlockedGraph(
+        blocks=blocks, blocks_ext=blocks, bsrc=stream[0], bdst=stream[1],
+        live=stream[2], shards=layout,
+        dst_start=np.searchsorted(keys // ntiles,
+                                  np.arange(ntiles + 1)).astype(np.int32),
+        bsrc_np=bsrc.reshape(-1), **fields)
 
 
 # --------------------------------------------------------------------- #
@@ -405,12 +636,21 @@ def tile_activity(src_vals, semiring: Semiring, features: bool = False):
     return act
 
 
+def block_activity(tile_act, bsrc, live=None):
+    """(nslots,) bool: slots whose source tile is active and that hold a
+    block -- the blocks a compacted step streams."""
+    act = jnp.take(tile_act, bsrc)
+    return act if live is None else jnp.logical_and(act, live)
+
+
 @jax.jit
-def compact_block_stream(tile_act, bsrc, bdst):
+def compact_block_stream(tile_act, bsrc, bdst, live=None):
     """Stable compaction of the active blocks to the front of a fixed-size
     index list (masked cumsum + scatter -- never a sort: the list is
     already (bdst, bsrc)-sorted and stability preserves that, keeping the
     kernel's consecutive-destination accumulation semantics intact).
+    `live` ((nb,) bool, optional) marks the slots that hold a block; a
+    slot that does not is never active.
 
     Returns ``(bsel, bsrc_c, bdst_c, n_active)``:
       * bsel   (nb,) i32 -- slot i's index into ``blocks_ext``; slots
@@ -422,7 +662,7 @@ def compact_block_stream(tile_act, bsrc, bdst):
       * n_active -- traced active-block count.
     """
     nb = bsrc.shape[0]
-    act = jnp.take(tile_act, bsrc)
+    act = block_activity(tile_act, bsrc, live)
     pos = jnp.cumsum(act.astype(jnp.int32)) - 1
     n_active = jnp.sum(act.astype(jnp.int32))
     sel = jnp.full((nb,), nb, dtype=jnp.int32)
@@ -448,6 +688,7 @@ def _relax_jnp(src_vals, carry, blocks, bsrc, bdst,
     """
     tax = -3 if features else -2
     ntiles = carry.shape[tax]
+    blocks = blocks[:bsrc.shape[0]]    # a sharded slab's sentinel stays out
     sv = jnp.take(src_vals, bsrc, axis=tax)          # (..., nb, T[, d])
     if features:
         cand = semiring.contract_jnp(sv, blocks)     # (..., nb, T, d)
@@ -530,9 +771,14 @@ def relax_grid(bg: BlockedGraph, batch: int, mode: str = "auto",
     """``(path, grid steps per relax step)`` that `frontier_relax` takes
     for a (B, ntiles, T[, d]) state over `bg`, from static shapes alone:
     the Pallas kernel's 'grouped' or 'slab' grid, or ('jnp', 0) off the
-    Pallas paths."""
+    Pallas paths. On a sharded layout, those of each device's call."""
     if resolve_relax_mode(mode) == "jnp":
         return "jnp", 0
+    sh = bg.shards
+    if sh is not None:      # per device: the whole state into its slab
+        path = relax_path(batch, sh.ntiles_p, sh.tiles_per_dev, bg.tile,
+                          feature_dim)
+        return path, relax_grid_steps(path, sh.slots, batch)
     path = relax_path(batch, bg.ntiles, bg.ntiles, bg.tile, feature_dim)
     return path, relax_grid_steps(path, int(bg.bsrc.shape[0]), batch)
 
@@ -589,7 +835,7 @@ def frontier_relax(src_vals, carry, bg: BlockedGraph, mode: str = "auto",
                                      interpret=interpret,
                                      feature_dim=feature_dim)
     bsel, bsrc_c, bdst_c, n_active = compact_block_stream(
-        tile_activity(src_vals, sr, features), bg.bsrc, bg.bdst)
+        tile_activity(src_vals, sr, features), bg.bsrc, bg.bdst, bg.live)
     return frontier_relax_pallas(src_vals, carry, bg.blocks_ext, bsrc_c,
                                  bdst_c, semiring=sr, interpret=interpret,
                                  bsel=bsel, feature_dim=feature_dim,

@@ -18,7 +18,11 @@ machinery already computes and would otherwise throw away:
   * ``step_wall_s``      (steps,)   -- host-measured per-step wall time;
     only the host-driven fixpoint can observe it (the on-device
     `lax.while_loop` exposes no per-iteration clock), so it is None on
-    the device paths.
+    the device paths;
+  * ``shard_live_max`` / ``shard_live_mean`` (steps,) -- sharded
+    fixpoint only: the live slots (blocks streamed) of the fullest
+    device and the mean over devices, which read the shards' imbalance
+    (``blocks_fetched`` is their sum over devices).
 
 One engine fixpoint produces one `DispatchTelemetry`; a `QueryResult`
 carries a `QueryTelemetry` aggregating the dispatches of that query
@@ -50,6 +54,10 @@ class StepTrace:
     blocks_skipped: np.ndarray           # (steps,)   i32
     converged: np.ndarray                # (steps, B) bool
     step_wall_s: np.ndarray | None = None   # (steps,) f64, host path only
+    # sharded fixpoint only: live slots (blocks streamed) of the fullest
+    # device and the mean over devices; `blocks_fetched` is their sum
+    shard_live_max: np.ndarray | None = None    # (steps,) i32
+    shard_live_mean: np.ndarray | None = None   # (steps,) f64
 
     def __len__(self) -> int:
         return int(self.active_tiles.shape[0])
@@ -64,6 +72,9 @@ class StepTrace:
         }
         if self.step_wall_s is not None:
             d["step_wall_s"] = [float(x) for x in self.step_wall_s]
+        if self.shard_live_max is not None:
+            d["shard_live_max"] = self.shard_live_max.tolist()
+            d["shard_live_mean"] = [float(x) for x in self.shard_live_mean]
         return d
 
 

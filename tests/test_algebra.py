@@ -147,13 +147,14 @@ def test_pallas_compiled_matches_oracle(algo):
 
 @pytest.mark.skipif(len(jax.devices()) < 2,
                     reason="needs a real multi-device platform; the "
-                           "single-device CPU CI covers run_distributed "
+                           "single-device CPU CI covers the distributed path "
                            "via the forced-host subprocess tests")
 @pytest.mark.parametrize("algo", ["sssp", "pagerank"])
 def test_run_distributed_real_devices(algo):
     g = make_synthetic(96, 280, seed=4)
     ref, _ = reference.run(algo, g, 0)
-    got, steps = FlipEngine.build(g, algo, tile=32).run_distributed(0)
+    got, steps = FlipEngine.build(g, algo, tile=32).execute(
+        0, distributed=True)
     assert steps > 0
     _assert_close(got, ref, algo, "distributed")
 

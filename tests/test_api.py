@@ -69,14 +69,15 @@ def test_query_parity_solo_and_batch(algo, relax):
 @pytest.mark.parametrize("algo", ["sssp", "pagerank"])
 def test_query_parity_distributed(algo):
     """A distributed plan routes through the shard_map fixpoint and is
-    bit-exact vs run_distributed and vs the local path."""
+    bit-exact vs the engine's distributed execute and vs the local
+    path."""
     g = make_synthetic(48, 140, seed=5)
     plan = flip.ExecutionPlan(tile=16, relax_mode="jnp",
                               distributed=True)
     cq = flip.compile(g, algo, plan)
     assert cq.plan.distributed
     r = cq.query(3)
-    out, steps = _legacy(cq.engine, "run_distributed", 3)
+    out, steps = cq.engine.execute(3, distributed=True)
     np.testing.assert_array_equal(r.attrs, out)
     assert r.steps == steps
     local = flip.compile(
@@ -159,8 +160,6 @@ def test_legacy_shims_warn():
         eng.run(0)
     with pytest.warns(DeprecationWarning, match="run_batch"):
         eng.run_batch([0, 1])
-    with pytest.warns(DeprecationWarning, match="run_distributed"):
-        eng.run_distributed(0)
     prev, _ = _legacy(eng, "run", 0)
     batch = _monotone_batch(g)
     eng2, delta = eng.apply_updates(g.apply_updates(batch), batch)

@@ -86,7 +86,7 @@ def test_engine_distributed_matches_reference():
     for algo, src in [("bfs", 2), ("sssp", 2), ("wcc", 0),
                       ("widest", 2), ("reach", 2), ("pagerank", 0)]:
         eng = FlipEngine.build(g, algo, tile=32)
-        got, steps = eng.run_distributed(src)    # (result, steps) like run
+        got, steps = eng.execute(src, distributed=True)  # like execute
         assert steps >= 1, algo
         ref, _ = reference.run(algo, g, src)
         assert ALGEBRAS[algo].results_match(got, ref), algo
@@ -109,12 +109,12 @@ def test_engine_distributed_batched_and_zero_block_devices():
     for algo in ("sssp", "pagerank"):
         eng = FlipEngine.build(g, algo, tile=32)
         srcs = np.array([5, 0, 17, 23])
-        outs, steps = eng.run_distributed(srcs)
+        outs, steps = eng.execute(srcs, distributed=True)
         assert outs.shape == (4, g.n) and steps.shape == (4,)
         for b, s in enumerate(srcs):
             ref, _ = reference.run(algo, g, int(s))
             assert ALGEBRAS[algo].results_match(outs[b], ref), (algo, b)
-            solo, st = eng.run_distributed(int(s))
+            solo, st = eng.execute(int(s), distributed=True)
             assert np.array_equal(outs[b], solo), (algo, b)
             assert steps[b] == st, (algo, b)
     print("OK")

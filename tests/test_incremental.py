@@ -338,7 +338,7 @@ def test_run_distributed_warm_start():
     eng2, delta = eng.apply_updates(g2, batch)
     assert delta.monotone
     warm = WarmStart(prev, delta.affected_src)
-    got, _ = eng2.run_distributed(3, warm=warm)
+    got, _ = eng2.execute(3, warm=warm, distributed=True)
     np.testing.assert_array_equal(got, eng2.run(3)[0])
 
 

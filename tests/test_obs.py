@@ -173,10 +173,20 @@ def test_truncation_flag():
 
 
 def test_trace_distributed_raises():
+    # a distributed plan traces like a local one (bit-identical, with
+    # the shards' per-step counts); only a deadline, which needs host
+    # step boundaries, is still refused there
     g = make_road_network(96, seed=0)
     cq = flip.compile(g, "bfs", flip.ExecutionPlan(distributed=True))
+    r, rt = cq.query(0), cq.query(0, trace=True)
+    np.testing.assert_array_equal(r.attrs, rt.attrs)
+    assert r.steps == rt.steps
+    d = rt.telemetry.dispatches[0]
+    assert len(d.trace) == r.steps and d.meta["devices"] == 1
+    np.testing.assert_array_equal(d.trace.shard_live_max,
+                                  d.trace.blocks_fetched)
     with pytest.raises(ValueError, match="distributed"):
-        cq.query(0, trace=True)
+        cq.query(0, deadline_s=5.0)
 
 
 # ---------------------------------------------------------------- #

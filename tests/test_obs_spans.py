@@ -74,6 +74,23 @@ def test_solo_query_nests_dispatch_and_phases_in_order(g, tmp_path):
     assert all(a["end"] <= b["start"] for a, b in zip(spans, spans[1:]))
 
 
+def test_sharded_compile_and_query_spans(g, tmp_path):
+    # the slab build and placement is one set-up span; a sharded query
+    # has the local dispatch's phases, traced ones the readback too
+    ev = _profile(tmp_path, lambda: flip.compile(g, "sssp",
+                                                 _plan(distributed=True)))
+    shard, = [e for e in ev if e["name"] == "flip.shard"]
+    assert shard["attrs"]["devices"] == 1
+    cq = flip.compile(g, "sssp", _plan(distributed=True))
+    _warm(cq, ([0, 5], {}), ([0, 5], {"trace": True}))
+    ev = _profile(tmp_path / "query", lambda: (cq.query([0, 5]),
+                                               cq.query([0, 5], trace=True)))
+    plain, traced = [e for e in ev if e["name"] == "flip.dispatch"]
+    assert [e["name"] for e in _inside(plain, ev)] == PHASES
+    assert [e["name"] for e in _inside(traced, ev)] == PHASES + [
+        "flip.telemetry"]
+
+
 def test_bucketed_query_has_one_dispatch_per_bucket(g, tmp_path):
     cq = flip.compile(g, "sssp", _plan(compact=False, batch=2))
     srcs = [0, 5, 9, 17, 40]
@@ -143,6 +160,18 @@ def test_dense_fixpoint_program_is_named_flip_fixpoint(g):
             (bg.blocks, bg.blocks_ext, bg.bsrc, bg.bdst), attrs, aux,
             frontier, eng._device_budgets(None, 2)).as_text()
         assert "module @jit_flip_fixpoint" in text
+
+
+def test_sharded_fixpoint_program_is_named_flip_fixpoint_sharded(g):
+    eng = flip.compile(g, "sssp", _plan(distributed=True)).engine
+    attrs, aux, frontier = eng.initial_state([0, 5])
+    bg = eng.bg
+    assert bg.shards is not None
+    for cap in (0, 16):
+        text = eng._dense_fixpoint_jit(cap).lower(
+            (bg.blocks, bg.bsrc, bg.bdst, bg.live), attrs, aux, frontier,
+            eng._device_budgets(None, 2)).as_text()
+        assert "module @jit_flip_fixpoint_sharded" in text
 
 
 def test_span_records_name_and_attributes(tmp_path):

@@ -160,3 +160,57 @@ def test_features_d128_solo(one_chip, semiring):
     assert "tpu_custom_call" in _compile(one_chip, semiring,
                                          (ntiles, T, 128), nb, nb,
                                          feature_dim=128)
+
+
+GRAPH500_S16 = (512, 64353)   # bench/configs/graph500-s16.json over 4
+                              # chips: (tiles, slots of the fullest chip)
+
+
+@pytest.mark.parametrize("trace_cap", [0, 1 << 14])
+def test_sharded_fixpoint_graph500_s16_four_chips(topo, monkeypatch,
+                                                  trace_cap):
+    """The whole sharded SSSP fixpoint at B=8 over a described 2x2 v5e
+    (the `kron16-sssp-4chip` cell's program): each chip's arguments are
+    its own slab and stream and the replicated state -- nothing else of
+    the graph -- it runs the grouped kernel, and the step ends in an
+    all-gather."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.algebra import get_algebra
+    from repro.core.engine import FlipEngine
+    from repro.kernels.frontier.ops import BlockedGraph, ShardLayout
+    # the described chips, not this process's CPU, are the target
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    ntiles, slots = GRAPH500_S16
+    b, n, nb = 8, ntiles * T, 257200
+    shard = NamedSharding(mesh, P("data"))
+    whole = NamedSharding(mesh, P())
+
+    def sds(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    layout = (sds((4 * (slots + 1), T, T), jnp.float32, shard),
+              sds((4 * slots,), jnp.int32, shard),
+              sds((4 * slots,), jnp.int32, shard),
+              sds((4 * slots,), bool, shard))
+    bg = BlockedGraph(
+        n=n, tile=T, ntiles=ntiles, blocks=layout[0], bsrc=layout[1],
+        bdst=layout[2], perm=np.arange(n), inv_perm=np.arange(n),
+        algebra=get_algebra("sssp"), blocks_ext=layout[0],
+        dst_start=np.zeros(ntiles + 1, np.int32),
+        bsrc_np=np.zeros(0, np.int32), live=layout[3],
+        shards=ShardLayout(mesh=mesh, axis="data", tiles_per_dev=ntiles // 4,
+                           slots=slots, starts=np.zeros(5, np.int64),
+                           keys=np.zeros(nb, np.int64)))
+    eng = FlipEngine(bg=bg, algo="sssp", relax_mode="pallas")
+    state = sds((b, ntiles, T), jnp.float32, whole)
+    compiled = eng._dense_fixpoint_jit(trace_cap, bg).lower(
+        layout, state, state, sds((b, ntiles, T), bool, whole),
+        sds((b,), jnp.int32, whole)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+    slab = (slots + 1) * T * T * 4
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert slab <= args <= slab + (16 << 20)
