@@ -71,7 +71,7 @@ from repro.kernels.frontier.ops import (BlockedGraph, UpdateDelta,
                                         block_activity, build_blocks,
                                         frontier_relax, mesh_key,
                                         relax_grid, resolve_relax_mode,
-                                        tile_activity)
+                                        tile_activity, walk_activity)
 from repro.obs.spans import span
 from repro.obs.telemetry import DispatchTelemetry, StepTrace
 from repro.resilience.errors import InvalidRequest
@@ -124,10 +124,13 @@ class ExecutionDetail:
 
 def _layout_args(bg: BlockedGraph) -> tuple:
     """A layout's arrays as the fixpoint program takes them: a sharded
-    layout holds its slabs once and marks its live slots."""
+    layout holds its slabs once and marks its live slots. A program that
+    walks its stream in destination order never reads the source walk,
+    and the compiled program drops it."""
+    walk = (bg.src_order, bg.src_tiles)
     if bg.shards is None:
-        return (bg.blocks, bg.blocks_ext, bg.bsrc, bg.bdst)
-    return (bg.blocks, bg.bsrc, bg.bdst, bg.live)
+        return (bg.blocks, bg.blocks_ext, bg.bsrc, bg.bdst) + walk
+    return (bg.blocks, bg.bsrc, bg.bdst, bg.live) + walk
 
 
 def mapping_order(mapping: Mapping) -> np.ndarray:
@@ -290,6 +293,11 @@ class FlipEngine:
         return jax.lax.all_gather(relax(sv, mine), sh.axis, axis=1,
                                   tiled=True)
 
+    def _relax_walk(self, bg: BlockedGraph, batch: int) -> str:
+        """The order the relax of a batch of `batch` rows over `bg` walks
+        its block stream in (`ops.relax_grid`)."""
+        return relax_grid(bg, batch, self.relax_mode, self.feature_dim)[2]
+
     def _step_stats(self, sv, frontier, bg: BlockedGraph | None = None):
         """One trace row's worth of per-step stats, computed from the
         exact quantities the compaction machinery derives anyway: the
@@ -302,22 +310,39 @@ class FlipEngine:
         as i32; `fetched` is the blocks streamed from HBM this step
         (active blocks under compaction, all blocks under dense). On a
         device of the sharded fixpoint `fetched` is summed over the
-        devices, and a fourth entry is the largest device's share."""
+        devices, and a fourth entry is the largest device's share. Under
+        the source walk a last entry counts the runs of one source tile
+        in the stream, the broadcasts the kernel builds: the source
+        tiles with a streamed block (summed over the devices too)."""
         bg = self.bg if bg is None else bg
         act = tile_activity(sv, bg.semiring, self._features)  # (ntiles,)
         active_tiles = jnp.sum(act.astype(jnp.int32))
-        if self._use_compact:
+        runs = ()
+        if self._relax_walk(bg, sv.shape[0]) == "src":
+            # in the walk's order, as the relax streams them: a run starts
+            # at each streamed slot whose source differs from the slot
+            # before it (the walk keeps a source's slots together)
+            tiles = bg.src_tiles
+            streamed = (walk_activity(act, tiles) if self._use_compact
+                        else tiles >= 0)
+            starts = jnp.concatenate([jnp.ones((1,), bool),
+                                      tiles[1:] != tiles[:-1]])
+            runs = (jnp.sum(jnp.logical_and(streamed, starts)
+                            .astype(jnp.int32)),)
+        elif self._use_compact:
             streamed = block_activity(act, bg.bsrc, bg.live)
         else:
             streamed = (jnp.ones(bg.bsrc.shape, bool) if bg.live is None
                         else bg.live)
         fetched = jnp.sum(streamed.astype(jnp.int32))
         active_v = jnp.sum(frontier, axis=(1, 2)).astype(jnp.int32)
+        stats = (active_v, active_tiles, fetched)
         if bg.shards is None:
-            return active_v, active_tiles, fetched
+            return stats + runs
         axis = bg.shards.axis
         return (active_v, active_tiles, jax.lax.psum(fetched, axis),
-                jax.lax.pmax(fetched, axis))
+                jax.lax.pmax(fetched, axis)) + tuple(
+                    jax.lax.psum(r, axis) for r in runs)
 
     def _step_stats_jit(self):
         """`_step_stats` as one cached jitted dispatch: the host-driven
@@ -439,14 +464,16 @@ class FlipEngine:
         rows = min(n_iter, trace_cap)
         bufs = [np.asarray(x)[:rows] for x in bufs]
         b_av, b_at, b_bf, b_cv = bufs[0], bufs[1], bufs[2], bufs[-1]
-        shard = {}
+        extra = {}
         if bg.shards is not None:
-            shard = dict(shard_live_max=bufs[3],
+            extra = dict(shard_live_max=bufs[3],
                          shard_live_mean=b_bf / bg.shards.ndev)
+        if self._relax_walk(bg, b_av.shape[1]) == "src":
+            extra["src_runs"] = bufs[-2]
         trace = StepTrace(active_vertices=b_av, active_tiles=b_at,
                           blocks_fetched=b_bf,
                           blocks_skipped=np.int32(bg.n_blocks) - b_bf,
-                          converged=b_cv, **shard)
+                          converged=b_cv, **extra)
         return trace, n_iter > trace_cap
 
     def _dense_fixpoint_jit(self, trace_cap: int,
@@ -515,9 +542,11 @@ class FlipEngine:
                       budgets)
             if trace_cap:
                 # active vertices, active tiles, blocks fetched[, the
-                # largest device's live slots], converged
+                # largest device's live slots][, source runs], converged
                 rows = ([((b,), jnp.int32)] + [((), jnp.int32)] * 2
                         + [((), jnp.int32)] * (sh is not None)
+                        + [((), jnp.int32)]
+                        * (self._relax_walk(bg, b) == "src")
                         + [((b,), bool)])
                 bufs0 = tuple(jnp.zeros((trace_cap,) + shape, dtype)
                               for shape, dtype in rows)
@@ -529,10 +558,12 @@ class FlipEngine:
         # the profiler's `XLA Modules` line
         @jax.jit
         def flip_fixpoint(layout, attrs0, aux0, frontier0, budgets):
-            blocks, blocks_ext, bsrc, bdst = layout
+            blocks, blocks_ext, bsrc, bdst, src_order, src_tiles = layout
             return loop(dataclasses.replace(bg, blocks=blocks,
                                             blocks_ext=blocks_ext,
-                                            bsrc=bsrc, bdst=bdst),
+                                            bsrc=bsrc, bdst=bdst,
+                                            src_order=src_order,
+                                            src_tiles=src_tiles),
                         attrs0, aux0, frontier0, budgets)
 
         if sh is None:
@@ -550,10 +581,12 @@ class FlipEngine:
                            in_specs=(P(sh.axis), P(), P(), P(), P()),
                            out_specs=P(), check_vma=False)
         def per_device(layout, attrs0, aux0, frontier0, budgets):
-            blocks, bsrc, bdst, live = layout
+            blocks, bsrc, bdst, live, src_order, src_tiles = layout
             return loop(dataclasses.replace(bg, blocks=blocks,
                                             blocks_ext=blocks, bsrc=bsrc,
-                                            bdst=bdst, live=live),
+                                            bdst=bdst, live=live,
+                                            src_order=src_order,
+                                            src_tiles=src_tiles),
                         attrs0, aux0, frontier0, budgets)
 
         # `jit_flip_fixpoint_sharded` on the profiler's `XLA Modules` line
@@ -624,8 +657,7 @@ class FlipEngine:
                     if n_iter < trace_cap:
                         # stats stay on device until after the loop:
                         # only the row tuple is kept per step
-                        av, at, bf = st
-                        rows.append((av, at, bf, ~live))
+                        rows.append(tuple(st) + (~live,))
                 else:
                     attrs, aux, frontier = self._masked_step(
                         attrs, aux, frontier, jnp.asarray(live))
@@ -643,6 +675,8 @@ class FlipEngine:
         now) read back as ``(StepTrace, truncated)``."""
         nb = int(self.bg.bsrc.shape[0])
         bf = np.asarray([int(r[2]) for r in rows], dtype=np.int32)
+        runs = (np.asarray([int(r[3]) for r in rows], dtype=np.int32)
+                if self._relax_walk(self.bg, b) == "src" else None)
         trace = StepTrace(
             active_vertices=(np.stack([np.asarray(r[0]) for r in rows])
                              .astype(np.int32) if rows
@@ -651,9 +685,10 @@ class FlipEngine:
                                     dtype=np.int32),
             blocks_fetched=bf,
             blocks_skipped=np.int32(nb) - bf,
-            converged=(np.stack([r[3] for r in rows]) if rows
+            converged=(np.stack([r[-1] for r in rows]) if rows
                        else np.zeros((0, b), bool)),
-            step_wall_s=np.asarray(walls, dtype=np.float64))
+            step_wall_s=np.asarray(walls, dtype=np.float64),
+            src_runs=runs)
         return trace, truncated
 
     # -------------------------------------------------------------- #
@@ -843,8 +878,8 @@ class FlipEngine:
             trace, truncated = read_trace()
             sp.set_metadata(rows=len(trace.active_tiles))
             b = int(steps.shape[0])
-            path, grid_steps = relax_grid(bg, b, self.relax_mode,
-                                          self.feature_dim)
+            path, grid_steps, walk = relax_grid(bg, b, self.relax_mode,
+                                                self.feature_dim)
             meta = {}
             sh = bg.shards
             if sh is not None:
@@ -863,7 +898,7 @@ class FlipEngine:
                 trace=trace, wall_s=time.perf_counter() - t0,
                 truncated=truncated, tile=bg.tile,
                 feature_dim=self.feature_dim, relax_path=path,
-                relax_grid_steps=grid_steps, meta=meta)
+                relax_grid_steps=grid_steps, relax_walk=walk, meta=meta)
         return out, steps, tele, converged, expired
 
     # -------------------------------------------------------------- #

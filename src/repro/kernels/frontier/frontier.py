@@ -12,10 +12,10 @@ delta-PageRank.
 
 The frontier bitmask plays FLIP's packet-trigger role: a block whose
 source tile holds only ⊕-identity lanes leaves a query's output
-untouched (a `pl.when` on the slab grid, a select on the grouped grid),
-per query -- the kernel preserves the paper's "only active vertices
-scatter" property. Because the ⊕-identity annihilates ⊗, skipping such
-a block is exact.
+untouched (a `pl.when` on the slab grid, a select on the grouped grid,
+and on the source-run body the identity itself), per query -- the
+kernel preserves the paper's "only active vertices scatter" property.
+Because the ⊕-identity annihilates ⊗, skipping such a block is exact.
 
 Block-sparsity replaces the Inter-/Intra-Tables: `bsrc/bdst` (scalar-
 prefetched) name the tile pair of each block; position inside the block
@@ -25,10 +25,11 @@ destination's partial ⊕ accumulates in VMEM across consecutive slots.
 Compacted block streaming extends the skip to the memory system: the
 block stream is indexed through a scalar-prefetched selection list
 ``bsel`` (see `ops.compact_block_stream`), whose first ``n_active`` slots
-name the live blocks in (bdst, bsrc) order and whose tail repeats one
-all-identity sentinel block index. A stable compaction keeps the
-destination order. The dense stream is ``bsel = arange(nb)``,
-``n_active = nb``.
+name the live blocks in the order the call walks them and whose tail
+repeats one all-identity sentinel block index. A stable compaction keeps
+the layout's destination order, or through the layout's `src_order` the
+source order (`relax_walk`). The dense stream is ``bsel = arange(nb)``
+(``src_order`` under the source walk), ``n_active = nb``.
 
 The state is (B, ntiles, T) -- B independent queries over one shared
 block structure -- or (B, ntiles, T, d) for vector-valued vertex state.
@@ -54,6 +55,23 @@ same slot order, so both grids give the same bits. A device of the
 distributed fixpoint, whose source state holds more tiles than its
 destination slab, takes this grid too.
 
+Source-run body (grouped grid, B a multiple of 8, idempotent ⊕:
+`source_runs`). The row-by-row body rebuilds, every slot, a broadcast
+that depends only on (row, source tile). Here the call walks the stream
+source-major (`relax_walk` == 'src'), so each source tile owns one run
+of consecutive slots, and the body keeps the broadcast in a VMEM
+scratch: xs[c, i] is an (8, T) tile whose sublane r holds lane i of row
+8c + r's source tile on every lane, built at the call's first slot and
+where the source tile changes. Each slot then combines its block against
+it with no lane broadcast: acc = ⊕_i xs[c, i] ⊗ W[i, :] (W's row i
+broadcast over the sublanes, CHAINS interleaved partial sums), and one
+(8, T) merge into the 8 output rows of its destination. No trigger is
+read: an inactive row's broadcast is the ⊕-identity. Each candidate is
+one ⊗ of the same two f32 values as on the slab grid, and ⊕ is exact in
+any order, so the bits are the slab grid's. (+, ×) keeps the
+destination walk and the row-by-row body: its sums would change with
+the order.
+
 Slab grid (d > 1, or a state over the budget): grid = (nslots, B), one
 (slot, query) pair per step. The weight block's index map reads
 ``bsel[i]`` and ignores the query index, so each block is fetched once
@@ -75,13 +93,14 @@ for the tropical/boolean pairs, so each streamed block feeds B·d lanes.
 VMEM (T a multiple of 128, f32; d' = d rounded up to 128 lanes):
 
   grouped:  B * (ns' + ntiles') * T * 4 B + WEIGHT_BUFFERS * T*T * 4 B
+            + (B a multiple of 8) B * T*T * 4 B source broadcast
             (ns', ntiles' = tile counts rounded up to 8; one copy of
             each, as the block index never changes)
   slab d=1: 2 * (8*T + B*8*T + T*T + B*8*T) * 4 B
   slab d>1: 2 * (T*d' + B*T*d' + T*T + B*T*d') * 4 B
             + 8*T*d' * 4 B (min/max contraction transient)
 
-Examples: grouped 2.5 MiB at graph500-s15 (256 tiles, T=128) for B=8,
+Examples: grouped 3 MiB at graph500-s15 (256 tiles, T=128) for B=8,
 8.5 MiB for the 2^20-vertex road graph solo (8192 tiles), 64 MiB budget
 (v5e has 128 MiB; the call raises its scoped limit to what it holds plus
 8 MiB); slab 648 KiB for T=128, B=32, d=1; 2.8 MiB for T=128, B=8,
@@ -190,6 +209,10 @@ WEIGHT_BUFFERS = 8
 # plus the weight buffers. v5e has 128 MiB of VMEM; states above this
 # take the slab grid.
 GROUPED_VMEM_BUDGET = 64 << 20
+# independent ⊕ chains of the source-run body's slot: ⊕ over a block's
+# T source lanes as CHAINS interleaved partial sums, so consecutive
+# vector ops do not wait on each other (exact: ⊕ is idempotent there)
+CHAINS = 8
 # scoped VMEM granted above the resident bytes, for Mosaic's own scratch
 # and the (T, T) combine transient of one row
 _VMEM_HEADROOM = 8 << 20
@@ -203,10 +226,13 @@ def grouped_vmem_bytes(batch: int, ns: int, ntiles: int, t: int) -> int:
     """VMEM the grouped path holds at state (B, ns | ntiles, T): one copy
     each of the source and output state (their block index never
     changes, so the pipeline keeps a single buffer), laid out in
-    (8, 128) f32 tiles, plus WEIGHT_BUFFERS (T, T) weight buffers."""
+    (8, 128) f32 tiles, plus WEIGHT_BUFFERS (T, T) weight buffers, plus
+    at B a multiple of 8 the (B/8, T, 8, T) source broadcast of the
+    source-run body (B * T * T * 4 B)."""
     lanes = _round_up(t, 128)
     state = batch * (_round_up(ns, 8) + _round_up(ntiles, 8)) * lanes * 4
-    return state + WEIGHT_BUFFERS * _round_up(t, 8) * lanes * 4
+    runs = batch * t * lanes * 4 if batch % SLAB == 0 else 0
+    return state + WEIGHT_BUFFERS * _round_up(t, 8) * lanes * 4 + runs
 
 
 def relax_path(batch: int, ns: int, ntiles: int, t: int,
@@ -226,6 +252,22 @@ def relax_grid_steps(path: str, nslots: int, batch: int) -> int:
     """Grid steps of one relax call: ceil(nslots / GROUP) on the grouped
     path (dead groups run an empty step), nslots * B on the slab grid."""
     return -(-nslots // GROUP) if path == "grouped" else nslots * batch
+
+
+def source_runs(batch: int, semiring: Semiring) -> bool:
+    """Whether the grouped grid relaxes a batch with the source-run body
+    (module doc): B a multiple of 8 under an idempotent ⊕, whose merges
+    are exact in any slot order."""
+    return batch % SLAB == 0 and semiring.idempotent
+
+
+def relax_walk(path: str, batch: int, semiring: Semiring) -> str:
+    """The order a relax call on `path` walks its block stream in: 'src'
+    (source-major, (bsrc, bdst)) where the grouped grid runs the
+    source-run body, else 'dst' (the layout's (bdst, bsrc) order)."""
+    if path == "grouped" and source_runs(batch, semiring):
+        return "src"
+    return "dst"
 
 
 def _row_triggers(src_vals, zero) -> jnp.ndarray:
@@ -250,17 +292,20 @@ def _make_grouped_kernel(semiring: Semiring, batch: int, group: int):
     VMEM-resident output. Weight blocks stay in HBM and are copied in by
     hand through a ring of WEIGHT_BUFFERS buffers, the copies of the
     next slots in flight while slot j relaxes, across group boundaries
-    too."""
+    too. At B a multiple of 8 under an idempotent ⊕ the body is the
+    source-run one (`source_runs`, module doc), whose broadcast scratch
+    `xs` is the last argument."""
     add, mul = semiring.add_jnp, semiring.mul_jnp
     add_reduce = semiring.add_reduce_jnp
     ahead = WEIGHT_BUFFERS - 1
+    runs = source_runs(batch, semiring)
 
     def _grouped_relax_kernel(nact_ref, bsrc_ref, bdst_ref, bsel_ref,
                               trig_ref, src_ref, carry_hbm, blocks_hbm,
-                              out_ref, wbuf, wsem, csem):
+                              out_ref, wbuf, wsem, csem, *xs):
         g = pl.program_id(0)
         n = nact_ref[0]
-        ns = src_ref.shape[1]
+        ns, t = src_ref.shape[1], src_ref.shape[2]
 
         def fetch(j):
             k = j % WEIGHT_BUFFERS
@@ -283,6 +328,46 @@ def _make_grouped_kernel(semiring: Semiring, batch: int, group: int):
             fetch(j).wait()
             w = wbuf.at[j % WEIGHT_BUFFERS]
             s, d = bsrc_ref[j], bdst_ref[j]
+
+            if runs:
+                # a run of slots with one source tile shares the tile's
+                # broadcast: xs[c, i] is (8, T), sublane r holding row
+                # 8c + r's lane i on every lane. It is built at the
+                # call's first slot and where the source tile changes;
+                # grid steps run in order, so it outlives them
+                @pl.when(jnp.logical_or(
+                        j == 0, s != bsrc_ref[jnp.maximum(j - 1, 0)]))
+                def _build():
+                    def build(c, carry):
+                        b0 = pl.multiple_of(c * SLAB, SLAB)
+                        v = src_ref[pl.ds(b0, SLAB), s, :]      # (8, T)
+                        for i in range(t):
+                            xs[0][c, i] = jnp.broadcast_to(
+                                v[:, i:i + 1], (SLAB, t))
+                        return carry
+                    jax.lax.fori_loop(0, batch // SLAB, build, 0)
+
+                # 8 rows at once: ⊕_i xs[c, i] ⊗ W[i, :], W's row i
+                # broadcast over the sublanes, summed in CHAINS
+                # independent chains, then one (8, T) merge. An inactive
+                # row's broadcast is the ⊕-identity, which annihilates ⊗,
+                # so it needs no trigger: its merge is an exact no-op
+                def rows8_runs(c, carry):
+                    b0 = pl.multiple_of(c * SLAB, SLAB)
+                    acc = [None] * CHAINS
+                    for i in range(t):
+                        p = mul(xs[0][c, i], w[pl.ds(i, 1), :])
+                        k = i % CHAINS
+                        acc[k] = p if acc[k] is None else add(acc[k], p)
+                    while len(acc) > 1:
+                        acc = [add(acc[k], acc[k + 1]) if k + 1 < len(acc)
+                               else acc[k] for k in range(0, len(acc), 2)]
+                    rows = (pl.ds(b0, SLAB), d, slice(None))
+                    out_ref[rows] = add(out_ref[rows], acc[0])
+                    return carry
+
+                return jax.lax.fori_loop(0, batch // SLAB, rows8_runs,
+                                         carry)
 
             def merge(b, cand):                         # cand: (1, T)
                 cur = out_ref[b, pl.ds(d, 1)]
@@ -331,6 +416,11 @@ def _relax_grouped(src_vals, carry, blocks, bsrc, bdst, bsel, n_active,
     batch, ns, t = src_vals.shape
     ntiles = carry.shape[1]
     nslots = bsrc.shape[0]
+    # the source-run body's broadcast scratch; it reads no trigger bits
+    runs = ([pltpu.VMEM((batch // SLAB, t, SLAB, t), jnp.float32)]
+            if source_runs(batch, semiring) else [])
+    trig = (jnp.zeros((1,), jnp.int32) if runs
+            else _row_triggers(src_vals, semiring.zero))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(-(-nslots // group),),
@@ -344,7 +434,7 @@ def _relax_grouped(src_vals, carry, blocks, bsrc, bdst, bsel, n_active,
                                memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((WEIGHT_BUFFERS, t, t), jnp.float32),
                         pltpu.SemaphoreType.DMA((WEIGHT_BUFFERS,)),
-                        pltpu.SemaphoreType.DMA(())],
+                        pltpu.SemaphoreType.DMA(())] + runs,
     )
     kwargs = {}
     if interpret:
@@ -361,7 +451,7 @@ def _relax_grouped(src_vals, carry, blocks, bsrc, bdst, bsel, n_active,
         input_output_aliases={6: 0},   # alias carry -> out
         **kwargs,
     )(jnp.reshape(n_active, (1,)).astype(jnp.int32), bsrc, bdst, bsel,
-      _row_triggers(src_vals, semiring.zero), src_vals, carry, blocks)
+      trig, src_vals, carry, blocks)
 
 
 @functools.partial(jax.jit,

@@ -29,6 +29,16 @@ while every shape stays static (no recompiles). Because the ⊕-identity
 annihilates ⊗, the sentinel relax is an exact no-op, so compacted results
 are bit-for-bit the dense-streaming results.
 
+Where the kernel runs its source-run body (`frontier.relax_walk` ==
+'src': B a multiple of 8 under an idempotent ⊕), the stream is walked
+source-major instead, through a static permutation per layout built
+once on the host: `BlockedGraph.src_order` (a stable argsort of bsrc)
+and `src_tiles` (the source tile of each slot in that order). The
+compaction reads the activity mask through `src_tiles` and scatters
+`src_order`'s entries, so the blocks stay in place, in one layout, and
+the active prefix names them in (bsrc, bdst) order: the same masked
+cumsum and scatter, never a sort on the device, and no more gathers.
+
 On the jnp/CPU path the same activity mask drives a gather of only the
 active blocks before the segment-⊕. Static shapes under `jit` cannot
 shrink, so when called with concrete (non-traced) arrays the active list
@@ -45,7 +55,8 @@ sentinel, built on the host one device at a time and placed on its own
 device only: the whole layout never exists on one device, and each
 device holds its weights once (the slab is both the dense and the
 sentinel-extended stream). Padding slots are not `live`, so compaction
-never streams them.
+never streams them. Each slab has its own `src_order` and `src_tiles`,
+over its slots.
 """
 from __future__ import annotations
 
@@ -61,7 +72,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.algebra import MIN_PLUS, Semiring, VertexAlgebra, get_algebra
 from repro.graphs.csr import Graph
 from repro.kernels.frontier.frontier import (frontier_relax_pallas,
-                                             relax_grid_steps, relax_path)
+                                             relax_grid_steps, relax_path,
+                                             relax_walk)
 from repro.obs.spans import span
 
 
@@ -145,6 +157,12 @@ class BlockedGraph:
     # A sharded slab's padding is not live, so compaction skips it.
     live: jnp.ndarray = None
     shards: ShardLayout = None  # set on a sharded layout
+    # the source walk (`frontier.relax_walk`, `_src_walk`), per device
+    # on a sharded layout: (nslots,) i32 slot positions in source-major
+    # (bsrc, bdst) order, and each one's source tile (-1: no block). The
+    # blocks stay where they are.
+    src_order: jnp.ndarray = None
+    src_tiles: jnp.ndarray = None
 
     def __post_init__(self):
         # precompute eagerly (construction always happens on the host):
@@ -160,6 +178,9 @@ class BlockedGraph:
                 np.arange(self.ntiles + 1)).astype(np.int32)
         if self.bsrc_np is None:
             self.bsrc_np = np.asarray(self.bsrc)
+        if self.src_order is None:
+            self.src_order, self.src_tiles = map(
+                jnp.asarray, _src_walk(self.bsrc_np))
 
     @property
     def padded_n(self) -> int:
@@ -548,6 +569,21 @@ def build_blocks(graph: Graph, algo: str | VertexAlgebra = "sssp",
     return block_keys(graph, algo, tile, order).build()
 
 
+def _src_walk(bsrc: np.ndarray,
+              live: np.ndarray | None = None) -> tuple[np.ndarray,
+                                                       np.ndarray]:
+    """The source walk of a (bdst, bsrc)-sorted stream: ``(order, tiles)``,
+    (nslots,) i32 each -- its slots' positions in (bsrc, bdst) order (a
+    stable sort by source keeps each source tile's destinations in
+    order) and the source tile of each, -1 where the slot holds no block
+    (not `live`)."""
+    order = np.argsort(bsrc, kind="stable").astype(np.int32)
+    tiles = bsrc[order].astype(np.int32)
+    if live is not None:
+        tiles = np.where(live[order], tiles, np.int32(-1))
+    return order, tiles
+
+
 def _split(keys: np.ndarray, ntiles: int,
            ndev: int) -> tuple[int, np.ndarray]:
     """``(tiles_per_dev, starts)`` of a layout sharded over `ndev`
@@ -603,13 +639,18 @@ def _sharded(keys: np.ndarray, mesh: Mesh, axis: str, fill,
             del slab
         blocks = jax.make_array_from_single_device_arrays(shape, sharding,
                                                           shards)
+        # each slab's source walk indexes its own slots; padding repeats
+        # the slab's last pair, so it sorts after that source's blocks
+        walk = [_src_walk(b, lv) for b, lv in zip(bsrc, live)]
         stream = [jax.device_put(a.reshape(-1), sharding)
-                  for a in (bsrc, bdst, live)]
+                  for a in (bsrc, bdst, live, np.stack([o for o, _ in walk]),
+                            np.stack([t for _, t in walk]))]
     layout = ShardLayout(mesh=mesh, axis=axis, tiles_per_dev=tpd,
                          slots=slots, starts=starts, keys=keys)
     return BlockedGraph(
         blocks=blocks, blocks_ext=blocks, bsrc=stream[0], bdst=stream[1],
-        live=stream[2], shards=layout,
+        live=stream[2], src_order=stream[3], src_tiles=stream[4],
+        shards=layout,
         dst_start=np.searchsorted(keys // ntiles,
                                   np.arange(ntiles + 1)).astype(np.int32),
         bsrc_np=bsrc.reshape(-1), **fields)
@@ -643,14 +684,24 @@ def block_activity(tile_act, bsrc, live=None):
     return act if live is None else jnp.logical_and(act, live)
 
 
+def walk_activity(tile_act, src_tiles):
+    """`block_activity` of the source walk's slots, in its order, from
+    the layout's `src_tiles` (-1: no block)."""
+    return jnp.logical_and(src_tiles >= 0,
+                           jnp.take(tile_act, src_tiles, mode="clip"))
+
+
 @jax.jit
-def compact_block_stream(tile_act, bsrc, bdst, live=None):
+def compact_block_stream(tile_act, bsrc, bdst, live=None, walk=None):
     """Stable compaction of the active blocks to the front of a fixed-size
     index list (masked cumsum + scatter -- never a sort: the list is
     already (bdst, bsrc)-sorted and stability preserves that, keeping the
     kernel's consecutive-destination accumulation semantics intact).
     `live` ((nb,) bool, optional) marks the slots that hold a block; a
-    slot that does not is never active.
+    slot that does not is never active. `walk` (optional; a layout's
+    ``(src_order, src_tiles)``) walks the slots in source order instead,
+    so the active blocks come out in (bsrc, bdst) order; `src_tiles`
+    marks the slots without a block then, and `live` is not read.
 
     Returns ``(bsel, bsrc_c, bdst_c, n_active)``:
       * bsel   (nb,) i32 -- slot i's index into ``blocks_ext``; slots
@@ -662,12 +713,16 @@ def compact_block_stream(tile_act, bsrc, bdst, live=None):
       * n_active -- traced active-block count.
     """
     nb = bsrc.shape[0]
-    act = block_activity(tile_act, bsrc, live)
+    if walk is None:
+        act = block_activity(tile_act, bsrc, live)
+    else:
+        act = walk_activity(tile_act, walk[1])
     pos = jnp.cumsum(act.astype(jnp.int32)) - 1
     n_active = jnp.sum(act.astype(jnp.int32))
     sel = jnp.full((nb,), nb, dtype=jnp.int32)
     sel = sel.at[jnp.where(act, pos, nb)].set(
-        jnp.arange(nb, dtype=jnp.int32), mode="drop")
+        jnp.arange(nb, dtype=jnp.int32) if walk is None else walk[0],
+        mode="drop")
     last = jnp.minimum(sel[jnp.maximum(n_active - 1, 0)], nb - 1)
     fill = jnp.where(jnp.arange(nb) < n_active, sel, last)
     return (sel, jnp.take(bsrc, fill), jnp.take(bdst, fill), n_active)
@@ -767,20 +822,25 @@ def resolve_relax_mode(mode: str) -> str:
 
 
 def relax_grid(bg: BlockedGraph, batch: int, mode: str = "auto",
-               feature_dim: int = 1) -> tuple[str, int]:
-    """``(path, grid steps per relax step)`` that `frontier_relax` takes
-    for a (B, ntiles, T[, d]) state over `bg`, from static shapes alone:
-    the Pallas kernel's 'grouped' or 'slab' grid, or ('jnp', 0) off the
-    Pallas paths. On a sharded layout, those of each device's call."""
+               feature_dim: int = 1) -> tuple[str, int, str]:
+    """``(path, grid steps per relax step, walk)`` that `frontier_relax`
+    takes for a (B, ntiles, T[, d]) state over `bg`, from static shapes
+    and the algebra alone: the Pallas kernel's 'grouped' or 'slab' grid
+    and the order it walks the stream in (`relax_walk`), or
+    ('jnp', 0, '') off the Pallas paths. On a sharded layout, those of
+    each device's call."""
     if resolve_relax_mode(mode) == "jnp":
-        return "jnp", 0
+        return "jnp", 0, ""
     sh = bg.shards
     if sh is not None:      # per device: the whole state into its slab
         path = relax_path(batch, sh.ntiles_p, sh.tiles_per_dev, bg.tile,
                           feature_dim)
-        return path, relax_grid_steps(path, sh.slots, batch)
-    path = relax_path(batch, bg.ntiles, bg.ntiles, bg.tile, feature_dim)
-    return path, relax_grid_steps(path, int(bg.bsrc.shape[0]), batch)
+        nslots = sh.slots
+    else:
+        path = relax_path(batch, bg.ntiles, bg.ntiles, bg.tile, feature_dim)
+        nslots = int(bg.bsrc.shape[0])
+    return (path, relax_grid_steps(path, nslots, batch),
+            relax_walk(path, batch, bg.semiring))
 
 
 def frontier_relax(src_vals, carry, bg: BlockedGraph, mode: str = "auto",
@@ -829,13 +889,22 @@ def frontier_relax(src_vals, carry, bg: BlockedGraph, mode: str = "auto",
                               semiring=sr, features=features)
         return _relax_jnp_bucketed(src_vals, carry, bg, features=features)
     interpret = mode == "interpret"
+    batch = src_vals.shape[0] if src_vals.ndim == 3 + features else 1
+    walk = (relax_grid(bg, batch, mode, feature_dim)[2] == "src")
     if not compact:
-        return frontier_relax_pallas(src_vals, carry, bg.blocks, bg.bsrc,
-                                     bg.bdst, semiring=sr,
-                                     interpret=interpret,
-                                     feature_dim=feature_dim)
+        if not walk:
+            return frontier_relax_pallas(src_vals, carry, bg.blocks,
+                                         bg.bsrc, bg.bdst, semiring=sr,
+                                         interpret=interpret,
+                                         feature_dim=feature_dim)
+        order = bg.src_order
+        return frontier_relax_pallas(
+            src_vals, carry, bg.blocks, jnp.take(bg.bsrc, order),
+            jnp.take(bg.bdst, order), semiring=sr, interpret=interpret,
+            bsel=order, feature_dim=feature_dim)
     bsel, bsrc_c, bdst_c, n_active = compact_block_stream(
-        tile_activity(src_vals, sr, features), bg.bsrc, bg.bdst, bg.live)
+        tile_activity(src_vals, sr, features), bg.bsrc, bg.bdst, bg.live,
+        (bg.src_order, bg.src_tiles) if walk else None)
     return frontier_relax_pallas(src_vals, carry, bg.blocks_ext, bsrc_c,
                                  bdst_c, semiring=sr, interpret=interpret,
                                  bsel=bsel, feature_dim=feature_dim,

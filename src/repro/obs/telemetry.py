@@ -22,7 +22,10 @@ machinery already computes and would otherwise throw away:
   * ``shard_live_max`` / ``shard_live_mean`` (steps,) -- sharded
     fixpoint only: the live slots (blocks streamed) of the fullest
     device and the mean over devices, which read the shards' imbalance
-    (``blocks_fetched`` is their sum over devices).
+    (``blocks_fetched`` is their sum over devices);
+  * ``src_runs``         (steps,)   -- source walk only (``relax_walk ==
+    'src'``): runs of one source tile in the streamed blocks, i.e. the
+    source broadcasts the relax kernel built (summed over devices).
 
 One engine fixpoint produces one `DispatchTelemetry`; a `QueryResult`
 carries a `QueryTelemetry` aggregating the dispatches of that query
@@ -58,6 +61,8 @@ class StepTrace:
     # device and the mean over devices; `blocks_fetched` is their sum
     shard_live_max: np.ndarray | None = None    # (steps,) i32
     shard_live_mean: np.ndarray | None = None   # (steps,) f64
+    # source walk only: source-tile runs in the stream (broadcasts built)
+    src_runs: np.ndarray | None = None          # (steps,) i32
 
     def __len__(self) -> int:
         return int(self.active_tiles.shape[0])
@@ -75,6 +80,8 @@ class StepTrace:
         if self.shard_live_max is not None:
             d["shard_live_max"] = self.shard_live_max.tolist()
             d["shard_live_mean"] = [float(x) for x in self.shard_live_mean]
+        if self.src_runs is not None:
+            d["src_runs"] = self.src_runs.tolist()
         return d
 
 
@@ -97,6 +104,7 @@ class DispatchTelemetry:
     feature_dim: int = 1    # feature width d of the vertex state
     relax_path: str = ""    # relax kernel grid: 'grouped' | 'slab' | 'jnp'
     relax_grid_steps: int = 0   # kernel grid steps per relax step
+    relax_walk: str = ""    # stream order: 'src' | 'dst' | '' (jnp)
     meta: dict = dataclasses.field(default_factory=dict)
 
     def summary(self) -> dict:
@@ -111,6 +119,8 @@ class DispatchTelemetry:
         """
         tr, nt = self.trace, max(self.ntiles, 1)
         nsteps = len(tr)
+        runs = (int(tr.src_runs.sum()) if tr.src_runs is not None
+                else 0)
         t, d = self.tile, max(self.feature_dim, 1)
         state_lane_bytes = 2 * self.batch * nt * t * d * 4  # rd + wr
         return {
@@ -121,6 +131,10 @@ class DispatchTelemetry:
             "feature_dim": d,
             "relax_path": self.relax_path,
             "relax_grid_steps": self.relax_grid_steps,
+            "relax_walk": self.relax_walk,
+            # blocks streamed per source broadcast built (source walk)
+            "slots_per_src_run": (int(tr.blocks_fetched.sum()) / runs
+                                  if runs else None),
             "steps_max": int(self.steps.max()) if self.steps.size else 0,
             "steps_mean": float(self.steps.mean()) if self.steps.size
             else 0.0,
@@ -148,6 +162,7 @@ class DispatchTelemetry:
             "feature_dim": self.feature_dim,
             "relax_path": self.relax_path,
             "relax_grid_steps": self.relax_grid_steps,
+            "relax_walk": self.relax_walk,
             "steps": [int(s) for s in np.atleast_1d(self.steps)],
             "wall_s": self.wall_s, "truncated": self.truncated,
             "meta": self.meta, "trace": self.trace.to_json(),

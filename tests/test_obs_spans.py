@@ -15,6 +15,7 @@ from jax.profiler import ProfileData
 
 from repro import api as flip
 from repro import obs
+from repro.core.engine import _layout_args
 from repro.graphs import make_road_network
 
 PHASES = ["flip.prepare", "flip.launch", "flip.wait", "flip.finalize"]
@@ -157,8 +158,8 @@ def test_dense_fixpoint_program_is_named_flip_fixpoint(g):
     bg = eng.bg
     for cap in (0, 16):
         text = eng._dense_fixpoint_jit(cap).lower(
-            (bg.blocks, bg.blocks_ext, bg.bsrc, bg.bdst), attrs, aux,
-            frontier, eng._device_budgets(None, 2)).as_text()
+            _layout_args(bg), attrs, aux, frontier,
+            eng._device_budgets(None, 2)).as_text()
         assert "module @jit_flip_fixpoint" in text
 
 
@@ -169,7 +170,7 @@ def test_sharded_fixpoint_program_is_named_flip_fixpoint_sharded(g):
     assert bg.shards is not None
     for cap in (0, 16):
         text = eng._dense_fixpoint_jit(cap).lower(
-            (bg.blocks, bg.bsrc, bg.bdst, bg.live), attrs, aux, frontier,
+            _layout_args(bg), attrs, aux, frontier,
             eng._device_budgets(None, 2)).as_text()
         assert "module @jit_flip_fixpoint_sharded" in text
 

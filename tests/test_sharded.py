@@ -29,13 +29,15 @@ SCRIPT = r"""
 import json
 import numpy as np
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from bench import graphs
 import repro.api.plan as plan_mod
 from repro import api as flip
 from repro.graphs.csr import Graph
-from repro.kernels.frontier.ops import block_keys, build_blocks
+from repro.kernels.frontier.ops import (block_keys, build_blocks,
+                                        compact_block_stream)
 
 csr = graphs.generate({"generator": "kronecker", "dataset_seed": 0,
                        "scale": 10, "edgefactor": 16,
@@ -44,7 +46,8 @@ g = Graph(indptr=csr.indptr, indices=csr.indices, weights=csr.weights,
           directed=False)
 roots = np.flatnonzero(np.diff(csr.indptr) > 0)[::37][:8]
 mesh = Mesh(np.array(jax.devices()), ("x",))
-out = {"layout": {}, "fixpoint": {}, "interpret": {}, "updates": {}}
+out = {"layout": {}, "walk": {}, "fixpoint": {}, "interpret": {},
+       "updates": {}}
 
 
 def same(a, b):
@@ -91,6 +94,38 @@ for tile in TILES:
                         and not live[k, c:].any())
     checks.update(ranges=ranges, slabs=slabs, padding=padding)
     out["layout"][str(tile)] = checks
+
+    # each slab's source walk: a permutation of its own slots in
+    # (bsrc, bdst) order with their source tiles (-1 on padding), through
+    # which compaction names the slab's live blocks of active source
+    # tiles -- never a padding slot -- and the sentinel tail
+    order = np.asarray(sh.src_order).reshape(4, lay.slots)
+    tiles = np.asarray(sh.src_tiles).reshape(4, lay.slots)
+    rng = np.random.default_rng(tile)
+    act = jnp.asarray(rng.random(lay.ntiles_p) < 0.5)
+    walk = {"permutation": True, "sorted": True, "tiles": True,
+            "same_blocks": True, "no_padding": True, "tail": True,
+            "padded_slab": False}
+    for k in range(4):
+        o = order[k]
+        walk["permutation"] &= sorted(o.tolist()) == list(range(lay.slots))
+        key = bsrc[k, o].astype(np.int64) * lay.ntiles_p + bdst[k, o]
+        walk["sorted"] &= bool((np.diff(key) >= 0).all())
+        walk["tiles"] &= same(tiles[k], np.where(live[k, o], bsrc[k, o], -1))
+        walk["padded_slab"] |= not live[k].all()
+        args = (act, jnp.asarray(bsrc[k]), jnp.asarray(bdst[k]),
+                jnp.asarray(live[k]))
+        sel, bs, bd, n = map(np.asarray, compact_block_stream(
+            *args, (jnp.asarray(o), jnp.asarray(tiles[k]))))
+        sel_d, _, _, n_d = map(np.asarray, compact_block_stream(*args))
+        n = int(n)
+        walk["same_blocks"] &= (n == int(n_d) and sorted(sel[:n].tolist())
+                                == sorted(sel_d[:n].tolist()))
+        walk["no_padding"] &= bool(live[k][sel[:n]].all())
+        walk["tail"] &= bool((sel[n:] == lay.slots).all())
+        walk["sorted"] &= bool((np.diff(bs[:n].astype(np.int64)
+                                        * lay.ntiles_p + bd[:n]) > 0).all())
+    out["walk"][str(tile)] = walk
 
 
 # ---- the fixpoint: local, default plan over a small budget, mesh ----- #
@@ -157,6 +192,15 @@ def compare(algo, relax_mode, batches, table):
                                 >= tr.blocks_fetched))),
             "gather_bytes": tt.meta.get("gather_bytes")
             == b * auto.engine.bg.shards.ntiles_p * FIX_TILE * 4,
+            # the walk each device takes is the local one; a source tile
+            # is a run on every device that streams a block of it
+            "walk": tt.relax_walk == tl.relax_walk == (
+                "" if relax_mode == "jnp" else
+                "src" if b % 8 == 0 and algo != "pagerank" else "dst"),
+            "src_runs": (tr.src_runs is None) == (tl.trace.src_runs is None)
+            and (tr.src_runs is None
+                 or bool((tr.src_runs >= tl.trace.src_runs).all()
+                         and (tr.src_runs <= tr.blocks_fetched).all())),
             "second_call_compiles": n_compiles,
         }
 
@@ -225,6 +269,16 @@ def seen():
 def test_sharded_layout_is_the_whole_layout_split_by_destination(seen,
                                                                  tile):
     checks = seen["layout"][str(tile)]
+    assert all(checks.values()), checks
+
+
+@pytest.mark.parametrize("tile", TILES)
+def test_sharded_source_walk_per_slab(seen, tile):
+    # at T=96 the last device's slab ends in padding slots
+    checks = seen["walk"][str(tile)]
+    if tile == FIX_TILE:
+        assert checks["padded_slab"]
+    checks.pop("padded_slab")
     assert all(checks.values()), checks
 
 
