@@ -2,8 +2,7 @@
 correctness cost; on TPU these dispatch to the Pallas kernels).
 
 Emits the per-algebra frontier-relax rows future PRs track, a batched
-(B, ntiles, T) relax row, the dense-vs-compacted frontier-density sweep
-(`bench_frontier_density`), the feature-width d-sweep
+(B, ntiles, T) relax row, the feature-width d-sweep
 (`bench_features`), and the end-to-end multi-query batching win:
 B=32 BFS sources on an LRN road network through one batched
 `CompiledQuery.query` fixpoint vs 32 sequential scalar queries on the
@@ -19,8 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks import (bench_features, bench_frontier_density,
-                        bench_incremental)
+from benchmarks import bench_features, bench_incremental
 from benchmarks.common import RESULTS, emit, timed, write_json
 from repro import api as flip
 from repro.algebra import ALGEBRAS
@@ -67,9 +65,6 @@ def run():
     _, us = timed(lambda: fb(batt, batt).block_until_ready(), repeats=20)
     emit(f"kernel_frontier_relax_{size}_bfs_b32", us,
          f"batched B=32 edges={g.m} blocks={bg.blocks.shape[0]}")
-
-    # dense vs frontier-compacted streaming across frontier densities
-    bench_frontier_density.run(fast)
 
     # feature-width (d) sweep: vector-state amortization of the weight
     # stream (matmul contraction vs sequential scalar steps)

@@ -31,7 +31,6 @@ def main() -> None:
                             bench_mapping_quality, bench_kernels,
                             bench_serving, bench_traffic_replay,
                             bench_features, bench_incremental,
-                            bench_frontier_density,
                             bench_telemetry_overhead, bench_autotune)
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -58,7 +57,6 @@ def main() -> None:
         # on a non-empty kwargs dict); fast honors BENCH_FAST
         (bench_features, dict(fast=fast)),
         (bench_incremental, dict(fast=fast)),
-        (bench_frontier_density, dict(fast=fast)),
         # overhead gate disabled here (inf): recorded only; the
         # telemetry-overhead CI job enforces the bound
         (bench_telemetry_overhead, dict(max_ratio=float("inf"))),

@@ -10,9 +10,8 @@
 Everything the fragmented `FlipEngine.run*` surface did -- solo runs,
 batched multi-query fixpoints, shard_map distribution, serving-style
 bucketed dispatch, streaming updates with incremental recompute -- is
-one `compile` + `query` pair driven by a validated `ExecutionPlan`.
-The legacy entry points survive as deprecated shims over the same
-executor.
+one `compile` + `query` pair driven by a validated `ExecutionPlan`,
+over the engine's one executor, `FlipEngine.execute`.
 """
 from repro.api.plan import (ExecutionPlan, plan_from_cli,
                             resolve_cli_engine)

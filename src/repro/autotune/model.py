@@ -117,7 +117,7 @@ def load_bench_samples(paths=None, tile_default: int = 64) -> list:
         root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__)))))
         paths = [os.path.join(root, f"BENCH_{tag}.json")
-                 for tag in ("kernels", "features", "frontier_density")]
+                 for tag in ("kernels", "features")]
     out: list = []
     for path in paths:
         try:

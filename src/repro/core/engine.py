@@ -16,12 +16,9 @@ Data-centric mode additionally streams the weight blocks *compacted* by
 the runtime frontier (``compact``, default on for data mode): only blocks
 whose source tile is active for some query leave HBM; the rest are stood
 in for by one VMEM-resident sentinel block (see
-`repro.kernels.frontier.ops`). On the Pallas/interpret paths the
-compaction runs on-device inside the `while_loop` with static shapes; on
-the jnp/CPU path static shapes cannot shrink, so the fixpoint is driven
-from the host instead (`_fixpoint_host`) and each step runs a
-power-of-two-bucketed compacted relax -- the step cost tracks the live
-frontier, O(active·T²), instead of O(nb·T²). Compaction is exact (the
+`repro.kernels.frontier.ops`). The compaction runs on the device inside
+the `while_loop` with static shapes; the jnp/CPU relax streams every
+block, which gives the same results. Compaction is exact (the
 ⊕-identity annihilates ⊗), so results and step counts are bit-for-bit
 the dense-streaming ones.
 
@@ -32,11 +29,12 @@ so a new algebra runs here unchanged.
 
 Execution is batched over independent queries: the state is
 (B, ntiles, T) -- B sources relaxing against one shared block structure
-inside one `jax.lax.while_loop` fixpoint. `FlipEngine.execute` is the
-single entry point (scalar source = the B=1 view; `distributed=True`
-switches to the shard_map fixpoint; `warm=` resumes a prior result) --
-the legacy `run`/`run_batch`/`run_updated` methods are deprecated shims
-over it, and `repro.api` ( `flip.compile(graph,
+inside one `jax.lax.while_loop` fixpoint, the one compiled program
+(`jit_flip_fixpoint`) every call runs on every backend; a deadline runs
+it one step per call, so the host sees each step boundary.
+`FlipEngine.execute` is the single entry point (scalar source = the B=1
+view; `distributed=True` switches to the shard_map fixpoint; `warm=`
+resumes a prior result), and `repro.api` ( `flip.compile(graph,
 program, plan).query(srcs)` ) is the intended front door. Queries whose
 frontier has emptied are frozen by a per-query convergence mask, so a
 long-tail query never perturbs finished ones and batched results are
@@ -57,7 +55,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +80,12 @@ from repro.resilience.errors import InvalidRequest
 # (only their trace rows are dropped, flagged `truncated`).
 TRACE_CAP_DEFAULT = 4096
 
+# the compiled fixpoint programs, shared by every engine whose trace is
+# the same (keyed in `FlipEngine._dense_fixpoint_jit`): sessions and
+# tests build many engines over alike layouts, and each would otherwise
+# compile its own loop. A program closes over static fields only.
+_PROGRAMS: dict = {}
+
 
 @dataclasses.dataclass
 class WarmStart:
@@ -96,7 +99,7 @@ class WarmStart:
     fixpoint relaxes only what the update batch can actually improve and
     converges in O(delta) steps instead of O(graph). Sound only for
     monotone algebras under a `Semiring.monotone_under` update batch --
-    `FlipEngine.run_updated` applies that dispatch automatically.
+    `FlipEngine.resolve_warm` applies that dispatch.
     """
     attrs: np.ndarray
     seeds: np.ndarray
@@ -256,24 +259,6 @@ class FlipEngine:
         return attrs, aux, jnp.asarray(
             frontier.reshape(b, bg.ntiles, bg.tile))
 
-    def _step(self, attrs, aux, frontier, with_stats: bool = False,
-              bg: BlockedGraph | None = None):
-        """One relax step over `bg` (default: the engine's own layout;
-        the device fixpoint passes a view whose arrays are its traced
-        arguments)."""
-        alg, features = self.algebra, self._features
-        bg = self.bg if bg is None else bg
-        sv, carry = alg.scatter_carry_jnp(attrs, frontier,
-                                          op_mode=(self.mode == "op"),
-                                          features=features)
-        new = self._relax(sv, carry, bg)
-        out = alg.post_step_jnp(attrs, aux, sv, new, features=features)
-        if not with_stats:
-            return out
-        if bg is self.bg:
-            return out, self._step_stats_jit()(sv, frontier)
-        return out, self._step_stats(sv, frontier, bg)
-
     def _relax(self, sv, carry, bg: BlockedGraph):
         """The step's relax over `bg`. Inside the sharded fixpoint `bg` is
         one device's shard of a sharded layout: the device relaxes the
@@ -298,7 +283,7 @@ class FlipEngine:
         its block stream in (`ops.relax_grid`)."""
         return relax_grid(bg, batch, self.relax_mode, self.feature_dim)[2]
 
-    def _step_stats(self, sv, frontier, bg: BlockedGraph | None = None):
+    def _step_stats(self, sv, frontier, bg: BlockedGraph):
         """One trace row's worth of per-step stats, computed from the
         exact quantities the compaction machinery derives anyway: the
         frontier entering the step, the per-tile activity of the
@@ -314,7 +299,6 @@ class FlipEngine:
         the source walk a last entry counts the runs of one source tile
         in the stream, the broadcasts the kernel builds: the source
         tiles with a streamed block (summed over the devices too)."""
-        bg = self.bg if bg is None else bg
         act = tile_activity(sv, bg.semiring, self._features)  # (ntiles,)
         active_tiles = jnp.sum(act.astype(jnp.int32))
         runs = ()
@@ -344,33 +328,31 @@ class FlipEngine:
                 jax.lax.pmax(fetched, axis)) + tuple(
                     jax.lax.psum(r, axis) for r in runs)
 
-    def _step_stats_jit(self):
-        """`_step_stats` as one cached jitted dispatch: the host-driven
-        fixpoint runs its step eagerly (it must read concrete frontiers),
-        so fusing the half-dozen stat reductions into a single call keeps
-        traced host steps within the overhead bound. Inside the jitted
-        while_loop body the same tracing inlines and the wrapper is
-        free."""
-        fn = self.__dict__.get("_step_stats_fn")
-        if fn is None:
-            fn = self.__dict__["_step_stats_fn"] = jax.jit(self._step_stats)
-        return fn
+    def _trace_columns(self, bg: BlockedGraph, b: int) -> list:
+        """``(shape, dtype)`` of each column of a trace row over `bg` at
+        batch `b`: active vertices, active tiles, blocks fetched[, the
+        largest device's live slots][, source runs], converged."""
+        return ([((b,), jnp.int32)] + [((), jnp.int32)] * 2
+                + [((), jnp.int32)] * (bg.shards is not None)
+                + [((), jnp.int32)] * (self._relax_walk(bg, b) == "src")
+                + [((b,), bool)])
 
-    def _masked_step(self, attrs, aux, frontier, live,
-                     with_stats: bool = False,
-                     bg: BlockedGraph | None = None):
-        """One relax step with the per-query freeze applied: queries not
-        in `live` ((B,) bool -- frontier emptied, or step/deadline budget
-        exhausted) keep their state *and their frontier* untouched, so a
-        budget-frozen query still reads as non-converged (frontier
-        non-empty) while a finished one stays finished (its frontier
-        emptied naturally). The single body behind both fixpoint
-        drivers, so host-driven and while_loop runs stay bit-for-bit
-        identical."""
-        stepped = self._step(attrs, aux, frontier, with_stats=with_stats,
-                             bg=bg)
-        (attrs_n, aux_n, frontier_n), stats = \
-            stepped if with_stats else (stepped, None)
+    def _masked_step(self, attrs, aux, frontier, live, bg: BlockedGraph,
+                     with_stats: bool = False):
+        """One relax step over `bg`, the fixpoint program's view of its
+        layout, with the per-query freeze applied: queries not in `live`
+        ((B,) bool -- frontier emptied, or step budget exhausted) keep
+        their state *and their frontier* untouched, so a budget-frozen
+        query still reads as non-converged (frontier non-empty) while a
+        finished one stays finished (its frontier emptied naturally)."""
+        alg, features = self.algebra, self._features
+        sv, carry = alg.scatter_carry_jnp(attrs, frontier,
+                                          op_mode=(self.mode == "op"),
+                                          features=features)
+        new = self._relax(sv, carry, bg)
+        attrs_n, aux_n, frontier_n = alg.post_step_jnp(
+            attrs, aux, sv, new, features=features)
+        stats = self._step_stats(sv, frontier, bg) if with_stats else None
         # live broadcasts from the query axis over every trailing state
         # axis: (B, 1, 1) against (B, ntiles, T), one more 1 at d > 1
         ms = live.reshape(live.shape + (1,) * (attrs.ndim - 1))
@@ -392,19 +374,18 @@ class FlipEngine:
         per-query step cap: a query that reaches its budget with a
         non-empty frontier is frozen exactly like a converged one but
         keeps its frontier, so the final per-query convergence mask
-        (returned as the 5th element) reads False for it -- a partial
+        (returned as the 6th element) reads False for it -- a partial
         result is always *flagged*, never silently truncated. Budgets
         are a traced argument of the one compiled while_loop, so
         varying them never retraces.
 
-        `deadlines_t` ((B,) absolute `time.monotonic` deadlines, +inf =
-        none) needs host-observable step boundaries, so any finite
-        deadline routes the fixpoint through the host driver (same
-        body, bit-for-bit results). Compacted jnp streaming routes
-        there too (concrete frontiers pick the bucket sizes). `bg`
-        (default: the engine's layout) may be a sharded layout: the
-        same loop then runs as the sharded program over its mesh
-        (`_dense_fixpoint_jit`), always on the devices.
+        Every call runs the one compiled program (`_dense_fixpoint_jit`)
+        on every backend; `bg` (default: the engine's layout) may be a
+        sharded layout, and the loop then runs as the sharded program
+        over its mesh. `deadlines_t` ((B,) absolute `time.monotonic`
+        deadlines from `_resolve_deadlines`, None = none) needs step
+        boundaries the host sees, so a deadlined call runs the same
+        program one step per call (`_fixpoint_deadlined`).
 
         `trace_cap > 0` additionally records one per-step stats row into
         fixed-shape (trace_cap, ...) buffers riding the carry (see
@@ -423,14 +404,10 @@ class FlipEngine:
         bg = self.bg if bg is None else bg
         b = attrs0.shape[0]
         budgets = self._device_budgets(budgets, b)
-        deadlined = (deadlines_t is not None
-                     and bool(np.isfinite(deadlines_t).any()))
-        if bg.shards is None and (
-                deadlined or (self._use_compact
-                              and self._resolved_relax_mode() == "jnp")):
-            return self._fixpoint_host(attrs0, aux0, frontier0, trace_cap,
-                                       budgets=budgets,
-                                       deadlines_t=deadlines_t)
+        if deadlines_t is not None:
+            return self._fixpoint_deadlined(attrs0, aux0, frontier0,
+                                            trace_cap, budgets, deadlines_t,
+                                            bg)
         with span("flip.launch"):
             out = self._dense_fixpoint_jit(trace_cap, bg)(
                 _layout_args(bg), attrs0, aux0, frontier0, budgets)
@@ -478,13 +455,20 @@ class FlipEngine:
 
     def _dense_fixpoint_jit(self, trace_cap: int,
                             bg: BlockedGraph | None = None):
-        """The whole device while_loop compiled as ONE jitted program per
-        (engine, trace_cap[, mesh]), cached on the instance: eager
-        per-call dispatch of the loop would otherwise dominate the step
-        cost (and blow the traced/untraced overhead bound). The traced
-        variant only adds fixed-shape stat-buffer writes to the carry,
-        so both compile to the same fused step with tracing as a few
-        extra reductions.
+        """The whole device while_loop compiled as ONE jitted program:
+        eager per-call dispatch of the loop would otherwise dominate the
+        step cost (and blow the traced/untraced overhead bound). The
+        traced variant only adds fixed-shape stat-buffer writes to the
+        carry, so both compile to the same fused step with tracing as a
+        few extra reductions.
+
+        Programs are shared by every engine whose trace is the same
+        (`_PROGRAMS`): the key holds everything the trace reads besides
+        its arguments -- the algebra, mode, resolved relax mode and
+        compaction, feature width, `trace_cap`, the layout's sizes and
+        mesh -- and `jax.jit` keys the argument shapes itself. A program
+        closes over static fields only, never a layout's arrays, so a
+        dropped layout's blocks are freed.
 
         The layout's arrays are arguments of the program, not values it
         closes over: a closed-over array is embedded in the program as a
@@ -500,11 +484,28 @@ class FlipEngine:
         engine's own layout."""
         bg = self.bg if bg is None else bg
         sh = bg.shards
-        cache = self.__dict__.setdefault("_fixpoint_cache", {})
-        key = (trace_cap, None if sh is None else sh.key())
-        fn = cache.get(key)
-        if fn is not None:
-            return fn
+        key = (trace_cap, self.mode, self._resolved_relax_mode(),
+               self._use_compact, self.feature_dim, bg.algebra, bg.n,
+               bg.tile, bg.ntiles,
+               None if sh is None else (sh.key(), sh.tiles_per_dev,
+                                        sh.slots))
+        fn = _PROGRAMS.get(key)
+        if fn is None:
+            # the program closes over this engine with its dispatch
+            # resolved, over `bg`'s static fields alone
+            template = FlipEngine(bg=bg.static(), algo=self.algo,
+                                  mode=self.mode, relax_mode=key[2],
+                                  compact=key[3],
+                                  feature_dim=self.feature_dim)
+            fn = _PROGRAMS[key] = template._program(trace_cap)
+        return fn
+
+    def _program(self, trace_cap: int):
+        """The fixpoint program of this engine over its layout's static
+        fields (`BlockedGraph.static`); each call rebuilds the layout's
+        view from the program's traced arguments."""
+        static = self.bg
+        sh = static.shards
 
         def live_mask(frontier, steps, budgets):
             """(B,) per-query liveness: frontier still active AND the
@@ -523,12 +524,12 @@ class FlipEngine:
             live = live_mask(frontier, steps, budgets)
             if not trace_cap:
                 attrs, aux, frontier = self._masked_step(
-                    attrs, aux, frontier, live, bg=bg)
+                    attrs, aux, frontier, live, bg)
                 return (attrs, aux, frontier,
                         steps + live.astype(jnp.int32), budgets)
             it, bufs = state[5], state[6]
             (attrs, aux, frontier), stats = self._masked_step(
-                attrs, aux, frontier, live, with_stats=True, bg=bg)
+                attrs, aux, frontier, live, bg, with_stats=True)
             # rows past the capacity are dropped, not wrapped: the trace
             # stays a prefix of the run and `truncated` flags the cut
             bufs = tuple(buf.at[it].set(x, mode="drop")
@@ -541,15 +542,9 @@ class FlipEngine:
             state0 = (attrs0, aux0, frontier0, jnp.zeros(b, jnp.int32),
                       budgets)
             if trace_cap:
-                # active vertices, active tiles, blocks fetched[, the
-                # largest device's live slots][, source runs], converged
-                rows = ([((b,), jnp.int32)] + [((), jnp.int32)] * 2
-                        + [((), jnp.int32)] * (sh is not None)
-                        + [((), jnp.int32)]
-                        * (self._relax_walk(bg, b) == "src")
-                        + [((b,), bool)])
                 bufs0 = tuple(jnp.zeros((trace_cap,) + shape, dtype)
-                              for shape, dtype in rows)
+                              for shape, dtype
+                              in self._trace_columns(bg, b))
                 state0 = state0 + (jnp.int32(0), bufs0)
             return jax.lax.while_loop(cond, functools.partial(body, bg),
                                       state0)
@@ -559,7 +554,7 @@ class FlipEngine:
         @jax.jit
         def flip_fixpoint(layout, attrs0, aux0, frontier0, budgets):
             blocks, blocks_ext, bsrc, bdst, src_order, src_tiles = layout
-            return loop(dataclasses.replace(bg, blocks=blocks,
+            return loop(dataclasses.replace(static, blocks=blocks,
                                             blocks_ext=blocks_ext,
                                             bsrc=bsrc, bdst=bdst,
                                             src_order=src_order,
@@ -567,11 +562,10 @@ class FlipEngine:
                         attrs0, aux0, frontier0, budgets)
 
         if sh is None:
-            cache[key] = flip_fixpoint
             return flip_fixpoint
 
         zero = np.float32(self.algebra.semiring.zero)
-        pad = sh.ntiles_p - bg.ntiles
+        pad = sh.ntiles_p - static.ntiles
 
         def widen(x, fill):
             widths = ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)
@@ -582,7 +576,7 @@ class FlipEngine:
                            out_specs=P(), check_vma=False)
         def per_device(layout, attrs0, aux0, frontier0, budgets):
             blocks, bsrc, bdst, live, src_order, src_tiles = layout
-            return loop(dataclasses.replace(bg, blocks=blocks,
+            return loop(dataclasses.replace(static, blocks=blocks,
                                             blocks_ext=blocks, bsrc=bsrc,
                                             bdst=bdst, live=live,
                                             src_order=src_order,
@@ -595,37 +589,31 @@ class FlipEngine:
                                   budgets):
             out = per_device(layout, widen(attrs0, zero), widen(aux0, 0),
                              widen(frontier0, False), budgets)
-            return tuple(x[:, :bg.ntiles] for x in out[:3]) + out[3:]
+            return tuple(x[:, :static.ntiles] for x in out[:3]) + out[3:]
 
-        cache[key] = flip_fixpoint_sharded
         return flip_fixpoint_sharded
 
-    def _fixpoint_host(self, attrs, aux, frontier, trace_cap: int = 0,
-                       budgets=None, deadlines_t=None):
-        """Host-driven fixpoint for compacted jnp streaming and for
-        deadline-budgeted queries: identical body semantics to the
-        while_loop above (same live-mask freezing, same step accounting
-        -- bit-for-bit results), but each step reads the concrete
-        frontier so `frontier_relax` can bucket the compacted block list
-        -- and, because every step boundary is host-observable, this is
-        where per-query deadlines are enforced: a query whose
-        `deadlines_t` entry has passed is frozen (kept frontier, so it
-        reads non-converged) before the next step starts; work already
-        done is returned as a flagged partial result.
+    def _fixpoint_deadlined(self, attrs, aux, frontier, trace_cap: int,
+                            budgets, deadlines_t, bg: BlockedGraph):
+        """The fixpoint under per-query deadlines: the same program,
+        called for one step at a time, so that each step boundary is one
+        the host sees. Before each call the host reads which queries
+        still have a frontier and freezes those whose `deadlines_t`
+        entry has passed: they keep their frontier, so they read
+        non-converged, and the work already done comes back as a flagged
+        partial result. Each query still live and under its step budget
+        gets a budget of one step for the call, every other a budget of
+        none, so results and steps are bit-for-bit those of one call
+        when no deadline bites.
 
-        With `trace_cap`, stats rows are recorded host-side -- and since
-        this loop observes every step from the host anyway, it also
-        records real per-step wall times (`StepTrace.step_wall_s`),
-        which the on-device while_loop cannot."""
+        With `trace_cap`, each call runs the traced program with one
+        row, which stays on the device until the loop ends, and the host
+        clocks each step from its call to the next boundary's read
+        (`StepTrace.step_wall_s`), which one whole-loop call cannot."""
         b = int(attrs.shape[0])
-        if budgets is None:
-            budgets = np.full(b, self.max_steps, dtype=np.int32)
         budgets = np.asarray(budgets)
-        deadlines = (None if deadlines_t is None
-                     or not np.isfinite(deadlines_t).any()
-                     else np.broadcast_to(np.asarray(deadlines_t,
-                                                     dtype=np.float64),
-                                          (b,)))
+        program = self._dense_fixpoint_jit(min(trace_cap, 1), bg)
+        layout = _layout_args(bg)
         expired = np.zeros(b, dtype=bool)
         steps = np.zeros(b, np.int32)
         rows: list[tuple] = []
@@ -634,62 +622,42 @@ class FlipEngine:
         t0 = time.perf_counter()
         while True:
             with span("flip.step", step=n_iter):
-                # this concrete read is the loop's natural per-step sync:
-                # it also closes the previous traced step's wall
-                # measurement, so tracing adds no extra host<->device
-                # round trips
+                # the loop's one sync a step; it also ends the previous
+                # traced step's wall
                 active = np.asarray(frontier.any(axis=(1, 2)))
                 if len(walls) < len(rows):
                     walls.append(time.perf_counter() - t0)
-                if deadlines is not None:
-                    # a deadline only *expires* a query that still has
-                    # work left: converged queries met their deadline by
-                    # definition
-                    expired |= active & (deadlines <= time.monotonic())
+                # a deadline only *expires* a query that still has work
+                # left: converged queries met their deadline by definition
+                expired |= active & (deadlines_t <= time.monotonic())
                 live = active & ~expired & (steps < budgets)
                 if not live.any():
                     break
                 t0 = time.perf_counter()
-                if trace_cap:
-                    (attrs, aux, frontier), st = self._masked_step(
-                        attrs, aux, frontier, jnp.asarray(live),
-                        with_stats=True)
-                    if n_iter < trace_cap:
-                        # stats stay on device until after the loop:
-                        # only the row tuple is kept per step
-                        rows.append(tuple(st) + (~live,))
-                else:
-                    attrs, aux, frontier = self._masked_step(
-                        attrs, aux, frontier, jnp.asarray(live))
-                steps = steps + live.astype(np.int32)
+                out = program(layout, attrs, aux, frontier,
+                              jnp.asarray(live.astype(np.int32)))
+                attrs, aux, frontier = out[:3]
+                if trace_cap and n_iter < trace_cap:
+                    rows.append(out[6])
+                steps += live.astype(np.int32)
                 n_iter += 1
-        converged = ~np.asarray(frontier.any(axis=(1, 2)))
-        read_trace = (functools.partial(self._host_trace, rows, walls, b,
-                                        n_iter > trace_cap)
+        read_trace = (functools.partial(self._read_rows, rows, walls,
+                                        n_iter, trace_cap, bg, b)
                       if trace_cap else None)
-        return (attrs, aux, frontier, jnp.asarray(steps), read_trace,
-                converged, expired)
+        return attrs, aux, frontier, steps, read_trace, ~active, expired
 
-    def _host_trace(self, rows, walls, b: int, truncated: bool):
-        """The host fixpoint's per-step stat rows (device scalars until
-        now) read back as ``(StepTrace, truncated)``."""
-        nb = int(self.bg.bsrc.shape[0])
-        bf = np.asarray([int(r[2]) for r in rows], dtype=np.int32)
-        runs = (np.asarray([int(r[3]) for r in rows], dtype=np.int32)
-                if self._relax_walk(self.bg, b) == "src" else None)
-        trace = StepTrace(
-            active_vertices=(np.stack([np.asarray(r[0]) for r in rows])
-                             .astype(np.int32) if rows
-                             else np.zeros((0, b), np.int32)),
-            active_tiles=np.asarray([int(r[1]) for r in rows],
-                                    dtype=np.int32),
-            blocks_fetched=bf,
-            blocks_skipped=np.int32(nb) - bf,
-            converged=(np.stack([r[-1] for r in rows]) if rows
-                       else np.zeros((0, b), bool)),
-            step_wall_s=np.asarray(walls, dtype=np.float64),
-            src_runs=runs)
-        return trace, truncated
+    def _read_rows(self, rows, walls, n_iter: int, trace_cap: int,
+                   bg: BlockedGraph, b: int):
+        """The deadlined loop's one-row stat buffers read back as one
+        ``(StepTrace, truncated)``, with each step's wall."""
+        host = jax.device_get(rows)
+        bufs = [np.concatenate([np.zeros((0,) + shape, dtype)]
+                               + [r[i] for r in host])
+                for i, (shape, dtype)
+                in enumerate(self._trace_columns(bg, b))]
+        trace, truncated = self._read_trace(n_iter, bufs, trace_cap, bg)
+        return dataclasses.replace(
+            trace, step_wall_s=np.asarray(walls, dtype=np.float64)), truncated
 
     # -------------------------------------------------------------- #
     # the one plan-driven executor
@@ -724,12 +692,12 @@ class FlipEngine:
         -- the partial result is *flagged* via the per-query convergence
         mask, which `detail=True` exposes: the call then returns an
         `ExecutionDetail` (attrs / steps / converged / deadline_expired
-        / telemetry) instead of the bare tuple. Deadlines are a local
-        (host-driven) mechanism; a distributed plan rejects them.
+        / telemetry) instead of the bare tuple. A deadline runs the
+        fixpoint program one step per call (`_fixpoint_deadlined`); a
+        distributed plan rejects deadlines.
 
         `repro.api.CompiledQuery` is the intended driver: it resolves an
-        `ExecutionPlan` into these arguments. The legacy `run*` methods
-        are deprecated shims over this method.
+        `ExecutionPlan` into these arguments.
         """
         batched = bool(np.ndim(srcs))
         srcs = np.atleast_1d(np.asarray(srcs, dtype=np.int64))
@@ -949,12 +917,11 @@ class FlipEngine:
         segment, and the (B,) bool end-of-segment convergence mask
         (True = frontier empty; inert/idle lanes read True).
 
-        Segmenting is exact: the per-step body is `_masked_step` -- the
-        same body both fixpoint drivers run -- so K-step segments
-        compose into bit-for-bit the single-call fixpoint, per lane
-        (budgets only partition the step sequence; they never change
-        it). The dense while_loop path takes budgets as a traced
-        argument, so varying segment lengths never retrace."""
+        Segmenting is exact: a segment is a call of the one fixpoint
+        program, so K-step segments compose into bit-for-bit the
+        single-call fixpoint, per lane (budgets only partition the step
+        sequence; they never change it). The program takes budgets as a
+        traced argument, so varying segment lengths never retrace."""
         attrs, aux, frontier = state
         budgets = jnp.asarray(np.asarray(budgets, dtype=np.int32))
         attrs, aux, frontier, steps, _, converged, _ = self._fixpoint(
@@ -983,37 +950,3 @@ class FlipEngine:
         ``(new_engine, delta)`` -- this engine is left untouched."""
         bg2, delta = self.bg.apply_updates(new_graph, updates)
         return dataclasses.replace(self, bg=bg2), delta
-
-    # -------------------------------------------------------------- #
-    # deprecated pre-api entry points: thin shims over `execute`
-    # -------------------------------------------------------------- #
-    @staticmethod
-    def _warn_legacy(name: str) -> None:
-        warnings.warn(
-            f"FlipEngine.{name} is deprecated; compile a session with "
-            "flip.compile(graph, program, plan) (repro.api) and call "
-            ".query(...), or drive FlipEngine.execute directly",
-            DeprecationWarning, stacklevel=3)
-
-    def run(self, src: int = 0, warm: WarmStart | None = None):
-        """Deprecated: `execute(src)`. Single-query fixpoint; returns
-        the algebra's result vector in original vertex order plus the
-        number of relaxation steps taken."""
-        self._warn_legacy("run")
-        return self.execute(int(src), warm=warm)
-
-    def run_batch(self, srcs, warm: WarmStart | None = None):
-        """Deprecated: `execute(srcs)` with a sequence. Batched fixpoint
-        over B independent sources sharing one weight-block stream;
-        returns ((B, n) results, (B,) per-query step counts), each row
-        bit-for-bit the corresponding solo result."""
-        self._warn_legacy("run_batch")
-        return self.execute(np.atleast_1d(np.asarray(srcs)), warm=warm)
-
-    def run_updated(self, src, prev, delta: UpdateDelta):
-        """Deprecated: `execute(src, warm=resolve_warm(prev, delta))`.
-        Recompute after `apply_updates`, incrementally when sound (see
-        `resolve_warm`); the result is bit-for-bit the from-scratch
-        fixpoint on the updated graph either way."""
-        self._warn_legacy("run_updated")
-        return self.execute(src, warm=self.resolve_warm(prev, delta))

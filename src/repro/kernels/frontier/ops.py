@@ -39,12 +39,9 @@ compaction reads the activity mask through `src_tiles` and scatters
 the active prefix names them in (bsrc, bdst) order: the same masked
 cumsum and scatter, never a sort on the device, and no more gathers.
 
-On the jnp/CPU path the same activity mask drives a gather of only the
-active blocks before the segment-⊕. Static shapes under `jit` cannot
-shrink, so when called with concrete (non-traced) arrays the active list
-is padded to the next power-of-two bucket -- at most log2(nb) specialized
-executables -- which is where the CPU fallback's asymptotic win comes
-from (`FlipEngine` drives its jnp fixpoint through this path).
+On the jnp/CPU path the relax streams every block, compacted or not:
+static shapes under `jit` cannot shrink, the dense stream is the
+compacted one's fixed-size bound, and the results are the same.
 
 Sharded layout (`BlockKeys.build(mesh=...)`, `BlockedGraph.shard`): for
 a graph whose blocks one device cannot hold, device k of a mesh axis
@@ -147,9 +144,6 @@ class BlockedGraph:
     # dst_start[d]:dst_start[d+1] (a sharded layout splits the list at
     # these bounds).
     dst_start: np.ndarray = None
-    bsrc_np: np.ndarray = None  # host copy of bsrc for the per-step
-                                # bucketing path (avoids a device->host
-                                # conversion every fixpoint step)
     version: int = 0            # Graph.version this layout was built from
     graph_fp: str = None        # Graph.fingerprint() of that graph, so
                                 # engine caches can detect stale layouts
@@ -165,6 +159,8 @@ class BlockedGraph:
     src_tiles: jnp.ndarray = None
 
     def __post_init__(self):
+        if self.bsrc is None or isinstance(self.bsrc, jax.core.Tracer):
+            return      # a program's view (`static`): nothing to derive
         # precompute eagerly (construction always happens on the host):
         # materializing these lazily inside a trace would cache tracers
         if self.blocks_ext is None and self.algebra is not None:
@@ -176,11 +172,21 @@ class BlockedGraph:
             self.dst_start = np.searchsorted(
                 np.asarray(self.bdst),
                 np.arange(self.ntiles + 1)).astype(np.int32)
-        if self.bsrc_np is None:
-            self.bsrc_np = np.asarray(self.bsrc)
         if self.src_order is None:
             self.src_order, self.src_tiles = map(
-                jnp.asarray, _src_walk(self.bsrc_np))
+                jnp.asarray, _src_walk(np.asarray(self.bsrc)))
+
+    def static(self) -> "BlockedGraph":
+        """This layout's static fields alone, every array None: what a
+        compiled fixpoint program closes over. The program rebuilds its
+        view from its traced arguments, so it holds none of a layout's
+        arrays, and programs are shared across layouts
+        (`FlipEngine._dense_fixpoint_jit`)."""
+        sh = self.shards and dataclasses.replace(self.shards, starts=None,
+                                                 keys=None)
+        return BlockedGraph(n=self.n, tile=self.tile, ntiles=self.ntiles,
+                            blocks=None, bsrc=None, bdst=None, perm=None,
+                            inv_perm=None, algebra=self.algebra, shards=sh)
 
     @property
     def padded_n(self) -> int:
@@ -653,7 +659,7 @@ def _sharded(keys: np.ndarray, mesh: Mesh, axis: str, fill,
         shards=layout,
         dst_start=np.searchsorted(keys // ntiles,
                                   np.arange(ntiles + 1)).astype(np.int32),
-        bsrc_np=bsrc.reshape(-1), **fields)
+        **fields)
 
 
 # --------------------------------------------------------------------- #
@@ -757,65 +763,10 @@ def _relax_jnp(src_vals, carry, blocks, bsrc, bdst,
     return semiring.add_jnp(carry, best)
 
 
-@functools.partial(jax.jit, static_argnames=("semiring", "features"))
-def _relax_jnp_compact(src_vals, carry, blocks_ext, bsrc, bdst, bsel,
-                       semiring: Semiring = MIN_PLUS,
-                       features: bool = False):
-    """Compacted jnp relax: ⊗-combine + segment-⊕ over only the blocks
-    named by ``bsel`` (a prefix of active block ids padded with the
-    sentinel index nb). Sentinel rows gather the all-identity block, so
-    they contribute the ⊕-identity to their segment: bit-for-bit the
-    dense result, at O(len(bsel)·T²) instead of O(nb·T²). Vector state
-    (`features=True`) contracts each gathered block over its (T, d) slab.
-    """
-    tax = -3 if features else -2
-    ntiles = carry.shape[tax]
-    src_ix = jnp.take(bsrc, bsel, mode="clip")      # sentinel -> last block
-    seg_ix = jnp.take(bdst, bsel, mode="clip")
-    sv = jnp.take(src_vals, src_ix, axis=tax)            # (..., k, T[, d])
-    w = jnp.take(blocks_ext, bsel, axis=0)               # (k, T, T)
-    if features:
-        cand = semiring.contract_jnp(sv, w)              # (..., k, T, d)
-    else:
-        cand = semiring.add_reduce_jnp(
-            semiring.mul_jnp(sv[..., :, None], w), axis=-2)
-    def seg(x):
-        return semiring.segment_reduce_jnp(x, seg_ix, ntiles)
-    batched = cand.ndim == (4 if features else 3)
-    best = jax.vmap(seg)(cand) if batched else seg(cand)
-    return semiring.add_jnp(carry, best)
-
-
-_BUCKET_MIN = 8     # smallest compacted-list size: bounds executables at
-                    # ~log2(nb) buckets per (semiring, state shape)
-
-
-def _relax_jnp_bucketed(src_vals, carry, bg: "BlockedGraph",
-                        features: bool = False):
-    """Host-side compacted jnp step for concrete (non-traced) inputs: read
-    the active count, round it up to a power-of-two bucket, and run the
-    bucket-sized compacted relax. Falls back to the dense step when the
-    bucket would not be smaller than the full list."""
-    sr = bg.semiring
-    nb = int(bg.bsrc.shape[0])
-    act = np.asarray(tile_activity(src_vals, sr, features))[bg.bsrc_np]
-    idx = np.flatnonzero(act).astype(np.int32)
-    bucket = max(_BUCKET_MIN,
-                 1 << int(idx.size - 1).bit_length() if idx.size else 0)
-    if bucket >= nb:
-        return _relax_jnp(src_vals, carry, bg.blocks, bg.bsrc, bg.bdst,
-                          semiring=sr, features=features)
-    bsel = np.full(bucket, nb, dtype=np.int32)
-    bsel[:idx.size] = idx
-    return _relax_jnp_compact(src_vals, carry, bg.blocks_ext, bg.bsrc,
-                              bg.bdst, jnp.asarray(bsel), semiring=sr,
-                              features=features)
-
-
 def resolve_relax_mode(mode: str) -> str:
     """The single 'auto' dispatch rule: Pallas on TPU, jnp elsewhere.
-    Shared with `FlipEngine` so the engine's host-fixpoint redirect can
-    never disagree with the kernel dispatch below."""
+    Shared with `FlipEngine` so the engine's telemetry and program keys
+    can never disagree with the kernel dispatch below."""
     if mode == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "jnp"
     return mode
@@ -856,9 +807,8 @@ def frontier_relax(src_vals, carry, bg: BlockedGraph, mode: str = "auto",
     compact: frontier-compacted block streaming -- stream only blocks
              with an active source tile (any query); exact (bit-for-bit
              the dense result). On the pallas/interpret path the
-             compaction runs on-device with static shapes; on the jnp
-             path it buckets host-side, so under a trace (e.g. inside
-             `lax.while_loop`) it falls back to the dense step.
+             compaction runs on-device with static shapes; the jnp path
+             streams every block either way (its fixed-size bound).
     feature_dim: static feature width d; must match the state's trailing
              axis when > 1 (explicit, because (ntiles, T, d) and
              (B, ntiles, T) are rank-ambiguous).
@@ -878,16 +828,8 @@ def frontier_relax(src_vals, carry, bg: BlockedGraph, mode: str = "auto",
             "mode='interpret' (Pallas interpreter, exact but slow) or "
             "mode='jnp' (vectorized fallback)")
     if mode == "jnp":
-        if not compact:
-            return _relax_jnp(src_vals, carry, bg.blocks, bg.bsrc, bg.bdst,
-                              semiring=sr, features=features)
-        if isinstance(src_vals, jax.core.Tracer):
-            # traced shapes cannot shrink: the dense step *is* the
-            # compacted stream's fixed-size upper bound, and it avoids a
-            # pointless full-width gather of blocks_ext
-            return _relax_jnp(src_vals, carry, bg.blocks, bg.bsrc, bg.bdst,
-                              semiring=sr, features=features)
-        return _relax_jnp_bucketed(src_vals, carry, bg, features=features)
+        return _relax_jnp(src_vals, carry, bg.blocks, bg.bsrc, bg.bdst,
+                          semiring=sr, features=features)
     interpret = mode == "interpret"
     batch = src_vals.shape[0] if src_vals.ndim == 3 + features else 1
     walk = (relax_grid(bg, batch, mode, feature_dim)[2] == "src")

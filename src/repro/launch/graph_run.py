@@ -137,7 +137,7 @@ def main():
           f"avg routing length {mapping.avg_routing_length():.2f}")
 
     if srcs is not None:
-        ok = _run_batched(args, g, mapping, srcs)
+        ok = _batched_run(args, g, mapping, srcs)
         print(f"[graph] correct vs reference: {ok}")
         raise SystemExit(0 if ok else 1)
 
@@ -261,7 +261,7 @@ def _replay_updates(args, g, cq, res):
     return cq.graph, res.attrs
 
 
-def _run_batched(args, g, mapping, srcs) -> bool:
+def _batched_run(args, g, mapping, srcs) -> bool:
     """--srcs path: one batched fixpoint (or serving-bucket dispatch)."""
     t0 = time.time()
     if args.batch:

@@ -16,9 +16,9 @@ machinery already computes and would otherwise throw away:
   * ``converged``        (steps, B) -- per-query convergence mask
     *entering* the step (a converged query is frozen by the engine);
   * ``step_wall_s``      (steps,)   -- host-measured per-step wall time;
-    only the host-driven fixpoint can observe it (the on-device
-    `lax.while_loop` exposes no per-iteration clock), so it is None on
-    the device paths;
+    only a deadline-driven call, which runs the fixpoint program one
+    step per call, can observe it (one whole-loop call exposes no
+    per-iteration clock), so it is None on every other call;
   * ``shard_live_max`` / ``shard_live_mean`` (steps,) -- sharded
     fixpoint only: the live slots (blocks streamed) of the fullest
     device and the mean over devices, which read the shards' imbalance
@@ -56,7 +56,7 @@ class StepTrace:
     blocks_fetched: np.ndarray           # (steps,)   i32
     blocks_skipped: np.ndarray           # (steps,)   i32
     converged: np.ndarray                # (steps, B) bool
-    step_wall_s: np.ndarray | None = None   # (steps,) f64, host path only
+    step_wall_s: np.ndarray | None = None   # (steps,) f64: deadlined, sim
     # sharded fixpoint only: live slots (blocks streamed) of the fullest
     # device and the mean over devices; `blocks_fetched` is their sum
     shard_live_max: np.ndarray | None = None    # (steps,) i32

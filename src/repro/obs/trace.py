@@ -7,10 +7,10 @@ one span per *step*, with the per-step frontier stats attached as span
 ``args`` so hovering a step shows its active vertices / tiles / blocks
 fetched.
 
-Timing semantics: the host-driven fixpoint records real per-step wall
-times and those become the step span durations; the on-device
-`lax.while_loop` paths expose no per-iteration clock, so their step
-spans divide the dispatch wall evenly and are tagged
+Timing semantics: a deadline-driven call (the fixpoint program called
+one step at a time) records real per-step wall times and those become
+the step span durations; one whole-loop call exposes no per-iteration
+clock, so its step spans divide the dispatch wall evenly and are tagged
 ``"synthetic_timing": true`` -- the span *structure* and the attached
 stats are exact either way, only the widths are approximate.
 """

@@ -95,13 +95,13 @@ def np_contract(sr, sv, w):
 
 
 def check_batch(eng, g, srcs, algo):
-    """run_batch rows must be bit-for-bit the solo runs and match the
+    """Batched `execute` rows must be bit-for-bit the solo runs and match the
     oracle (the batched-execution contract)."""
-    outs, steps = eng.run_batch(srcs)
+    outs, steps = eng.execute(srcs)
     assert outs.shape == (len(srcs), g.n)
     assert steps.shape == (len(srcs),)
     for b, s in enumerate(srcs):
-        solo_out, solo_steps = eng.run(int(s))
+        solo_out, solo_steps = eng.execute(int(s))
         np.testing.assert_array_equal(outs[b], solo_out)
         assert steps[b] == solo_steps
         assert ALGEBRAS[algo].results_match(outs[b], oracle(algo, g,

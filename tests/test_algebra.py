@@ -42,7 +42,7 @@ def test_engine_matches_oracle_20_graphs(algo):
         for mode in ("data", "op"):
             eng = FlipEngine.build(g, algo, tile=64, mode=mode,
                                    relax_mode="jnp")
-            got, steps = eng.run(src)
+            got, steps = eng.execute(src)
             assert steps > 0
             _assert_close(got, ref, algo, f"mode={mode}")
 
@@ -55,7 +55,7 @@ def test_engine_multitile_blocksparse(algo):
     for mode in ("data", "op"):
         eng = FlipEngine.build(g, algo, tile=16, mode=mode,
                                relax_mode="jnp")
-        got, _ = eng.run(1)
+        got, _ = eng.execute(1)
         _assert_close(got, ref, algo, f"multitile mode={mode}")
 
 
@@ -66,7 +66,7 @@ def test_interpret_kernel_non_tropical(algo):
     ref, _ = reference.run(algo, g, 2)
     eng = FlipEngine.build(g, algo, tile=16, mode="data",
                            relax_mode="interpret")
-    got, _ = eng.run(2)
+    got, _ = eng.execute(2)
     _assert_close(got, ref, algo, "interpret")
 
 
@@ -80,7 +80,7 @@ def test_sim_cross_layer(algo):
         ref, _ = reference.run(algo, g, src)
         _assert_close(r.attrs, ref, algo, "sim")
         got, _ = FlipEngine.build(g, algo, tile=64,
-                                  relax_mode="jnp").run(src)
+                                  relax_mode="jnp").execute(src)
         _assert_close(got, ref, algo, "engine-vs-sim graph")
 
 
@@ -130,7 +130,7 @@ def test_pagerank_mass_conservation():
     """Rank sums to (1 - leaked dangling mass) <= 1, never more."""
     g = make_power_law(48, 140, seed=3)
     got, _ = FlipEngine.build(g, "pagerank", tile=64,
-                              relax_mode="jnp").run(0)
+                              relax_mode="jnp").execute(0)
     assert 0.0 < float(np.sum(got)) <= 1.0 + 1e-4
 
 
@@ -141,7 +141,7 @@ def test_pallas_compiled_matches_oracle(algo):
     ref, _ = reference.run(algo, g, 0)
     eng = FlipEngine.build(g, algo, tile=128, mode="data",
                            relax_mode="pallas")
-    got, _ = eng.run(0)
+    got, _ = eng.execute(0)
     _assert_close(got, ref, algo, "pallas-compiled")
 
 
@@ -196,7 +196,7 @@ def test_register_custom_algebra_end_to_end():
                     heapq.heappush(heap, (cand, v))
         for mode in ("data", "op"):
             got, _ = FlipEngine.build(g, minimax, tile=64, mode=mode,
-                                      relax_mode="jnp").run(2)
+                                      relax_mode="jnp").execute(2)
             _assert_close(got, best, "minimax", f"mode={mode}")
         # and on the cycle simulator, unchanged
         m = compile_mapping(g, effort=0, seed=0)

@@ -3,11 +3,9 @@
 The redesign's contract, proven here:
 
   * `flip.compile(graph, program, plan).query(srcs)` is bit-exact vs
-    every legacy `FlipEngine.run*` entry point -- solo, batched,
+    the engine's own `FlipEngine.execute` -- solo, batched,
     distributed, and incremental-recompute -- across all registered
     algebras x {jnp, interpret} relax modes;
-  * the legacy `run*` methods are deprecated shims (DeprecationWarning)
-    over the same executor;
   * `ExecutionPlan` validation rejects inconsistent knob combinations
     at compile time;
   * a `Program`-defined custom algorithm (algebra + oracle registered
@@ -26,14 +24,6 @@ from repro.core.engine import FlipEngine, WarmStart
 from repro.graphs import make_power_law, make_synthetic, reference
 
 
-def _legacy(eng, method, *args, **kw):
-    """Call a deprecated shim with its warning silenced (the warning
-    itself is asserted once in test_legacy_shims_warn)."""
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        return getattr(eng, method)(*args, **kw)
-
-
 def _monotone_batch(g):
     """⊕-improving reweights: halve the first three edge weights."""
     eu = g.edge_sources()
@@ -42,25 +32,25 @@ def _monotone_batch(g):
 
 
 # --------------------------------------------------------------------- #
-# bit-exact parity: new surface vs legacy run* paths
+# bit-exact parity: the session surface vs the engine's execute
 # --------------------------------------------------------------------- #
 @pytest.mark.parametrize("algo", ALGOS)
 @pytest.mark.parametrize("relax", ["jnp", "interpret"])
 def test_query_parity_solo_and_batch(algo, relax):
-    """query(src) == run(src) and query(srcs) == run_batch(srcs),
+    """query(src) == execute(src) and query(srcs) == execute(srcs),
     bit-for-bit, for every algebra on both CPU kernel paths."""
     g = make_synthetic(40, 110, seed=3)
     cq = flip.compile(g, algo,
                       flip.ExecutionPlan(tile=16, relax_mode=relax))
     r = cq.query(2)
-    out, steps = _legacy(cq.engine, "run", 2)
+    out, steps = cq.engine.execute(2)
     np.testing.assert_array_equal(r.attrs, out)
     assert r.steps == steps
     assert_close(r.attrs, oracle(algo, g, 2), algo, "solo")
 
     srcs = np.array([2, 7, 19])
     rb = cq.query(srcs)
-    outs, steps = _legacy(cq.engine, "run_batch", srcs)
+    outs, steps = cq.engine.execute(srcs)
     np.testing.assert_array_equal(rb.attrs, outs)
     np.testing.assert_array_equal(rb.steps, steps)
     assert rb.check()
@@ -87,7 +77,7 @@ def test_query_parity_distributed(algo):
 
 @pytest.mark.parametrize("algo", ["sssp", "bfs", "widest"])
 def test_query_parity_incremental(algo):
-    """session.update + query(warm=prev) == run_updated == scratch,
+    """session.update + query(warm=prev) == warm execute == scratch,
     bit-for-bit (the incremental-recompute leg of the old surface)."""
     g = make_power_law(48, 140, seed=7)
     cq = flip.compile(g, algo,
@@ -96,10 +86,10 @@ def test_query_parity_incremental(algo):
     batch = _monotone_batch(g)
     cq2, delta = cq.update(batch)
     warm = cq2.query(3, warm=prev)
-    legacy_out, legacy_steps = _legacy(cq2.engine, "run_updated", 3,
-                                       prev.attrs, delta)
-    np.testing.assert_array_equal(warm.attrs, legacy_out)
-    assert warm.steps == legacy_steps
+    eng_out, eng_steps = cq2.engine.execute(
+        3, warm=cq2.engine.resolve_warm(prev.attrs, delta))
+    np.testing.assert_array_equal(warm.attrs, eng_out)
+    assert warm.steps == eng_steps
     scratch = cq2.query(3)
     np.testing.assert_array_equal(warm.attrs, scratch.attrs)
     if delta.monotone and ALGEBRAS[algo].kind == "monotone":
@@ -109,7 +99,7 @@ def test_query_parity_incremental(algo):
 
 def test_query_nonmonotone_update_falls_back_to_scratch():
     """warm='auto' on a delete (non-monotone delta): query(warm=...)
-    silently recomputes from scratch, exactly like run_updated did."""
+    silently recomputes from scratch, as `resolve_warm` does."""
     g = make_power_law(48, 140, seed=2)
     cq = flip.compile(g, "sssp",
                       flip.ExecutionPlan(tile=16, relax_mode="jnp"))
@@ -151,20 +141,25 @@ def test_bucketed_dispatch_is_bitexact():
 
 
 # --------------------------------------------------------------------- #
-# deprecated shims
+# the engine's warm start
 # --------------------------------------------------------------------- #
-def test_legacy_shims_warn():
+def test_engine_warm_execute_matches_scratch():
+    """After a monotone update, `execute(src, warm=resolve_warm(prev,
+    delta))` is bit-for-bit a from-scratch `execute` on the updated
+    graph, and takes no more steps."""
     g = make_synthetic(40, 110, seed=0)
     eng = FlipEngine.build(g, "sssp", tile=16, relax_mode="jnp")
-    with pytest.warns(DeprecationWarning, match="run is deprecated"):
-        eng.run(0)
-    with pytest.warns(DeprecationWarning, match="run_batch"):
-        eng.run_batch([0, 1])
-    prev, _ = _legacy(eng, "run", 0)
+    prev, _ = eng.execute(0)
     batch = _monotone_batch(g)
-    eng2, delta = eng.apply_updates(g.apply_updates(batch), batch)
-    with pytest.warns(DeprecationWarning, match="run_updated"):
-        eng2.run_updated(0, prev, delta)
+    g2 = g.apply_updates(batch)
+    eng2, delta = eng.apply_updates(g2, batch)
+    warm = eng2.resolve_warm(prev, delta)
+    assert delta.monotone and warm is not None
+    out, steps = eng2.execute(0, warm=warm)
+    scratch, scratch_steps = FlipEngine.build(g2, "sssp", tile=16,
+                                              relax_mode="jnp").execute(0)
+    np.testing.assert_array_equal(out, scratch)
+    assert steps <= scratch_steps
 
 
 # --------------------------------------------------------------------- #

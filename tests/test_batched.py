@@ -1,8 +1,8 @@
 """Batched multi-query execution: (B, ntiles, T) equivalence.
 
-`run_batch(srcs)` threads B independent queries through one shared
+`execute(srcs)` threads B independent queries through one shared
 while_loop fixpoint; every row must be bit-for-bit the corresponding solo
-`run(src)` (the per-query convergence mask freezes finished queries), and
+`execute(src)` (the per-query convergence mask freezes finished queries), and
 must match the numpy oracle, on the jnp fallback and the Pallas-interpret
 kernel, in both data and op modes. The serving front-end adds bucketed
 dispatch + tail padding on top and must preserve the same guarantee.
@@ -41,7 +41,7 @@ def test_run_batch_heterogeneous_convergence():
     keeps relaxing while finished ones stay frozen."""
     g = make_synthetic(60, 130, seed=9)
     eng = FlipEngine.build(g, "sssp", tile=32, relax_mode="jnp")
-    outs, steps = eng.run_batch(np.arange(8))
+    outs, steps = eng.execute(np.arange(8))
     assert steps.min() >= 1 and len(set(steps.tolist())) > 1
     for b in range(8):
         ref, _ = reference.run("sssp", g, b)
@@ -51,8 +51,8 @@ def test_run_batch_heterogeneous_convergence():
 def test_run_batch_single_source_matches_run():
     g = make_synthetic(40, 110, seed=1)
     eng = FlipEngine.build(g, "widest", tile=32, relax_mode="jnp")
-    outs, steps = eng.run_batch([7])
-    solo, s = eng.run(7)
+    outs, steps = eng.execute([7])
+    solo, s = eng.execute(7)
     np.testing.assert_array_equal(outs[0], solo)
     assert steps[0] == s
 
@@ -87,7 +87,7 @@ def test_graph_server_padding_is_bitexact():
     assert srv.dispatches == 1
     eng = srv.engine("bfs")
     for r in reqs:
-        solo, steps = eng.run(r.src)
+        solo, steps = eng.execute(r.src)
         np.testing.assert_array_equal(r.result, solo)
         assert r.steps == steps
 

@@ -140,20 +140,21 @@ def test_tile_activity_matches_trigger():
 
 @pytest.mark.parametrize("algo", ALGOS)
 def test_engine_compact_fixpoint_bitexact(algo):
-    """End-to-end: the host-driven bucketed fixpoint (compact, jnp) is
-    bit-for-bit the dense while_loop fixpoint -- results and per-query
-    step counts -- and matches the oracle."""
+    """End-to-end: the fixpoint over the compacted stream the chip runs
+    (compact, the Pallas kernel interpreted) is bit-for-bit the dense
+    jnp fixpoint -- results and per-query step counts -- and matches
+    the oracle."""
     g = make_power_law(64, 190, seed=3)
     srcs = np.array([3, 11, 0, 27, 42, 8, 19, 33]) % g.n
     dense = FlipEngine.build(g, algo, tile=16, relax_mode="jnp",
                              compact=False)
-    comp = FlipEngine.build(g, algo, tile=16, relax_mode="jnp",
+    comp = FlipEngine.build(g, algo, tile=16, relax_mode="interpret",
                             compact=True)
-    o1, s1 = dense.run_batch(srcs)
-    o2, s2 = comp.run_batch(srcs)
+    o1, s1 = dense.execute(srcs)
+    o2, s2 = comp.execute(srcs)
     np.testing.assert_array_equal(o1, o2)
     np.testing.assert_array_equal(s1, s2)
-    solo, st = comp.run(int(srcs[0]))
+    solo, st = comp.execute(int(srcs[0]))
     np.testing.assert_array_equal(o2[0], solo)
     assert s2[0] == st
     ref, _ = reference.run(algo, g, int(srcs[0]))

@@ -15,7 +15,7 @@ the execution layers against the numpy reference oracles:
 
 then drives a random mutation sequence (inserts / deletes / reweights,
 including self-loop and parallel-edge upserts) through the incremental
-engines: after every batch the delta-driven `run_updated` result must be
+engines: after every batch the delta-driven warm `execute` result must be
 bit-for-bit the from-scratch run on the mutated graph and match the
 oracle, and the incrementally rebuilt block layout must equal a full
 rebuild.
@@ -103,14 +103,14 @@ def test_fuzz_differential(seed):
         for mode in ("data", "op"):
             eng = FlipEngine.build(g, algo, tile=TILE, mode=mode,
                                    relax_mode="jnp")
-            got, steps = eng.run(src)
+            got, steps = eng.execute(src)
             assert ALGEBRAS[algo].results_match(got, ref), \
                 f"{algo} {mode}/jnp diverged from oracle; {repro}"
             if mode == "data":
                 engines[algo], results[algo] = eng, got
         if algo == interp_algo:
             got, _ = FlipEngine.build(g, algo, tile=8, mode="data",
-                                      relax_mode="interpret").run(src)
+                                      relax_mode="interpret").execute(src)
             assert ALGEBRAS[algo].results_match(got, ref), \
                 f"{algo} data/interpret diverged from oracle; {repro}"
 
@@ -130,8 +130,9 @@ def test_fuzz_differential(seed):
         g_next = g_cur.apply_updates(batch)
         for algo in ALGOS:
             eng2, delta = engines[algo].apply_updates(g_next, batch)
-            inc, _ = eng2.run_updated(src, results[algo], delta)
-            scr, _ = eng2.run(src)
+            inc, _ = eng2.execute(
+                src, warm=eng2.resolve_warm(results[algo], delta))
+            scr, _ = eng2.execute(src)
             np.testing.assert_array_equal(
                 inc, scr,
                 err_msg=f"{algo} incremental != scratch after mutation "
@@ -223,7 +224,7 @@ def test_fuzz_features(seed):
     # long) and the corpus covers both
     algo = VEC_ALGOS[seed % len(VEC_ALGOS)]
     eng = FlipEngine.build(g, algo, tile=TILE, relax_mode="jnp")
-    got, _ = eng.run(src)
+    got, _ = eng.execute(src)
     assert ALGEBRAS[algo].results_match(got, oracle(algo, g, src)), \
         f"{algo} engine diverged from (n, d) oracle; {repro}"
 

@@ -8,8 +8,8 @@ The contract under test:
     including delete-then-reinsert, updates into carry-only destination
     tiles, and batches that activate a previously empty tile pair
     (shape-changing rebuilds);
-  * after a `Semiring.monotone_under` batch, `FlipEngine.run_updated`
-    resumes from the previous fixpoint with only the affected sources
+  * after a `Semiring.monotone_under` batch,
+    `FlipEngine.execute(src, warm=resolve_warm(prev, delta))` resumes from the previous fixpoint with only the affected sources
     seeded, and the result is **bit-for-bit** the from-scratch run --
     across all registered algebras x {jnp, interpret} x {solo, B=8};
   * non-monotone batches (deletes, ⊕-worsening reweights, non-idempotent
@@ -129,14 +129,14 @@ def test_empty_update_batch_is_noop():
     forward and changes nothing else, end to end."""
     g = make_synthetic(40, 110, seed=3)
     eng = FlipEngine.build(g, "sssp", tile=16, relax_mode="jnp")
-    prev, _ = eng.run(2)
+    prev, _ = eng.execute(2)
     g2 = g.apply_updates([])
     assert g2.version == g.version + 1 and g2.m == g.m
     eng2, delta = eng.apply_updates(g2, [])
     assert (delta.monotone and not delta.shape_changed
             and delta.affected_src.size == 0)
     assert eng2.bg.graph_fp == g2.fingerprint()
-    out, steps = eng2.run_updated(2, prev, delta)
+    out, steps = eng2.execute(2, warm=eng2.resolve_warm(prev, delta))
     assert steps == 0
     np.testing.assert_array_equal(out, prev)
 
@@ -185,13 +185,13 @@ def test_monotone_under_per_semiring():
 # --------------------------------------------------------------------- #
 def _check_incremental(g, algo, relax_mode, tile, srcs, rng):
     eng = FlipEngine.build(g, algo, tile=tile, relax_mode=relax_mode)
-    prev, _ = eng.run_batch(srcs)
+    prev, _ = eng.execute(srcs)
     batch = _monotone_batch(g, algo, rng)
     g2 = g.apply_updates(batch)
     eng2, delta = eng.apply_updates(g2, batch)
     assert delta.monotone == ALGEBRAS[algo].semiring.idempotent
-    inc, inc_steps = eng2.run_updated(srcs, prev, delta)
-    scr, scr_steps = eng2.run_batch(srcs)
+    inc, inc_steps = eng2.execute(srcs, warm=eng2.resolve_warm(prev, delta))
+    scr, scr_steps = eng2.execute(srcs)
     np.testing.assert_array_equal(inc, scr)     # bit-exact, every query
     for b, s in enumerate(srcs):
         assert ALGEBRAS[algo].results_match(inc[b],
@@ -229,21 +229,22 @@ def test_delete_then_reinsert(algo):
     g = make_power_law(48, 150, seed=9)
     eng = FlipEngine.build(g, algo, tile=16, relax_mode="jnp")
     src = 3
-    base, _ = eng.run(src)
+    base, _ = eng.execute(src)
     u = int(g.edge_sources()[7])
     v, w = int(g.indices[7]), float(g.weights[7])
 
     g_del = g.apply_updates([(u, v, None)])
     eng_del, d1 = eng.apply_updates(g_del, [(u, v, None)])
     assert not d1.monotone                      # delete is never monotone
-    mid, _ = eng_del.run_updated(src, base, d1)  # falls back to scratch
-    np.testing.assert_array_equal(mid, eng_del.run(src)[0])
+    # not monotone: falls back to scratch
+    mid, _ = eng_del.execute(src, warm=eng_del.resolve_warm(base, d1))
+    np.testing.assert_array_equal(mid, eng_del.execute(src)[0])
     assert ALGEBRAS[algo].results_match(mid, oracle(algo, g_del, src))
 
     g_re = g_del.apply_updates([(u, v, w)])
     eng_re, d2 = eng_del.apply_updates(g_re, [(u, v, w)])
     assert d2.monotone == ALGEBRAS[algo].semiring.idempotent
-    fin, _ = eng_re.run_updated(src, mid, d2)
+    fin, _ = eng_re.execute(src, warm=eng_re.resolve_warm(mid, d2))
     np.testing.assert_array_equal(fin, base)    # graph round-tripped
     # and the layout did too
     np.testing.assert_array_equal(np.asarray(eng_re.bg.blocks),
@@ -258,14 +259,14 @@ def test_update_into_carry_only_destination_tile(mode):
     g = Graph.from_edges(24, edges, weights=[2.0] * len(edges),
                          directed=True)
     eng = FlipEngine.build(g, "sssp", tile=8, relax_mode=mode)
-    prev, _ = eng.run(0)
+    prev, _ = eng.execute(0)
     assert prev[8] == np.inf                    # tile 1 unreachable from 0
     batch = [(0, 9, 1.5)]                       # open a path into tile 1
     g2 = g.apply_updates(batch)
     eng2, delta = eng.apply_updates(g2, batch)
     assert delta.monotone
-    inc, _ = eng2.run_updated(0, prev, delta)
-    np.testing.assert_array_equal(inc, eng2.run(0)[0])
+    inc, _ = eng2.execute(0, warm=eng2.resolve_warm(prev, delta))
+    np.testing.assert_array_equal(inc, eng2.execute(0)[0])
     assert ALGEBRAS["sssp"].results_match(inc, oracle("sssp", g2, 0))
     assert inc[9] == 1.5
 
@@ -288,10 +289,10 @@ def test_update_activates_empty_tile_pair():
     np.testing.assert_array_equal(np.asarray(bg2.blocks),
                                   np.asarray(full.blocks))
     eng = FlipEngine.build(g, "sssp", tile=8, relax_mode="jnp")
-    prev, _ = eng.run(0)
+    prev, _ = eng.execute(0)
     eng2, delta = eng.apply_updates(g2, batch)
-    inc, _ = eng2.run_updated(0, prev, delta)
-    np.testing.assert_array_equal(inc, eng2.run(0)[0])
+    inc, _ = eng2.execute(0, warm=eng2.resolve_warm(prev, delta))
+    np.testing.assert_array_equal(inc, eng2.execute(0)[0])
     assert inc[17] == 5.0 and inc[18] == 6.0
 
 
@@ -316,12 +317,13 @@ def test_warm_start_validation_and_noop():
     g = make_synthetic(40, 110, seed=1)
     eng = FlipEngine.build(g, "pagerank", tile=16, relax_mode="jnp")
     with pytest.raises(ValueError, match="monotone algebra"):
-        eng.run(0, warm=WarmStart(np.zeros(g.n, np.float32),
+        eng.execute(0, warm=WarmStart(np.zeros(g.n, np.float32),
                                   np.array([0])))
     eng = FlipEngine.build(g, "sssp", tile=16, relax_mode="jnp")
-    base, _ = eng.run(2)
+    base, _ = eng.execute(2)
     # empty seed set: nothing to relax, zero steps, result untouched
-    out, steps = eng.run(2, warm=WarmStart(base, np.array([], np.int64)))
+    out, steps = eng.execute(2,
+                             warm=WarmStart(base, np.array([], np.int64)))
     assert steps == 0
     np.testing.assert_array_equal(out, base)
 
@@ -331,7 +333,7 @@ def test_run_distributed_warm_start():
     on CPU CI; real meshes shard the same code)."""
     g = make_power_law(48, 140, seed=5)
     eng = FlipEngine.build(g, "sssp", tile=16)
-    prev, _ = eng.run(3)
+    prev, _ = eng.execute(3)
     rng = np.random.default_rng(4)
     batch = _monotone_batch(g, "sssp", rng)
     g2 = g.apply_updates(batch)
@@ -339,7 +341,7 @@ def test_run_distributed_warm_start():
     assert delta.monotone
     warm = WarmStart(prev, delta.affected_src)
     got, _ = eng2.execute(3, warm=warm, distributed=True)
-    np.testing.assert_array_equal(got, eng2.run(3)[0])
+    np.testing.assert_array_equal(got, eng2.execute(3)[0])
 
 
 # --------------------------------------------------------------------- #

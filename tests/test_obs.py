@@ -125,16 +125,18 @@ def test_bfs_trace_matches_frontier_replay_1k(compact):
 
 
 def test_trace_identical_across_fixpoints():
-    """The host-driven and while_loop fixpoints must record the same
-    stats row for row (only step_wall_s is host-exclusive)."""
+    """The deadline-driven loop (one program call per step) and the one
+    whole-loop call must record the same stats row for row (only
+    step_wall_s is the step loop's own)."""
     g = make_power_law(512, 1536, seed=1)
     srcs = [0, 7]
-    traces = {}
-    for compact in (True, False):
-        cq = flip.compile(g, "bfs", flip.ExecutionPlan(compact=compact))
-        traces[compact] = cq.query(srcs, trace=True)
-    th = traces[True].telemetry.dispatches[0].trace
-    tw = traces[False].telemetry.dispatches[0].trace
+    cq = flip.compile(g, "bfs", flip.ExecutionPlan())
+    host = cq.query(srcs, trace=True, deadline_s=1e6)
+    whole = cq.query(srcs, trace=True)
+    np.testing.assert_array_equal(host.attrs, whole.attrs)
+    np.testing.assert_array_equal(host.steps, whole.steps)
+    th = host.telemetry.dispatches[0].trace
+    tw = whole.telemetry.dispatches[0].trace
     np.testing.assert_array_equal(th.active_vertices, tw.active_vertices)
     np.testing.assert_array_equal(th.active_tiles, tw.active_tiles)
     np.testing.assert_array_equal(th.converged, tw.converged)

@@ -127,11 +127,13 @@ def test_telemetry_span_only_with_trace(g, tmp_path):
 
 
 def test_host_driven_fixpoint_has_a_span_per_step(g, tmp_path):
-    # compacted jnp streaming runs the fixpoint from the host
+    # a deadline runs the fixpoint program one step per call, from the
+    # host
     cq = flip.compile(g, "bfs", _plan(compact=True))
-    _warm(cq, (3, {}))
+    _warm(cq, (3, {"deadline_s": 1e6}))
     out = {}
-    ev = _profile(tmp_path, lambda: out.setdefault("r", cq.query(3)))
+    ev = _profile(tmp_path, lambda: out.setdefault(
+        "r", cq.query(3, deadline_s=1e6)))
     dispatch, = [e for e in ev if e["name"] == "flip.dispatch"]
     steps = _inside(dispatch, ev, "flip.step")
     # one span per relax step, and the last one the read that finds the

@@ -313,14 +313,15 @@ def test_telemetry_reports_relax_grid(relax_mode, batch, algo, path, walk):
 
 
 def test_host_fixpoint_reports_source_runs():
-    """A deadline runs the fixpoint from the host; its trace counts the
-    same source runs as the device loop's, and the answer is the same."""
+    """A deadline runs the fixpoint program one step per call from the
+    host; its trace counts the same source runs as one whole-loop
+    call's, and the answer is the same."""
     g = make_road_network(160, seed=0)
     eng = flip.compile(g, "sssp", flip.ExecutionPlan(
         relax_mode="interpret", tile=64)).engine
     out, steps, host = eng.execute(SRCS8[:8], trace=True, deadline_s=1e6)
     want, want_steps, dev = eng.execute(SRCS8[:8], trace=True)
-    assert host.trace.step_wall_s is not None     # the host loop ran
+    assert host.trace.step_wall_s is not None     # one step per call
     np.testing.assert_array_equal(out, want)
     np.testing.assert_array_equal(steps, want_steps)
     assert host.relax_walk == dev.relax_walk == "src"

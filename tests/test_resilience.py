@@ -7,8 +7,8 @@ The contract under test (docs/RESILIENCE.md):
     bucket, its stream, or the server;
   * budget-stopped fixpoints (`max_steps` / `deadline_s`) come back as
     FLAGGED partials (`converged=False` + typed error), never silent
-    truncations -- across both the host-driven and the jitted
-    while_loop fixpoint;
+    truncations -- whether the fixpoint program runs one step per call
+    (a deadline) or as one whole loop;
   * the degradation ladder (pallas->jnp, compact->dense) is EXACT:
     a degraded response is bit-for-bit the primary response;
   * admission control sheds the newest request with `CapacityExceeded`;
@@ -135,12 +135,13 @@ def test_server_ladder_result_bit_exact_with_primary(g):
 
 
 # ------------------------------------------------------------------ #
-# truncated fixpoints: host-driven AND jitted while_loop
+# truncated fixpoints: deadline-driven AND one whole-loop call
 # ------------------------------------------------------------------ #
 TRUNC_PLANS = [
-    # compact+jnp routes through the host-driven fixpoint
-    pytest.param(dict(mode="data", compact=True, relax_mode="jnp"),
-                 id="host-compact"),
+    # a plan deadline runs the fixpoint program one step per call
+    pytest.param(dict(mode="data", compact=True, relax_mode="jnp",
+                      deadline_s=1e6),
+                 id="deadline-compact"),
     # dense data mode runs the jitted while_loop fixpoint
     pytest.param(dict(mode="data", compact=False, relax_mode="jnp"),
                  id="jit-dense"),

@@ -201,9 +201,8 @@ def test_sharded_fixpoint_graph500_s16_four_chips(topo, monkeypatch,
         n=n, tile=T, ntiles=ntiles, blocks=layout[0], bsrc=layout[1],
         bdst=layout[2], perm=np.arange(n), inv_perm=np.arange(n),
         algebra=get_algebra("sssp"), blocks_ext=layout[0],
-        dst_start=np.zeros(ntiles + 1, np.int32),
-        bsrc_np=np.zeros(0, np.int32), live=layout[3], src_order=layout[4],
-        src_tiles=layout[5],
+        dst_start=np.zeros(ntiles + 1, np.int32), live=layout[3],
+        src_order=layout[4], src_tiles=layout[5],
         shards=ShardLayout(mesh=mesh, axis="data", tiles_per_dev=ntiles // 4,
                            slots=slots, starts=np.zeros(5, np.int64),
                            keys=np.zeros(nb, np.int64)))
