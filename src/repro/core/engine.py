@@ -846,8 +846,8 @@ class FlipEngine:
             trace, truncated = read_trace()
             sp.set_metadata(rows=len(trace.active_tiles))
             b = int(steps.shape[0])
-            path, grid_steps, walk = relax_grid(bg, b, self.relax_mode,
-                                                self.feature_dim)
+            path, grid_steps, walk, body = relax_grid(
+                bg, b, self.relax_mode, self.feature_dim)
             meta = {}
             sh = bg.shards
             if sh is not None:
@@ -866,7 +866,8 @@ class FlipEngine:
                 trace=trace, wall_s=time.perf_counter() - t0,
                 truncated=truncated, tile=bg.tile,
                 feature_dim=self.feature_dim, relax_path=path,
-                relax_grid_steps=grid_steps, relax_walk=walk, meta=meta)
+                relax_grid_steps=grid_steps, relax_walk=walk,
+                relax_body=body, meta=meta)
         return out, steps, tele, converged, expired
 
     # -------------------------------------------------------------- #

@@ -72,6 +72,19 @@ any order, so the bits are the slab grid's. (+, ×) keeps the
 destination walk and the row-by-row body: its sums would change with
 the order.
 
+Table body (grouped grid, B not a multiple of 8, any scalar semiring,
+where the table fits the budget too: `relax_body` == 'table'). The
+row-by-row body rebuilds, every slot, the (T, T) broadcast of the
+slot's source tile. Here the call's first grid step builds each live
+(row, source tile) broadcast once into a (B, ns, T, T) VMEM scratch,
+table[b, s][i, :] = src[b, s, i], and each slot reads it back: the same
+⊗ of the same f32 values and the same axis-0 ⊕-reduce of a (T, T)
+operand, in the same destination walk, so the bits are the row body's
+for every semiring, (+, ×) included. A tile whose trigger bit is clear
+is not built, and the merge's select drops its slots. Where the table
+does not fit (the 2^20-vertex road graph: 512 MiB), the row body stays;
+the table never moves a shape off the grouped grid.
+
 Slab grid (d > 1, or a state over the budget): grid = (nslots, B), one
 (slot, query) pair per step. The weight block's index map reads
 ``bsel[i]`` and ignores the query index, so each block is fetched once
@@ -96,12 +109,15 @@ VMEM (T a multiple of 128, f32; d' = d rounded up to 128 lanes):
             + (B a multiple of 8) B * T*T * 4 B source broadcast
             (ns', ntiles' = tile counts rounded up to 8; one copy of
             each, as the block index never changes)
+            + (table body) B * ns' * T*T * 4 B table, granted to the
+            call but not counted against the budget by `relax_path`
   slab d=1: 2 * (8*T + B*8*T + T*T + B*8*T) * 4 B
   slab d>1: 2 * (T*d' + B*T*d' + T*T + B*T*d') * 4 B
             + 8*T*d' * 4 B (min/max contraction transient)
 
 Examples: grouped 3 MiB at graph500-s15 (256 tiles, T=128) for B=8,
-8.5 MiB for the 2^20-vertex road graph solo (8192 tiles), 64 MiB budget
+16.75 MiB with its table for B=1 (the table fits up to B=3), 8.5 MiB
+for the 2^20-vertex road graph solo (8192 tiles), 64 MiB budget
 (v5e has 128 MiB; the call raises its scoped limit to what it holds plus
 8 MiB); slab 648 KiB for T=128, B=32, d=1; 2.8 MiB for T=128, B=8,
 d=8; 1 MiB for d=128 solo -- inside the 16 MiB default scoped VMEM. In
@@ -270,6 +286,27 @@ def relax_walk(path: str, batch: int, semiring: Semiring) -> str:
     return "dst"
 
 
+def table_vmem_bytes(batch: int, ns: int, t: int) -> int:
+    """VMEM of the table body's (B, ns, T, T) broadcast table, counted
+    at ns rounded up to 8 as the state is (B * ns' * T*T * 4 B)."""
+    return (batch * _round_up(ns, 8) * _round_up(t, 8)
+            * _round_up(t, 128) * 4)
+
+
+def relax_body(batch: int, ns: int, ntiles: int, t: int,
+               semiring: Semiring) -> str:
+    """The body the grouped grid runs at these shapes (module doc): at B
+    a multiple of 8 'runs' (`source_runs`) or 'rows8'; below, 'table'
+    where the grouped state and the table fit GROUPED_VMEM_BUDGET
+    together, else 'row'. Shapes and the algebra alone decide."""
+    if batch % SLAB == 0:
+        return "runs" if source_runs(batch, semiring) else "rows8"
+    if (grouped_vmem_bytes(batch, ns, ntiles, t)
+            + table_vmem_bytes(batch, ns, t) <= GROUPED_VMEM_BUDGET):
+        return "table"
+    return "row"
+
+
 def _row_triggers(src_vals, zero) -> jnp.ndarray:
     """(B, ns, T) source state -> (words * ns,) i32 trigger bitmask, words
     = ceil(B / 32): bit b % 32 of entry (b // 32) * ns + s is set iff row
@@ -285,20 +322,19 @@ def _row_triggers(src_vals, zero) -> jnp.ndarray:
 
 
 @functools.lru_cache(maxsize=None)
-def _make_grouped_kernel(semiring: Semiring, batch: int, group: int):
+def _make_grouped_kernel(semiring: Semiring, batch: int, group: int,
+                         body: str):
     """Kernel body of the grouped path: grid step g relaxes slots
     [g*group, min((g+1)*group, n_active)) of the block stream, each
     against all B rows of the VMEM-resident source state, into the
     VMEM-resident output. Weight blocks stay in HBM and are copied in by
     hand through a ring of WEIGHT_BUFFERS buffers, the copies of the
     next slots in flight while slot j relaxes, across group boundaries
-    too. At B a multiple of 8 under an idempotent ⊕ the body is the
-    source-run one (`source_runs`, module doc), whose broadcast scratch
-    `xs` is the last argument."""
+    too. `body` is `relax_body`'s (module doc); the source-run body's
+    broadcast scratch or the table body's table is the last argument."""
     add, mul = semiring.add_jnp, semiring.mul_jnp
     add_reduce = semiring.add_reduce_jnp
     ahead = WEIGHT_BUFFERS - 1
-    runs = source_runs(batch, semiring)
 
     def _grouped_relax_kernel(nact_ref, bsrc_ref, bdst_ref, bsel_ref,
                               trig_ref, src_ref, carry_hbm, blocks_hbm,
@@ -312,6 +348,11 @@ def _make_grouped_kernel(semiring: Semiring, batch: int, group: int):
             return pltpu.make_async_copy(blocks_hbm.at[bsel_ref[j]],
                                          wbuf.at[k], wsem.at[k])
 
+        def fires(b, s):
+            """Row b's trigger bit for source tile s: set iff the tile
+            holds a lane other than the ⊕-identity."""
+            return (trig_ref[(b // 32) * ns + s] >> (b % 32)) & 1
+
         # seed the resident output with the carry (current attrs for
         # monotone algebras, the un-absorbed residual for delta-PR) and
         # put the first blocks in flight
@@ -321,6 +362,20 @@ def _make_grouped_kernel(semiring: Semiring, batch: int, group: int):
             seed.start()
             for j in range(ahead):
                 pl.when(j < n)(lambda j=j: fetch(j).start())
+            if body == "table":
+                # each live (row, source tile) broadcast, once a call:
+                # table[b, s][i, :] = src[b, s, i]. A tile whose trigger
+                # bit is clear is not built: the merge's select drops
+                # its slots, so a stale entry is never merged
+                for b in range(batch):
+                    def build(s, carry, b=b):
+                        @pl.when(fires(b, s) != 0)
+                        def _build():
+                            v = src_ref[b, pl.ds(s, 1)]          # (1, T)
+                            xs[0][b, s] = jnp.broadcast_to(
+                                v[0][:, None], (t, t))
+                        return carry
+                    jax.lax.fori_loop(0, ns, build, 0)
             seed.wait()
 
         def slot(j, carry):
@@ -329,7 +384,7 @@ def _make_grouped_kernel(semiring: Semiring, batch: int, group: int):
             w = wbuf.at[j % WEIGHT_BUFFERS]
             s, d = bsrc_ref[j], bdst_ref[j]
 
-            if runs:
+            if body == "runs":
                 # a run of slots with one source tile shares the tile's
                 # broadcast: xs[c, i] is (8, T), sublane r holding row
                 # 8c + r's lane i on every lane. It is built at the
@@ -376,14 +431,14 @@ def _make_grouped_kernel(semiring: Semiring, batch: int, group: int):
                 # the scalar trigger, not a branch: rows without a branch
                 # between them interleave, which on a v5e costs less than
                 # the compute a branch would skip
-                fire = (trig_ref[(b // 32) * ns + s] >> (b % 32)) & 1
                 out_ref[b, pl.ds(d, 1)] = jnp.where(
-                    fire != 0, add(cur, cand), cur)
+                    fires(b, s) != 0, add(cur, cand), cur)
 
-            if batch % SLAB == 0:
+            if body == "rows8":
                 # one (8, T) -> (T, 8) transpose puts the source tile of 8
                 # rows on the sublane axis at once; below 8 rows the
-                # per-row (1, T) -> (T, 1) relayout costs less
+                # table body, or the per-row (1, T) -> (T, 1) relayout,
+                # costs less
                 def rows8(c, carry):
                     b0 = pl.multiple_of(c * SLAB, SLAB)
                     cols = src_ref[pl.ds(b0, SLAB), s, :].T  # (T, 8)
@@ -394,11 +449,19 @@ def _make_grouped_kernel(semiring: Semiring, batch: int, group: int):
 
                 return jax.lax.fori_loop(0, batch // SLAB, rows8, carry)
 
-            def row(b, carry):
-                src = src_ref[b, pl.ds(s, 1)]               # (1, T)
-                merge(b, add_reduce(mul(src[0][:, None], w[...]),
-                                    axis=0)[None])
-                return carry
+            if body == "table":
+                # the slot's source tile, broadcast once a call: the row
+                # body's ⊗ and ⊕-reduce on the same values
+                def row(b, carry):
+                    merge(b, add_reduce(mul(xs[0][b, s], w[...]),
+                                        axis=0)[None])
+                    return carry
+            else:
+                def row(b, carry):
+                    src = src_ref[b, pl.ds(s, 1)]           # (1, T)
+                    merge(b, add_reduce(mul(src[0][:, None], w[...]),
+                                        axis=0)[None])
+                    return carry
 
             return jax.lax.fori_loop(0, batch, row, carry,
                                      unroll=batch <= 8)
@@ -410,16 +473,20 @@ def _make_grouped_kernel(semiring: Semiring, batch: int, group: int):
 
 
 def _relax_grouped(src_vals, carry, blocks, bsrc, bdst, bsel, n_active,
-                   semiring, interpret, group=GROUP):
+                   semiring, interpret, group=GROUP, body=None):
     """The grouped grid: ceil(nslots / group) steps over (B, ns, T)
-    source and (B, ntiles, T) carry, both whole in VMEM."""
+    source and (B, ntiles, T) carry, both whole in VMEM, running `body`
+    (`relax_body`'s when None; tests and the microbenchmark name one)."""
     batch, ns, t = src_vals.shape
     ntiles = carry.shape[1]
     nslots = bsrc.shape[0]
-    # the source-run body's broadcast scratch; it reads no trigger bits
-    runs = ([pltpu.VMEM((batch // SLAB, t, SLAB, t), jnp.float32)]
-            if source_runs(batch, semiring) else [])
-    trig = (jnp.zeros((1,), jnp.int32) if runs
+    body = body or relax_body(batch, ns, ntiles, t, semiring)
+    # the body's scratch: the source-run body's broadcasts (it reads no
+    # trigger bits) or the table body's table
+    scratch = {"runs": [pltpu.VMEM((batch // SLAB, t, SLAB, t), jnp.float32)],
+               "table": [pltpu.VMEM((batch, ns, t, t), jnp.float32)]
+               }.get(body, [])
+    trig = (jnp.zeros((1,), jnp.int32) if body == "runs"
             else _row_triggers(src_vals, semiring.zero))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -434,18 +501,20 @@ def _relax_grouped(src_vals, carry, blocks, bsrc, bdst, bsel, n_active,
                                memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((WEIGHT_BUFFERS, t, t), jnp.float32),
                         pltpu.SemaphoreType.DMA((WEIGHT_BUFFERS,)),
-                        pltpu.SemaphoreType.DMA(())] + runs,
+                        pltpu.SemaphoreType.DMA(())] + scratch,
     )
     kwargs = {}
     if interpret:
         kwargs["interpret"] = interpret
     else:
         need = grouped_vmem_bytes(batch, ns, ntiles, t)
+        if body == "table":
+            need += table_vmem_bytes(batch, ns, t)
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=need + _VMEM_HEADROOM)
     return pl.pallas_call(
-        _make_grouped_kernel(semiring, batch, group),
+        _make_grouped_kernel(semiring, batch, group, body),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(carry.shape, jnp.float32),
         input_output_aliases={6: 0},   # alias carry -> out

@@ -69,8 +69,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.algebra import MIN_PLUS, Semiring, VertexAlgebra, get_algebra
 from repro.graphs.csr import Graph
 from repro.kernels.frontier.frontier import (frontier_relax_pallas,
-                                             relax_grid_steps, relax_path,
-                                             relax_walk)
+                                             relax_body, relax_grid_steps,
+                                             relax_path, relax_walk)
 from repro.obs.spans import span
 
 
@@ -773,25 +773,27 @@ def resolve_relax_mode(mode: str) -> str:
 
 
 def relax_grid(bg: BlockedGraph, batch: int, mode: str = "auto",
-               feature_dim: int = 1) -> tuple[str, int, str]:
-    """``(path, grid steps per relax step, walk)`` that `frontier_relax`
-    takes for a (B, ntiles, T[, d]) state over `bg`, from static shapes
-    and the algebra alone: the Pallas kernel's 'grouped' or 'slab' grid
-    and the order it walks the stream in (`relax_walk`), or
-    ('jnp', 0, '') off the Pallas paths. On a sharded layout, those of
-    each device's call."""
+               feature_dim: int = 1) -> tuple[str, int, str, str]:
+    """``(path, grid steps per relax step, walk, body)`` that
+    `frontier_relax` takes for a (B, ntiles, T[, d]) state over `bg`,
+    from static shapes and the algebra alone: the Pallas kernel's
+    'grouped' or 'slab' grid, the order it walks the stream in
+    (`relax_walk`) and the grouped grid's body (`relax_body`; '' on the
+    slab grid), or ('jnp', 0, '', '') off the Pallas paths. On a sharded
+    layout, those of each device's call."""
     if resolve_relax_mode(mode) == "jnp":
-        return "jnp", 0, ""
+        return "jnp", 0, "", ""
     sh = bg.shards
     if sh is not None:      # per device: the whole state into its slab
-        path = relax_path(batch, sh.ntiles_p, sh.tiles_per_dev, bg.tile,
-                          feature_dim)
-        nslots = sh.slots
+        ns, ntiles, nslots = sh.ntiles_p, sh.tiles_per_dev, sh.slots
     else:
-        path = relax_path(batch, bg.ntiles, bg.ntiles, bg.tile, feature_dim)
+        ns = ntiles = bg.ntiles
         nslots = int(bg.bsrc.shape[0])
+    path = relax_path(batch, ns, ntiles, bg.tile, feature_dim)
+    body = (relax_body(batch, ns, ntiles, bg.tile, bg.semiring)
+            if path == "grouped" else "")
     return (path, relax_grid_steps(path, nslots, batch),
-            relax_walk(path, batch, bg.semiring))
+            relax_walk(path, batch, bg.semiring), body)
 
 
 def frontier_relax(src_vals, carry, bg: BlockedGraph, mode: str = "auto",

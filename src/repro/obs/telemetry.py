@@ -105,6 +105,9 @@ class DispatchTelemetry:
     relax_path: str = ""    # relax kernel grid: 'grouped' | 'slab' | 'jnp'
     relax_grid_steps: int = 0   # kernel grid steps per relax step
     relax_walk: str = ""    # stream order: 'src' | 'dst' | '' (jnp)
+    # grouped grid's body: 'runs' | 'rows8' | 'table' | 'row' | '' (slab,
+    # jnp)
+    relax_body: str = ""
     meta: dict = dataclasses.field(default_factory=dict)
 
     def summary(self) -> dict:
@@ -132,6 +135,7 @@ class DispatchTelemetry:
             "relax_path": self.relax_path,
             "relax_grid_steps": self.relax_grid_steps,
             "relax_walk": self.relax_walk,
+            "relax_body": self.relax_body,
             # blocks streamed per source broadcast built (source walk)
             "slots_per_src_run": (int(tr.blocks_fetched.sum()) / runs
                                   if runs else None),
@@ -163,6 +167,7 @@ class DispatchTelemetry:
             "relax_path": self.relax_path,
             "relax_grid_steps": self.relax_grid_steps,
             "relax_walk": self.relax_walk,
+            "relax_body": self.relax_body,
             "steps": [int(s) for s in np.atleast_1d(self.steps)],
             "wall_s": self.wall_s, "truncated": self.truncated,
             "meta": self.meta, "trace": self.trace.to_json(),

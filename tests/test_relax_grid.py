@@ -1,7 +1,7 @@
 """The relax kernel's two grids: the grouped path (GROUP slots of the
 block stream per grid step, all B rows, state resident in VMEM, weight
 blocks copied in by hand) against the slab path and the jnp fallback,
-and the shape rule that picks between them.
+and the shape rules that pick the grid and the grouped grid's body.
 
 The grouped path runs under the TPU interpreter
 (`pltpu.InterpretParams`), which executes its manual DMAs and
@@ -87,21 +87,27 @@ def _stream(sr, sv, blocks, bsrc, bdst, stream, walk=None):
             int(n_active))
 
 
-def _grouped(sv, carry, sr, *stream_args):
-    """The grouped grid at GROUP slots a step, on the TPU interpreter."""
+def _grouped(sv, carry, sr, *stream_args, body=None):
+    """The grouped grid at GROUP slots a step, on the TPU interpreter,
+    running `body` (the shapes' own when None)."""
     grouped = jax.jit(functools.partial(
         F._relax_grouped, semiring=sr, interpret=pltpu.InterpretParams(),
-        group=GROUP))
+        group=GROUP, body=body))
     return np.asarray(grouped(sv, carry, *stream_args))
 
 
 @pytest.mark.parametrize("stream", sorted(DEAD))
-@pytest.mark.parametrize("batch", [1, 3, 8, 16])
+@pytest.mark.parametrize("batch", [1, 2, 3, 8, 16])
 @pytest.mark.parametrize("sr", SCALAR, ids=lambda s: s.name)
 def test_grouped_matches_slab_and_jnp(sr, batch, stream):
     """The grouped grid over the stream in the order its calls walk it
     (source-major at B = 8 and 16 under an idempotent ⊕) against the
-    slab grid over the destination-major stream and the jnp fallback."""
+    slab grid over the destination-major stream and the jnp fallback.
+    Below 8 rows it runs the table body, whose table holds stale
+    entries for the tiles a row's trigger leaves unbuilt."""
+    body = F.relax_body(batch, NTILES, NTILES, T, sr)
+    assert body == ("table" if batch % 8 else
+                    "runs" if sr.idempotent else "rows8")
     sv, carry, blocks, bsrc, bdst = _case(sr, batch, stream)
     nb = bsrc.shape[0]
     want = np.asarray(_relax_jnp(sv, carry, blocks, bsrc, bdst,
@@ -160,6 +166,40 @@ def test_source_runs_bit_exact_with_real_floats(sr):
                                  semiring=sr))
     np.testing.assert_array_equal(got, slab)
     np.testing.assert_array_equal(got, want)
+    assert not np.array_equal(got, np.asarray(carry))   # it relaxed
+
+
+@pytest.mark.parametrize("batch", [1, 3])
+@pytest.mark.parametrize("sr", [MIN_PLUS, PLUS_TIMES], ids=lambda s: s.name)
+def test_table_body_bit_exact_with_real_floats(sr, batch):
+    """U[0,1) f32 weights and state, which round in every ⊗ and, under
+    (+, ×), in every ⊕: the table body gives the row body's bits, and
+    the slab grid's, because each slot runs the same ⊗ on the same f32
+    values, the same (T, T) axis-0 reduce and the same merge order."""
+    rng = np.random.default_rng(11)
+    nt = 9
+    pairs = sorted({(d, d) for d in range(nt)}
+                   | {(int(d), int(s)) for d, s in
+                      rng.integers(0, nt, (30, 2))})
+    bdst = jnp.asarray([d for d, _ in pairs], jnp.int32)
+    bsrc = jnp.asarray([s for _, s in pairs], jnp.int32)
+    blocks = rng.random((len(pairs), T, T), np.float32)
+    blocks[rng.random(blocks.shape) < 0.5] = sr.zero
+    sv = rng.random((batch, nt, T), np.float32)
+    sv[rng.random(sv.shape) < 0.3] = sr.zero
+    sv[:, [2, 6]] = sr.zero                     # dead for every row
+    sv[batch - 1, 4] = sr.zero                  # dead for one row
+    carry = rng.random((batch, nt, T), np.float32)
+    sv, carry, blocks = map(jnp.asarray, (sv, carry, blocks))
+    args = _stream(sr, sv, blocks, bsrc, bdst, "compact")
+    assert 0 < args[-1] < len(pairs)
+    assert F.relax_body(batch, nt, nt, T, sr) == "table"
+    got = _grouped(sv, carry, sr, *args)
+    row = _grouped(sv, carry, sr, *args, body="row")
+    slab = np.asarray(F._relax_slab(sv, carry, *args[:-1], semiring=sr,
+                                    interpret=True, feature_dim=1))
+    np.testing.assert_array_equal(got, row)
+    np.testing.assert_array_equal(got, slab)
     assert not np.array_equal(got, np.asarray(carry))   # it relaxed
 
 
@@ -231,6 +271,10 @@ def test_grouped_default_group_through_public_entry(batch):
 ])
 def test_relax_path_by_shape(feature_dim, batch, ntiles, path):
     assert F.relax_path(batch, ntiles, ntiles, 128, feature_dim) == path
+    # the grid's VMEM count leaves the table body's table out
+    assert F.grouped_vmem_bytes(batch, ntiles, ntiles, 128) == {
+        (8, 256): 3145728, (1, 8192): 8912896, (32, 8192): 271056896,
+        (1, 128): 655360}[batch, ntiles]
     nslots = 65448
     steps = F.relax_grid_steps(path, nslots, batch)
     if path == "grouped":
@@ -250,6 +294,47 @@ def test_budget_edge():
     assert F.grouped_vmem_bytes(1, fit, fit, t) <= F.GROUPED_VMEM_BUDGET
     assert F.relax_path(1, fit, fit, t) == "grouped"
     assert F.relax_path(1, fit + 8, fit + 8, t) == "slab"
+
+
+@pytest.mark.parametrize("batch, ntiles, sr, body", [
+    (1, 256, MIN_PLUS, "table"),     # graph500-s15 solo: a 16 MiB table
+    (1, 256, PLUS_TIMES, "table"),   # (+, ×) too, in the destination walk
+    (3, 256, OR_AND, "table"),       # 48 MiB: the largest B that fits
+    (4, 256, MIN_PLUS, "row"),       # 64 MiB of table alone: over budget
+    (1, 8192, MIN_PLUS, "row"),      # 2^20-vertex road graph: 512 MiB
+    (8, 256, MIN_PLUS, "runs"),
+    (8, 256, PLUS_TIMES, "rows8"),
+    (16, 256, MAX_MIN, "runs"),
+    (32, 8192, MIN_PLUS, "runs"),    # the body a grouped call would run
+])
+def test_relax_body_by_shape(batch, ntiles, sr, body):
+    """The grouped grid's body from shapes and the algebra alone; the
+    table never moves a shape off the grouped grid."""
+    assert F.relax_body(batch, ntiles, ntiles, 128, sr) == body
+    if body == "table":
+        assert (F.grouped_vmem_bytes(batch, ntiles, ntiles, 128)
+                + F.table_vmem_bytes(batch, ntiles, 128)
+                <= F.GROUPED_VMEM_BUDGET)
+    if ntiles == 256:
+        assert F.relax_path(batch, ntiles, ntiles, 128) == "grouped"
+
+
+def test_table_budget_edge():
+    """The table body's budget is inclusive and counts the grouped
+    state, the weight buffers and B * ns' * T*T * 4 B of table; one
+    more slab of destination tiles tips it to the row body, and the
+    grid stays grouped either way."""
+    t, ns = 128, 960
+    table = ns * t * t * 4
+    weights = F.WEIGHT_BUFFERS * t * t * 4
+    ntiles = (F.GROUPED_VMEM_BUDGET - table - weights) // (t * 4) - ns
+    assert ntiles % 8 == 0
+    assert F.table_vmem_bytes(1, ns, t) == table
+    assert (F.grouped_vmem_bytes(1, ns, ntiles, t) + table
+            == F.GROUPED_VMEM_BUDGET)
+    assert F.relax_body(1, ns, ntiles, t, MIN_PLUS) == "table"
+    assert F.relax_body(1, ns, ntiles + 8, t, MIN_PLUS) == "row"
+    assert F.relax_path(1, ns, ntiles + 8, t) == "grouped"
 
 
 def test_distributed_shape_takes_grouped_path():
@@ -293,12 +378,17 @@ def test_telemetry_reports_relax_grid(relax_mode, batch, algo, path, walk):
     d = rt.telemetry.dispatches[0]
     nslots = d.n_blocks
     want_steps = 0 if path == "jnp" else -(-nslots // F.GROUP)
-    want = (path, want_steps, walk)
-    assert (d.relax_path, d.relax_grid_steps, d.relax_walk) == want
+    body = {1: "table", 4: "table", 8: "runs" if walk == "src"
+            else "rows8"}[batch] if path == "grouped" else ""
+    want = (path, want_steps, walk, body)
+    assert (d.relax_path, d.relax_grid_steps, d.relax_walk,
+            d.relax_body) == want
     s = d.summary()
-    assert (s["relax_path"], s["relax_grid_steps"], s["relax_walk"]) == want
+    assert (s["relax_path"], s["relax_grid_steps"], s["relax_walk"],
+            s["relax_body"]) == want
     j = d.to_json()
-    assert (j["relax_path"], j["relax_grid_steps"], j["relax_walk"]) == want
+    assert (j["relax_path"], j["relax_grid_steps"], j["relax_walk"],
+            j["relax_body"]) == want
     tr = d.trace
     if walk != "src":
         assert tr.src_runs is None and s["slots_per_src_run"] is None
@@ -347,10 +437,12 @@ def test_microbench_rehearses_on_the_interpreter(tmp_path):
                        str(out)]) == 0
     lines = [json.loads(x) for x in out.read_text().splitlines()]
     assert [(x["batch"], x["body"], x["run"]) for x in lines] == [
-        (1, "row", 1), (1, "row", 4), (8, "rows8", 1), (8, "rows8", 4),
-        (8, "runs", 1), (8, "runs", 4)]
+        (1, "table", 1), (1, "table", 4), (1, "row", 1), (1, "row", 4),
+        (8, "rows8", 1), (8, "rows8", 4), (8, "runs", 1), (8, "runs", 4)]
     assert all(x["us_per_slot"] is None for x in lines)
-    assert F.source_runs(8, MIN_PLUS) and F.CHAINS == 8
+    assert F.relax_body(8, 8, 8, 128, MIN_PLUS) == "runs"
+    assert F.relax_body(1, 8, 8, 128, MIN_PLUS) == "table"
+    assert F.CHAINS == 8
 
 
 def test_relax_grid_slab_for_vector_programs():
@@ -362,6 +454,7 @@ def test_relax_grid_slab_for_vector_programs():
     bg = cq.engine.bg
     nslots = int(bg.bsrc.shape[0])
     assert relax_grid(bg, 4, "interpret", feature_dim=8) == \
-        ("slab", nslots * 4, "dst")
+        ("slab", nslots * 4, "dst", "")
     assert relax_grid(bg, 4, "interpret") == ("grouped",
-                                              -(-nslots // F.GROUP), "dst")
+                                              -(-nslots // F.GROUP), "dst",
+                                              "table")

@@ -32,7 +32,8 @@ from repro.algebra import MAX_MIN, MIN_PLUS, OR_AND, PLUS_TIMES
 from repro.kernels.frontier.frontier import (GROUPED_VMEM_BUDGET,
                                              _VMEM_HEADROOM,
                                              frontier_relax_pallas,
-                                             grouped_vmem_bytes, relax_path)
+                                             grouped_vmem_bytes, relax_body,
+                                             relax_path, table_vmem_bytes)
 
 T = 128
 EXT_LRN = (128, 382)          # (ntiles, blocks) of make_dataset("ExtLRN")
@@ -126,18 +127,22 @@ def _used_vmem(hlo: str) -> int:
                if c["memory_space"] == "1")
 
 
-@pytest.mark.parametrize("batch", [1, 8])
+@pytest.mark.parametrize("batch", [1, 3, 8])
 @pytest.mark.parametrize("semiring", [MIN_PLUS, PLUS_TIMES],
                          ids=lambda s: s.name)
 def test_grouped_graph500_s15(one_chip, semiring, batch):
     """The benchmark's shape on the grouped grid, compacted stream: Mosaic
-    takes the resident state and the manual weight copies, within the
+    takes the resident state, the manual weight copies and, below B = 8,
+    the table body's (B, 256, T, T) table (48 MiB at B = 3), within the
     scoped VMEM the call grants."""
     ntiles, nb = GRAPH500_S15
     assert relax_path(batch, ntiles, ntiles, T) == "grouped"
     state = (batch, ntiles, T) if batch > 1 else (ntiles, T)
     hlo = _compile(one_chip, semiring, state, nb + 1, nb, compact=True)
     need = grouped_vmem_bytes(batch, ntiles, ntiles, T)
+    if batch % 8:
+        assert relax_body(batch, ntiles, ntiles, T, semiring) == "table"
+        need += table_vmem_bytes(batch, ntiles, T)
     assert need <= GROUPED_VMEM_BUDGET
     # Mosaic's own transients fit the headroom the call grants above the
     # resident bytes

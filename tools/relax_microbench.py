@@ -11,11 +11,13 @@ below `n_active`) over a stream of --slots slots in which each source
 tile owns a run of R consecutive slots, for each R of --runs: the shape
 the source walk gives a Graph500 stream (~125-256 slots a source tile),
 and R = 1, the destination walk's. Each slot copies a distinct 64 KiB
-weight block (the stream cycles over --blocks of them). Bodies: 'row'
-at B = 1; at B = 8 'runs', the source-run body the kernel takes, and
-'rows8', the per-slot transpose-and-broadcast body it replaced (run by
-switching `source_runs` off). --chains also times the source-run body
-at other CHAINS values.
+weight block (the stream cycles over --blocks of them). Bodies
+(`frontier.relax_body`): below B = 8 'table', the body the kernel takes
+where its table fits, and 'row', the per-slot relayout-and-broadcast
+body it replaced; at B = 8 'runs', the source-run body the kernel
+takes, and 'rows8', the per-slot transpose-and-broadcast body it
+replaced. --chains also times the source-run body at other CHAINS
+values.
 
 Prints one JSON line per (B, body, chains, R) with the best of --repeat
 timings in microseconds a slot; off a TPU it refuses to run unless
@@ -71,11 +73,9 @@ def main(argv=None) -> int:
     blocks = jnp.asarray(rng.random((args.blocks, t, t), np.float32))
     bsel = jnp.asarray(np.arange(nslots) % args.blocks, jnp.int32)
     bdst = jnp.asarray(np.arange(nslots) * 97 % ns, jnp.int32)
-    real_runs, real_chains = F.source_runs, F.CHAINS
+    real_chains = F.CHAINS
 
     def timed(batch, run, body, chains):
-        F.source_runs = (real_runs if body != "rows8"
-                         else lambda b, sr: False)
         F.CHAINS = chains
         F._make_grouped_kernel.cache_clear()
         bsrc = jnp.asarray(np.arange(nslots) // run % ns, jnp.int32)
@@ -88,7 +88,8 @@ def main(argv=None) -> int:
         def calls(sv, carry, blocks, bsrc, bdst, bsel):
             def one(_, c):
                 return F._relax_grouped(sv, c, blocks, bsrc, bdst, bsel,
-                                        nslots, MIN_PLUS, interpret)
+                                        nslots, MIN_PLUS, interpret,
+                                        body=body)
             return jax.lax.fori_loop(0, args.reps, one, carry)
 
         operands = (sv, carry, blocks, bsrc, bdst, bsel)
@@ -103,7 +104,8 @@ def main(argv=None) -> int:
 
     cases = []
     for batch in (int(b) for b in args.batches.split(",")):
-        bodies = ([("row", real_chains)] if batch % F.SLAB else
+        bodies = ([("table", real_chains), ("row", real_chains)]
+                  if batch % F.SLAB else
                   [("rows8", real_chains), ("runs", real_chains)]
                   + [("runs", int(c)) for c in args.chains.split(",")
                      if c and int(c) != real_chains])
@@ -123,7 +125,7 @@ def main(argv=None) -> int:
                 out.write(line + "\n")
                 out.flush()
     finally:
-        F.source_runs, F.CHAINS = real_runs, real_chains
+        F.CHAINS = real_chains
         F._make_grouped_kernel.cache_clear()
         if out:
             out.close()
